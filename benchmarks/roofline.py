@@ -48,8 +48,7 @@ from repro.configs import registry                              # noqa: E402
 from repro.core.parallelism import rules_for                    # noqa: E402
 from repro.launch import specs as S                             # noqa: E402
 from repro.launch.dryrun import collective_bytes, skip_reason   # noqa: E402
-from repro.launch.dryrun import cost_analysis_dict            # noqa: E402
-from repro.launch.mesh import make_production_mesh, mesh_context  # noqa: E402
+from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.models.config import ALL_SHAPES, ModelConfig, ShapeConfig  # noqa: E402
 from repro.optim import adam                                    # noqa: E402
 from repro.serve.engine import make_prefill, make_serve_step    # noqa: E402
@@ -111,9 +110,9 @@ def _lower_cell(cfg: ModelConfig, shape: ShapeConfig, mesh, *, qat: bool):
         args = (S.params_shapes(cfg), S.input_specs(cfg, shape)["tokens"],
                 S.cache_shapes(cfg, shape.global_batch, shape.seq_len),
                 jax.ShapeDtypeStruct((), jnp.int32))
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         compiled = jitted.lower(*args).compile()
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis()
         coll = collective_bytes(compiled.as_text())
     return (cost.get("flops", 0.0), cost.get("bytes accessed", 0.0),
             sum(coll.values()), coll)
@@ -148,12 +147,12 @@ def _rwkv_chunk_correction(cfg: ModelConfig, shape: ShapeConfig, mesh,
     def chunk_fn(r, k, v, lw, u, s0):
         return R._wkv_chunk(r, k, v, lw, u, s0)
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(chunk_fn, in_shardings=(sh4, sh4, sh4, sh4, None,
                                                    shs)).lower(
             sds((b, c, h, n)), sds((b, c, h, n)), sds((b, c, h, n)),
             sds((b, c, h, n)), sds((h, n)), sds((b, h, n, n))).compile()
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis()
     mult = (n_chunks - 1) * n_rwkv
     # training backward re-traverses the chunk scan (~2x fwd cost for the
     # matmul-dominated body) + remat replays the forward once more
@@ -264,4 +263,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -8,8 +8,9 @@ import pathlib
 import sys
 
 _REPO = pathlib.Path(__file__).resolve().parents[1]
-if str(_REPO) not in sys.path:
-    sys.path.insert(0, str(_REPO))
+for _p in (_REPO, _REPO / "src"):
+    if str(_p) not in sys.path:
+        sys.path.insert(0, str(_p))
 
 import argparse
 
@@ -76,4 +77,7 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

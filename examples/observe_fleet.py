@@ -21,6 +21,11 @@ naming exactly that host's gauge.  At the end the workers are stopped and
 the aggregator is polled once more to show liveness flipping dead
 (the ``heartbeat-gap`` rule fires for every silent host).
 
+Every host process is pinned to the CPU (``JAX_PLATFORMS=cpu``, set in the
+child before it imports JAX).  A TPU belongs to one process at a time: on a
+machine with a chip, the first host would take it and the other two would
+fail on its lock.  The demo is about the telemetry plane, not the device.
+
     PYTHONPATH=src python examples/observe_fleet.py
 """
 
@@ -39,7 +44,11 @@ STALENESS_S = 2.0
 
 def serve_host(name: str, rogue: bool, port_q, stop_evt) -> None:
     """One fleet member: engine + HTTP endpoint, traffic until told to
-    stop.  Runs in its own OS process (own registry, own port)."""
+    stop.  Runs in its own OS process (own registry, own port), on the
+    CPU: the chip admits one process, and there are three hosts."""
+    import os
+
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
     import numpy as np
 

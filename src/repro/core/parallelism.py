@@ -233,17 +233,6 @@ def tree_pspecs(spec_tree, rules: ShardingRules):
     )
 
 
-def ambient_mesh():
-    """The mesh currently in scope, across jax versions: jax >= 0.6 exposes
-    jax.sharding.get_abstract_mesh(); older releases track the Mesh entered
-    as a context manager in the thread-local resource env."""
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        return get_abstract()
-    from jax._src.mesh import thread_resources
-    return thread_resources.env.physical_mesh
-
-
 def constrain(x: jax.Array, rules: Optional[ShardingRules],
               *logical: Optional[str]) -> jax.Array:
     """with_sharding_constraint via logical axes (shape-aware; no-op when
@@ -251,26 +240,14 @@ def constrain(x: jax.Array, rules: Optional[ShardingRules],
     if rules is None:
         return x
     try:
-        mesh = ambient_mesh()
-        if mesh is None or mesh.empty:
+        mesh = jax.sharding.get_abstract_mesh()
+        if mesh.empty:
             return x
-        spec = rules.mesh_axes(logical, x.shape,
-                               _ConcreteShim(mesh))
+        spec = rules.mesh_axes(logical, x.shape, mesh)
         return jax.lax.with_sharding_constraint(x, spec)
     except (ValueError, RuntimeError):
         return x
 
 
-class _ConcreteShim:
-    """Adapter exposing .shape[axis] for abstract/physical meshes."""
-
-    def __init__(self, mesh):
-        sizes = getattr(mesh, "axis_sizes", None)
-        if sizes is None:  # physical Mesh pre-0.6: .shape is an OrderedDict
-            sizes = tuple(mesh.shape[name] for name in mesh.axis_names)
-        self.shape = dict(zip(mesh.axis_names, sizes))
-
-
 __all__ = ["ShardingRules", "Logical", "train_rules", "serve_rules",
-           "rules_for", "tree_shardings", "tree_pspecs", "constrain",
-           "ambient_mesh"]
+           "rules_for", "tree_shardings", "tree_pspecs", "constrain"]

@@ -28,15 +28,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 Array = jax.Array
+
+
+def _dot(a, b):
+    """f32 MXU contraction at full f32 precision: the default TPU precision
+    would round the f32 weights to bf16."""
+    return jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
 
 
 def _epilogue(acc, b_ref, activation: str):
     out = acc
     if b_ref is not None:
-        out = out + b_ref[...].astype(jnp.float32)
+        out = out + b_ref[...]
     if activation == "relu":
         out = jnp.maximum(out, 0.0)
     elif activation == "tanh":
@@ -52,8 +57,8 @@ def _dense_kernel_full(x_hi_ref, x_lo_ref, w_ref, b_ref, o_ref, acc_ref, *,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     w = w_ref[...]
-    acc_ref[...] += jnp.dot(x_hi_ref[...], w, preferred_element_type=jnp.float32)
-    acc_ref[...] += jnp.dot(x_lo_ref[...], w, preferred_element_type=jnp.float32)
+    acc_ref[...] += _dot(x_hi_ref[...], w)
+    acc_ref[...] += _dot(x_lo_ref[...], w)
 
     @pl.when(pl.program_id(2) == n_k - 1)
     def _done():
@@ -67,8 +72,7 @@ def _dense_kernel_half(x_ref, w_ref, b_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(x_ref[...], w_ref[...],
-                            preferred_element_type=jnp.float32)
+    acc_ref[...] += _dot(x_ref[...], w_ref[...])
 
     @pl.when(pl.program_id(2) == n_k - 1)
     def _done():
@@ -82,7 +86,8 @@ def fxp_dense_pallas(x_hi: Array, x_lo: Optional[Array], w: Array,
                      interpret: bool = False) -> Array:
     """Raw pallas_call; shapes must already be padded to block multiples.
 
-    x_hi/x_lo: (M, K) f32 limbs. w: (K, N) f32. b: (N,) f32 or None.
+    x_hi/x_lo: (M, K) f32 limbs. w: (K, N) f32. b: (1, N) f32 or None —
+    2-D so its block is a legal (1, bn) lane-major Mosaic tile.
     """
     m, k = x_hi.shape
     k2, n = w.shape
@@ -94,7 +99,7 @@ def fxp_dense_pallas(x_hi: Array, x_lo: Optional[Array], w: Array,
     x_spec = pl.BlockSpec((bm, bk), lambda i, j, s: (i, s))
     w_spec = pl.BlockSpec((bk, bn), lambda i, j, s: (s, j))
     o_spec = pl.BlockSpec((bm, bn), lambda i, j, s: (i, j))
-    b_spec = pl.BlockSpec((bn,), lambda i, j, s: (j,)) if b is not None else None
+    b_spec = pl.BlockSpec((1, bn), lambda i, j, s: (0, j))
 
     if full_precision:
         kern = functools.partial(_dense_kernel_full, activation=activation,
@@ -119,7 +124,7 @@ def fxp_dense_pallas(x_hi: Array, x_lo: Optional[Array], w: Array,
         out_specs=o_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*args)

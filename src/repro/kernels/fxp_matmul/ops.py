@@ -4,7 +4,7 @@
 limb split, dispatches the Pallas kernel, and unpads — so callers (DDPG
 networks, LM MLPs) can use it as a drop-in `x @ w + b` with a precision
 switch.  On CPU we run interpret mode; on TPU the same code emits the real
-Mosaic kernel (`interpret` defaults from jax.default_backend()).
+Mosaic kernel (`interpret` defaults from `kernels._compat.interpret_mode`).
 """
 from __future__ import annotations
 
@@ -14,7 +14,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels._compat import mlp_flops, round_up as _round_up
+from repro.kernels._compat import interpret_mode, mlp_flops, round_up as _round_up
 from repro.kernels.fxp_matmul.kernel import fxp_dense_pallas
 from repro.kernels.fxp_matmul.ref import limb_split
 
@@ -43,7 +43,7 @@ def fxp_dense(x: Array, w: Array, b: Optional[Array] = None, *,
     full_precision=False -> one-pass (post-delay, quantized activations)
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     orig_shape = x.shape
     k = orig_shape[-1]
     n = w.shape[-1]
@@ -54,7 +54,8 @@ def fxp_dense(x: Array, w: Array, b: Optional[Array] = None, *,
     mp, kp, np_ = _round_up(m, bm), _round_up(k, bk), _round_up(n, bn)
     x2 = jnp.pad(x2, ((0, mp - m), (0, kp - k)))
     wp = jnp.pad(w.astype(jnp.float32), ((0, kp - k), (0, np_ - n)))
-    bp = None if b is None else jnp.pad(b.astype(jnp.float32), (0, np_ - n))
+    bp = (None if b is None
+          else jnp.pad(b.astype(jnp.float32), (0, np_ - n)).reshape(1, np_))
 
     # half mode only consumes the hi limb — skip the dead lo computation
     hi, lo = limb_split(x2, with_lo=full_precision)

@@ -27,7 +27,8 @@ Design
   inter-layer activations live in registers/VMEM and never touch HBM.
 * **Fused QAT sites**: the Algorithm-1 range monitor + phase-selected
   quantizer (`kernels/quantize` semantics) runs inline on each layer input:
-  per-block masked min/max are written to a `(n_blocks, L)` output (reduced
+  per-block masked min/max are written to one lane-dense (8, 128) stats
+  tile per batch block (reduced
   to per-site scalars by the wrapper, then folded into `QATState` ranges by
   `QATContext.observe`), and the activation is projected onto the Q15.16
   lattice (monitor phase) or the captured n-bit affine lattice (quantized
@@ -65,9 +66,9 @@ Design
   in-kernel: Adam moment/param update (`optim/adam.leaf_update` /
   `optim/fxp_adam.leaf_update(ste=False)` against SMEM-shipped
   `StepConstants`) followed by the Polyak soft-update of the target nets.
-  The critic's first layer is split host-side into obs-rows and action-rows
-  so the actor's in-kernel output feeds it without a concat (launch 2), and
-  the target-critic sees kernel-computed target actions (launch 1).
+  The kernel-computed actions (launch 2) and target actions (launch 1) are
+  lane-rotated next to the observations (`pltpu.roll`), so the critic's
+  first layer runs the same concat-input dot as the custom-VJP path.
 
 Train-time dispatch (`serve/policy` + `train/learner`) chooses between
 `fused_step` (2 launches, best at large batch), `fused` (the 8-launch

@@ -8,7 +8,9 @@ rationale.  Layout summary:
   inputs          x (M, K0) blocked by row; per-layer w (Kp, Np) and
                   b (1, Np) with constant index maps (VMEM-resident);
                   deltas/zs (L,) f32 in SMEM (per-site affine params)
-  outputs         y (M, NL); per-block site mins/maxs (n_blocks, L)
+  outputs         y (M, NL); per-block stats (n_blocks * 8, 128): one
+                  lane-dense tile per grid step, site mins in row 0 and
+                  maxs in row 1 (see `_stats_tile`)
   scratch         f32 accumulator (bm, max Np)
 
 Shapes must be pre-padded: rows to bm, every feature dim to 128 lanes.
@@ -29,7 +31,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.fixedpoint import FXP32
-from repro.kernels._compat import CompilerParams
 from repro.optim import adam as fadam
 from repro.optim import fxp_adam
 
@@ -53,6 +54,59 @@ _H_BC1 = 10     # 1 - b1**t
 _H_BC2 = 11     # 1 - b2**t
 HYPER_LEN = 12
 
+# Per-grid-step scalars (site extrema, loss partials) leave the kernels in
+# one lane-dense f32 tile per batch block: Mosaic cannot store a scalar into
+# VMEM, and a (1, L) block per step breaks the (8, 128) tiling rule once
+# there is more than one block.  Row r, lane j of block b's tile holds the
+# j-th value of row r; `block_stats` undoes the packing on the host side.
+STATS_TILE = (8, 128)
+_ROW_MIN, _ROW_MAX, _ROW_PART = 0, 1, 2
+
+
+def _stats_tile(rows):
+    """Pack rows of scalars into one STATS_TILE (unused entries are 0)."""
+    flat = (jax.lax.broadcasted_iota(jnp.int32, STATS_TILE, 0) * STATS_TILE[1]
+            + jax.lax.broadcasted_iota(jnp.int32, STATS_TILE, 1))
+    tile = jnp.zeros(STATS_TILE, jnp.float32)
+    for r, vals in enumerate(rows):
+        for j, v in enumerate(vals):
+            tile = jnp.where(flat == r * STATS_TILE[1] + j, v, tile)
+    return tile
+
+
+def _stats_spec():
+    return pl.BlockSpec(STATS_TILE, lambda i, ph: (i, 0))
+
+
+def _stats_shape(n_blocks: int):
+    return jax.ShapeDtypeStruct((n_blocks * STATS_TILE[0], STATS_TILE[1]),
+                                jnp.float32)
+
+
+def block_stats(stats: Array, n_mins: int, n_parts: int = 0):
+    """Reduce the per-block stats tiles: (site mins over blocks, site maxs
+    over blocks, loss partials summed over blocks)."""
+    t = stats.reshape(-1, *STATS_TILE)
+    return (jnp.min(t[:, _ROW_MIN, :n_mins], axis=0),
+            jnp.max(t[:, _ROW_MAX, :n_mins], axis=0),
+            jnp.sum(t[:, _ROW_PART, :n_parts], axis=0))
+
+
+def _dot(a, b, dims=None):
+    """f32 MXU contraction at full f32 precision.  The limb datapath needs
+    exact f32 products: the default TPU precision would round the f32
+    weights to bf16 and break parity with the f32 reference."""
+    if dims is None:
+        dims = (((a.ndim - 1,), (0,)), ((), ()))
+    return jax.lax.dot_general(a, b, dims,
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+# contraction layouts of the backward chains
+_TN = (((0,), (0,)), ((), ()))   # a^T @ b: dW = q^T g
+_NT = (((1,), (1,)), ((), ()))   # a @ b^T: dx = g W^T
+
 
 def _site_project(x, quant, delta, z, *, n_bits: int, fxp32_phase1: bool):
     """Algorithm-1 activation projection, selected by the phase flag.
@@ -73,6 +127,138 @@ def _site_project(x, quant, delta, z, *, n_bits: int, fxp32_phase1: bool):
     return jnp.where(quant, y_quant, y_full)
 
 
+def _ste_site_mask(g, x_in, quant, delta, z, *, n_bits: int,
+                   fxp32_phase1: bool):
+    """Quantize-site backward: the straight-through clip mask of the
+    phase's quantizer on the site input, shared by every backward chain.
+
+    Each branch masks the gradient itself and the phase picks between the
+    two f32 results: Mosaic cannot select between boolean masks."""
+    lo = -z * delta
+    hi = (jnp.float32((1 << n_bits) - 1) - z) * delta
+    g_q = jnp.where(jnp.logical_and(x_in >= lo, x_in <= hi), g, 0.0)
+    if fxp32_phase1:
+        xs = x_in * jnp.float32(2.0 ** FXP32.frac_bits)
+        g_f = jnp.where(jnp.logical_and(xs >= jnp.float32(FXP32.raw_min),
+                                        xs <= jnp.float32(FXP32.raw_max)),
+                        g, 0.0)
+    else:
+        g_f = g
+    return jnp.where(quant, g_q, g_f)
+
+
+class _Sites:
+    """The QAT sites of one launch: the phase flag and the SMEM per-site
+    affine params, indexed by global site number (actor sites first, then
+    critic sites).  With qat=False every site is a pass-through."""
+
+    def __init__(self, quant, deltas_ref, zs_ref, *, qat: bool, n_bits: int,
+                 fxp32_phase1: bool):
+        self.quant = quant
+        self._deltas, self._zs = deltas_ref, zs_ref
+        self._qat = qat
+        self._kw = dict(n_bits=n_bits, fxp32_phase1=fxp32_phase1)
+
+    def project(self, site: int, x):
+        if not self._qat:
+            return x
+        return _site_project(x, self.quant, self._deltas[site],
+                             self._zs[site], **self._kw)
+
+    def ste(self, site: int, g, x_in):
+        if not self._qat:
+            return g
+        return _ste_site_mask(g, x_in, self.quant, self._deltas[site],
+                              self._zs[site], **self._kw)
+
+
+def _monitor_minmax(x, in_dim: int, row0, m_valid: int):
+    """Padding-masked (min, max) of a site input block whose first row is
+    global row `row0`: pad rows (>= m_valid) and pad lanes (>= in_dim) are
+    excluded."""
+    row = row0 + jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    valid = jnp.logical_and(row < m_valid, col < in_dim)
+    return (jnp.min(jnp.where(valid, x, jnp.inf)),
+            jnp.max(jnp.where(valid, x, -jnp.inf)))
+
+
+def _act_fwd(out, actn: str):
+    if actn == "relu":
+        return jnp.maximum(out, 0.0)
+    if actn == "tanh":
+        return jnp.tanh(out)
+    return out
+
+
+def _act_bwd(g, h, actn: str):
+    """Activation backward from the saved post-activation output:
+    `h > 0` for ReLU, `1 - h^2` for tanh."""
+    if actn == "relu":
+        return jnp.where(h > 0.0, g, 0.0)
+    if actn == "tanh":
+        return g * (1.0 - h * h)
+    return g
+
+
+def _dense_fwd(x, w_ref, b_ref, acc_ref, *, actn: str, quant):
+    """Dual-precision dense layer: the hi-limb dot always issues, the
+    lo-limb dot is predicated off in the quantized phase.  Returns (the
+    effective dense input the MACs consumed — hi only in the quantized
+    phase, hi + lo == x in full precision — and the post-activation
+    output block)."""
+    n_out_p = w_ref.shape[1]
+    hi = x.astype(jnp.bfloat16).astype(jnp.float32)
+    acc_ref[:, :n_out_p] = _dot(hi, w_ref[...])
+
+    def _lo_pass():
+        acc_ref[:, :n_out_p] += _dot(x - hi, w_ref[...])
+    pl.when(jnp.logical_not(quant))(_lo_pass)
+    out = _act_fwd(acc_ref[:, :n_out_p] + b_ref[...], actn)
+    return jnp.where(quant, hi, x), out
+
+
+def _net_fwd(x, wb, acc_ref, acts, sites: _Sites, site0: int, monitor=None):
+    """One network's forward over a batch block: per layer, the QAT site
+    (global number site0 + layer) then the dual-precision dense layer.
+
+    wb: interleaved (w0, b0, w1, b1, ...) refs.  monitor, when given, is
+    (in_dims, row0, m_valid): record each site input's padding-masked
+    extrema.  Returns (y, site inputs, effective dense inputs, layer
+    outputs, mins, maxs) — the backward chain's residuals.
+    """
+    ss, qeffs, hs, mins, maxs = [], [], [], [], []
+    for li, actn in enumerate(acts):
+        if monitor is not None:
+            in_dims, row0, m_valid = monitor
+            mn, mx = _monitor_minmax(x, in_dims[li], row0, m_valid)
+            mins.append(mn)
+            maxs.append(mx)
+        ss.append(x)
+        x = sites.project(site0 + li, x)
+        qe, x = _dense_fwd(x, wb[2 * li], wb[2 * li + 1], acc_ref, actn=actn,
+                           quant=sites.quant)
+        qeffs.append(qe)
+        hs.append(x)
+    return x, ss, qeffs, hs, mins, maxs
+
+
+def _net_bwd(g, w_refs, ss, qeffs, hs, acts, sites: _Sites, site0: int,
+             dw_refs=None, db_refs=None):
+    """One network's backward over a batch block, layers last to first:
+    activation backward, dW/db accumulated into dw_refs/db_refs (when
+    given), g @ W^T, then the STE clip mask of the layer's site.  Returns
+    the cotangent of the network input."""
+    for li in reversed(range(len(acts))):
+        g = _act_bwd(g, hs[li], acts[li])
+        if dw_refs is not None:
+            db_refs[li][...] += jnp.sum(g, axis=0, keepdims=True)
+            dw_refs[li][...] += _dot(qeffs[li], g, _TN)
+        g = _dot(g, w_refs[li][...], _NT)
+        g = sites.ste(site0 + li, g, ss[li])
+    return g
+
+
 def _mlp_kernel(phase_ref, *refs, n_layers: int, bm: int, m_valid: int,
                 in_dims: Sequence[int], activations: Sequence[str],
                 n_bits: int, qat: bool, fxp32_phase1: bool,
@@ -81,62 +267,25 @@ def _mlp_kernel(phase_ref, *refs, n_layers: int, bm: int, m_valid: int,
     wb_refs = refs[1:1 + 2 * n_layers]
     deltas_ref = refs[1 + 2 * n_layers]
     zs_ref = refs[2 + 2 * n_layers]
-    y_ref, mins_ref, maxs_ref = refs[3 + 2 * n_layers:6 + 2 * n_layers]
+    y_ref, stats_ref = refs[3 + 2 * n_layers:5 + 2 * n_layers]
+    acc_ref = refs[-1]
+
+    sites = _Sites(phase_ref[0] > 0, deltas_ref, zs_ref, qat=qat,
+                   n_bits=n_bits, fxp32_phase1=fxp32_phase1)
+    monitor = (in_dims, pl.program_id(0) * bm, m_valid)
+    y, _, qeffs, hs, mins, maxs = _net_fwd(x_ref[...], wb_refs, acc_ref,
+                                           activations, sites, 0, monitor)
+    y_ref[...] = y
+    stats_ref[...] = _stats_tile([mins, maxs])
     if save_residuals:
         # training-mode extra outputs: per-layer effective dense inputs and
         # the intermediate layer outputs (the backward kernel's residuals)
-        q_refs = refs[6 + 2 * n_layers:6 + 3 * n_layers]
-        h_refs = refs[6 + 3 * n_layers:5 + 4 * n_layers]
-    acc_ref = refs[-1]
-
-    i = pl.program_id(0)
-    quant = phase_ref[0] > 0
-    row_idx = jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
-    row_ok = (i * bm + row_idx) < m_valid
-
-    x = x_ref[...]
-    for li in range(n_layers):  # unrolled: one pipelined body, L layers deep
-        w_ref, b_ref = wb_refs[2 * li], wb_refs[2 * li + 1]
-
-        # ---- fused range monitor on the site input (padding masked) -------
-        col_idx = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-        valid = jnp.logical_and(row_ok, col_idx < in_dims[li])
-        mins_ref[0, li] = jnp.min(jnp.where(valid, x, jnp.inf))
-        maxs_ref[0, li] = jnp.max(jnp.where(valid, x, -jnp.inf))
-
-        # ---- fused quantize site (phase-selected projection) --------------
-        if qat:
-            x = _site_project(x, quant, deltas_ref[li], zs_ref[li],
-                              n_bits=n_bits, fxp32_phase1=fxp32_phase1)
-
-        # ---- dual-precision dense: hi pass always, lo pass predicated -----
-        hi = x.astype(jnp.bfloat16).astype(jnp.float32)
-        if save_residuals:
-            # the input the MACs actually consumed: hi only in half mode,
-            # hi + lo == x in full mode — what dW must contract against
-            q_refs[li][...] = jnp.where(quant, hi, x)
-        n_out_p = w_ref.shape[1]
-        acc_ref[:, :n_out_p] = jnp.dot(hi, w_ref[...],
-                                       preferred_element_type=jnp.float32)
-
-        def _lo_pass(x=x, hi=hi, w_ref=w_ref, n_out_p=n_out_p):
-            lo = x - hi  # residual limb: only materialized in full precision
-            acc_ref[:, :n_out_p] += jnp.dot(lo, w_ref[...],
-                                            preferred_element_type=jnp.float32)
-        pl.when(jnp.logical_not(quant))(_lo_pass)
-
-        # ---- fused epilogue: bias + activation on the accumulator ---------
-        out = acc_ref[:, :n_out_p] + b_ref[...]
-        actn = activations[li]
-        if actn == "relu":
-            out = jnp.maximum(out, 0.0)
-        elif actn == "tanh":
-            out = jnp.tanh(out)
-        if save_residuals and li < n_layers - 1:
-            h_refs[li][...] = out
-        x = out
-
-    y_ref[...] = x
+        q_refs = refs[5 + 2 * n_layers:5 + 3 * n_layers]
+        h_refs = refs[5 + 3 * n_layers:4 + 4 * n_layers]
+        for ref, v in zip(q_refs, qeffs):
+            ref[...] = v
+        for ref, v in zip(h_refs, hs):
+            ref[...] = v
 
 
 def fxp_mlp_pallas(phase: Array, x: Array, weights: Sequence[Array],
@@ -150,7 +299,8 @@ def fxp_mlp_pallas(phase: Array, x: Array, weights: Sequence[Array],
     phase: (1,) i32 scalar-prefetch flag.  x: (Mp, K0p) f32.
     weights[i]: (Kp_i, Np_i) f32, biases[i]: (1, Np_i) f32.
     deltas/zs: (L,) f32 per-site affine params (ignored when qat=False).
-    Returns (y (Mp, NLp), mins (n_blocks, L), maxs (n_blocks, L)); with
+    Returns (y (Mp, NLp), stats (n_blocks * 8, 128)) — reduce stats with
+    `block_stats(stats, L)` for the per-site extrema; with
     save_residuals=True additionally the per-layer effective dense inputs
     qs[i] (Mp, Kp_i) and intermediate outputs hs[i] (Mp, Np_i), i < L-1 —
     the VMEM-resident residuals `fxp_mlp_bwd_pallas` consumes.
@@ -177,16 +327,9 @@ def fxp_mlp_pallas(phase: Array, x: Array, weights: Sequence[Array],
     in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))  # zs
     args.extend((deltas, zs))
 
-    out_specs = [
-        pl.BlockSpec((bm, nlp), lambda i, ph: (i, 0)),
-        pl.BlockSpec((1, n_layers), lambda i, ph: (i, 0)),
-        pl.BlockSpec((1, n_layers), lambda i, ph: (i, 0)),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((mp, nlp), jnp.float32),
-        jax.ShapeDtypeStruct((n_blocks, n_layers), jnp.float32),
-        jax.ShapeDtypeStruct((n_blocks, n_layers), jnp.float32),
-    ]
+    out_specs = [pl.BlockSpec((bm, nlp), lambda i, ph: (i, 0)), _stats_spec()]
+    out_shape = [jax.ShapeDtypeStruct((mp, nlp), jnp.float32),
+                 _stats_shape(n_blocks)]
     if save_residuals:
         for w in weights:                                   # qs
             out_specs.append(pl.BlockSpec((bm, w.shape[0]),
@@ -215,7 +358,7 @@ def fxp_mlp_pallas(phase: Array, x: Array, weights: Sequence[Array],
         kern,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(phase, *args)
 
@@ -246,54 +389,19 @@ def _mlp_bwd_kernel(phase_ref, *refs, n_layers: int,
     dw_refs = refs[5 + 3 * n_layers:5 + 4 * n_layers]
     db_refs = refs[5 + 4 * n_layers:5 + 5 * n_layers]
 
-    i = pl.program_id(0)
-    quant = phase_ref[0] > 0
-
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _zero_accumulators():
         for li in range(n_layers):
             dw_refs[li][...] = jnp.zeros_like(dw_refs[li])
             db_refs[li][...] = jnp.zeros_like(db_refs[li])
 
-    g = g_ref[...]
-    for li in reversed(range(n_layers)):
-        # ---- activation backward from the saved post-activation output ----
-        h = h_refs[li][...]
-        actn = activations[li]
-        if actn == "relu":
-            g = jnp.where(h > 0.0, g, 0.0)
-        elif actn == "tanh":
-            g = g * (1.0 - h * h)
-
-        # ---- parameter gradients (accumulated across batch blocks) --------
-        db_refs[li][...] += jnp.sum(g, axis=0, keepdims=True)
-        q = q_refs[li][...]
-        dw_refs[li][...] += jax.lax.dot_general(
-            q, g, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-        # ---- dense input gradient: g @ W^T --------------------------------
-        g = jax.lax.dot_general(
-            g, w_refs[li][...], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-        # ---- quantize-site backward: STE clip mask on the site input ------
-        if qat:
-            x_in = x0_ref[...] if li == 0 else h_refs[li - 1][...]
-            delta = deltas_ref[li]
-            z = zs_ref[li]
-            lo = -z * delta
-            hi = (jnp.float32((1 << n_bits) - 1) - z) * delta
-            pass_q = jnp.logical_and(x_in >= lo, x_in <= hi)
-            if fxp32_phase1:
-                s32 = jnp.float32(2.0 ** FXP32.frac_bits)
-                xs = x_in * s32
-                pass_f = jnp.logical_and(xs >= jnp.float32(FXP32.raw_min),
-                                         xs <= jnp.float32(FXP32.raw_max))
-            else:
-                pass_f = jnp.ones_like(pass_q)
-            g = jnp.where(jnp.where(quant, pass_q, pass_f), g, 0.0)
-    dx_ref[...] = g
+    sites = _Sites(phase_ref[0] > 0, deltas_ref, zs_ref, qat=qat,
+                   n_bits=n_bits, fxp32_phase1=fxp32_phase1)
+    hs = [r[...] for r in h_refs]
+    ss = [x0_ref[...]] + hs[:-1]   # each site's input: the previous output
+    dx_ref[...] = _net_bwd(g_ref[...], w_refs, ss,
+                           [r[...] for r in q_refs], hs, activations, sites,
+                           0, dw_refs, db_refs)
 
 
 def fxp_mlp_bwd_pallas(phase: Array, g: Array, x0: Array,
@@ -359,7 +467,7 @@ def fxp_mlp_bwd_pallas(phase: Array, g: Array, x0: Array,
         kern,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(phase, *args)
     dx = outs[0]
@@ -373,79 +481,21 @@ def fxp_mlp_bwd_pallas(phase: Array, g: Array, x0: Array,
 # ---------------------------------------------------------------------------
 
 
-def _monitor_minmax(x, in_dim: int, row_ok):
-    """Padding-masked (min, max) of a site input block — the same masking
-    `_mlp_kernel`'s inline monitor uses."""
-    col_idx = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    valid = jnp.logical_and(row_ok, col_idx < in_dim)
-    return (jnp.min(jnp.where(valid, x, jnp.inf)),
-            jnp.max(jnp.where(valid, x, -jnp.inf)))
+def _append_lanes(x, a, offset: int):
+    """In-kernel lane concat [x, a]: a's leading lanes moved to start at
+    lane `offset`.  Exact when x is zero from `offset` on and a is zero past
+    its own width (the padding contract), so the critic's first layer sees
+    the same (bm, 128) input — and runs the same dot — as the host-side
+    concat of the custom-VJP path."""
+    return x + pltpu.roll(a, offset, 1)
 
 
-def _act_fwd(out, actn: str):
-    if actn == "relu":
-        return jnp.maximum(out, 0.0)
-    if actn == "tanh":
-        return jnp.tanh(out)
-    return out
-
-
-def _act_bwd(g, h, actn: str):
-    """Activation backward from the saved post-activation output — same
-    forms as `_mlp_bwd_kernel`."""
-    if actn == "relu":
-        return jnp.where(h > 0.0, g, 0.0)
-    if actn == "tanh":
-        return g * (1.0 - h * h)
-    return g
-
-
-def _ste_site_mask(g, x_in, quant, delta, z, *, n_bits: int,
-                   fxp32_phase1: bool):
-    """Quantize-site backward: the STE clip mask `_mlp_bwd_kernel` applies,
-    factored out so the fused training-step kernels share it."""
-    lo = -z * delta
-    hi = (jnp.float32((1 << n_bits) - 1) - z) * delta
-    pass_q = jnp.logical_and(x_in >= lo, x_in <= hi)
-    if fxp32_phase1:
-        s32 = jnp.float32(2.0 ** FXP32.frac_bits)
-        xs = x_in * s32
-        pass_f = jnp.logical_and(xs >= jnp.float32(FXP32.raw_min),
-                                 xs <= jnp.float32(FXP32.raw_max))
-    else:
-        pass_f = jnp.ones_like(pass_q)
-    return jnp.where(jnp.where(quant, pass_q, pass_f), g, 0.0)
-
-
-def _dense_fwd(x_parts, w_refs, b_ref, acc_ref, *, actn: str, quant):
-    """Dual-precision dense over one or more lane-aligned input segments.
-
-    With one segment this is exactly `_mlp_kernel`'s datapath (hi-limb dot
-    always, lo-limb dot predicated off in the quantized phase).  With two
-    segments the first layer's weight has been split host-side by input rows
-    (obs rows / action rows) so a kernel-computed action block can feed the
-    critic without an unaligned lane concat; the split dots accumulate into
-    the same f32 scratch.  Returns (per-segment effective dense inputs, the
-    post-activation output block).
-    """
-    n_out_p = w_refs[0].shape[1]
-    his, q_effs = [], []
-    for j, (x, w_ref) in enumerate(zip(x_parts, w_refs)):
-        hi_l = x.astype(jnp.bfloat16).astype(jnp.float32)
-        his.append(hi_l)
-        q_effs.append(jnp.where(quant, hi_l, x))
-        d = jnp.dot(hi_l, w_ref[...], preferred_element_type=jnp.float32)
-        if j == 0:
-            acc_ref[:, :n_out_p] = d
-        else:
-            acc_ref[:, :n_out_p] += d
-
-    def _lo_pass():
-        for x, hi_l, w_ref in zip(x_parts, his, w_refs):
-            acc_ref[:, :n_out_p] += jnp.dot(
-                x - hi_l, w_ref[...], preferred_element_type=jnp.float32)
-    pl.when(jnp.logical_not(quant))(_lo_pass)
-    return q_effs, _act_fwd(acc_ref[:, :n_out_p] + b_ref[...], actn)
+def _take_lanes(g, offset: int, width: int):
+    """Inverse of `_append_lanes`: lanes offset..offset+width-1 of g moved
+    to lanes 0..width-1, every other lane zero."""
+    lanes = g.shape[1]
+    col = jax.lax.broadcasted_iota(jnp.int32, g.shape, 1)
+    return jnp.where(col < width, pltpu.roll(g, lanes - offset, 1), 0.0)
 
 
 def _adam_soft_epilogue(hyper_ref, p_ref, g, m_ref, v_ref, t_ref,
@@ -478,22 +528,33 @@ def _adam_soft_epilogue(hyper_ref, p_ref, g, m_ref, v_ref, t_ref,
                       + hyper_ref[_H_TAU] * p2)
 
 
+def _step_epilogue(hyper_ref, p_wb, dw_refs, db_refs, m_wb, v_wb, t_wb,
+                   outs, *, fxp_weights: bool):
+    """Adam + target soft update over every leaf of one network, from the
+    grads accumulated across all batch blocks."""
+    out_p, out_m, out_v, out_t = outs
+    grads = [r[...] for pair in zip(dw_refs, db_refs) for r in pair]
+    for k, g in enumerate(grads):   # interleaved (w0, b0, w1, b1, ...)
+        _adam_soft_epilogue(hyper_ref, p_wb[k], g, m_wb[k], v_wb[k], t_wb[k],
+                            out_p[k], out_m[k], out_v[k], out_t[k],
+                            fxp_weights=fxp_weights)
+
+
 def _ddpg_critic_step_kernel(phase_ref, *refs, n_layers: int, bm: int,
-                             m_valid: int, actor_acts, critic_acts,
-                             critic_in_dims, n_bits: int, qat: bool,
-                             fxp32_phase1: bool, fxp_weights: bool,
-                             n_blocks: int):
+                             m_valid: int, obs_dim: int, actor_acts,
+                             critic_acts, critic_in_dims, n_bits: int,
+                             qat: bool, fxp32_phase1: bool,
+                             fxp_weights: bool, n_blocks: int):
     """Launch 1 of the fused DDPG step: the whole critic BP/WU.
 
     Per batch block: target-actor fwd on next_obs (no monitors — the host
-    update discards target-pass observations), target-critic fwd (first
-    layer split into obs/action row halves so the in-kernel next_a feeds it
-    lane-aligned), TD target y, online-critic fwd with range monitors and
-    VMEM-local residuals, the weighted-MSE cotangent, and the full dx/dW/db
-    backward chain with dW/db accumulated in VMEM scratch across blocks
-    ("arbitrary" grid).  On the LAST block the epilogue runs Adam over the
-    accumulated grads and soft-updates the target critic — params never
-    leave the launch between BP and WU.
+    update discards target-pass observations), target-critic fwd on the
+    in-kernel concat (next_obs, next_a), TD target y, online-critic fwd with
+    range monitors and VMEM-local residuals, the weighted-MSE cotangent, and
+    the full dx/dW/db backward chain with dW/db accumulated in VMEM scratch
+    across blocks ("arbitrary" grid).  On the LAST block the epilogue runs
+    Adam over the accumulated grads and soft-updates the target critic —
+    params never leave the launch between BP and WU.
     """
     L = n_layers
     pos = 0
@@ -506,27 +567,21 @@ def _ddpg_critic_step_kernel(phase_ref, *refs, n_layers: int, bm: int,
 
     xc_ref, nobs_ref, aux_ref = take(3)
     at_wb = take(2 * L)
-    tw0_obs_ref, tw0_act_ref, tb0_ref = take(3)
-    ct_hi = take(2 * (L - 1))            # target critic layers 1..L-1
-    ct_w0_full_ref, = take(1)            # unsplit w0, soft-update operand
+    ct_wb = take(2 * L)
     c_wb = take(2 * L)
     m_wb = take(2 * L)
     v_wb = take(2 * L)
     deltas_ref, zs_ref, hyper_ref = take(3)
-    out_p = take(2 * L)
-    out_m = take(2 * L)
-    out_v = take(2 * L)
-    out_t = take(2 * L)
-    mins_ref, maxs_ref, part_ref = take(3)
+    outs = [take(2 * L) for _ in range(4)]   # params, m, v, targets
+    stats_ref, = take(1)
     acc_ref, = take(1)
     dw_refs = take(L)
     db_refs = take(L)
     assert pos == len(refs)
 
     i = pl.program_id(0)
-    quant = phase_ref[0] > 0
-    row_idx = jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
-    row_ok = (i * bm + row_idx) < m_valid
+    sites = _Sites(phase_ref[0] > 0, deltas_ref, zs_ref, qat=qat,
+                   n_bits=n_bits, fxp32_phase1=fxp32_phase1)
 
     @pl.when(i == 0)
     def _zero_accumulators():
@@ -534,62 +589,28 @@ def _ddpg_critic_step_kernel(phase_ref, *refs, n_layers: int, bm: int,
             dw_refs[li][...] = jnp.zeros_like(dw_refs[li])
             db_refs[li][...] = jnp.zeros_like(db_refs[li])
 
-    xc = xc_ref[...]
     nobs = nobs_ref[...]
     reward = aux_ref[:, 0:1]
     done = aux_ref[:, 1:2]
     w = aux_ref[:, 2:3]
 
-    # ---- target actor forward on next_obs (observations discarded) --------
-    x = nobs
-    for li in range(L):
-        if qat:
-            x = _site_project(x, quant, deltas_ref[li], zs_ref[li],
-                              n_bits=n_bits, fxp32_phase1=fxp32_phase1)
-        _, x = _dense_fwd([x], [at_wb[2 * li]], at_wb[2 * li + 1], acc_ref,
-                          actn=actor_acts[li], quant=quant)
-    next_a = x   # (bm, 128); columns >= act_dim are exactly zero
-
-    # ---- target critic forward: split first layer, then the chain ---------
-    if qat:
-        nobs_q = _site_project(nobs, quant, deltas_ref[L], zs_ref[L],
-                               n_bits=n_bits, fxp32_phase1=fxp32_phase1)
-        na_q = _site_project(next_a, quant, deltas_ref[L], zs_ref[L],
-                             n_bits=n_bits, fxp32_phase1=fxp32_phase1)
-    else:
-        nobs_q, na_q = nobs, next_a
-    _, x = _dense_fwd([nobs_q, na_q], [tw0_obs_ref, tw0_act_ref], tb0_ref,
-                      acc_ref, actn=critic_acts[0], quant=quant)
-    for li in range(1, L):
-        if qat:
-            x = _site_project(x, quant, deltas_ref[L + li], zs_ref[L + li],
-                              n_bits=n_bits, fxp32_phase1=fxp32_phase1)
-        _, x = _dense_fwd([x], [ct_hi[2 * (li - 1)]], ct_hi[2 * (li - 1) + 1],
-                          acc_ref, actn=critic_acts[li], quant=quant)
-    q_next = x[:, 0:1]
-    y = reward + (hyper_ref[_H_GAMMA] * (1.0 - done)) * q_next
+    # ---- targets: target actor on next_obs, target critic on the concat ---
+    next_a, *_ = _net_fwd(nobs, at_wb, acc_ref, actor_acts, sites, 0)
+    q_next, *_ = _net_fwd(_append_lanes(nobs, next_a, obs_dim), ct_wb,
+                          acc_ref, critic_acts, sites, L)
+    y = reward + (hyper_ref[_H_GAMMA] * (1.0 - done)) * q_next[:, 0:1]
 
     # ---- online critic forward: monitors + VMEM-local residuals -----------
-    ss, qeffs, hs = [], [], []
-    x = xc
-    for li in range(L):
-        mn, mx = _monitor_minmax(x, critic_in_dims[li], row_ok)
-        mins_ref[0, li] = mn
-        maxs_ref[0, li] = mx
-        ss.append(x)
-        if qat:
-            x = _site_project(x, quant, deltas_ref[L + li], zs_ref[L + li],
-                              n_bits=n_bits, fxp32_phase1=fxp32_phase1)
-        qe, x = _dense_fwd([x], [c_wb[2 * li]], c_wb[2 * li + 1], acc_ref,
-                           actn=critic_acts[li], quant=quant)
-        qeffs.append(qe[0])
-        hs.append(x)
-    q = x[:, 0:1]
+    q, ss, qeffs, hs, mins, maxs = _net_fwd(
+        xc_ref[...], c_wb, acc_ref, critic_acts, sites, L,
+        monitor=(critic_in_dims, i * bm, m_valid))
+    q = q[:, 0:1]
 
     # ---- loss partials (host divides by sum(w) once) ----------------------
     diff = q - y
-    part_ref[0, 0] = jnp.sum(w * (diff * diff))   # sum w * (q - y)^2
-    part_ref[0, 1] = jnp.sum(w * y)               # sum w * y  (q_mean)
+    parts = [jnp.sum(w * (diff * diff)),   # sum w * (q - y)^2
+             jnp.sum(w * y)]               # sum w * y  (q_mean)
+    stats_ref[...] = _stats_tile([mins, maxs, parts])
 
     # ---- backward: weighted-mean MSE cotangent, then the dW/db/dx chain ---
     # d closs / dq = (w / sum_w) * 2 (q - y) — exactly XLA's transpose of
@@ -598,35 +619,14 @@ def _ddpg_critic_step_kernel(phase_ref, *refs, n_layers: int, bm: int,
     dval = (hyper_ref[_H_INVW] * w) * (2.0 * diff)
     col_l = jax.lax.broadcasted_iota(jnp.int32, hs[-1].shape, 1)
     g = jnp.where(col_l == 0, dval, 0.0)
-    for li in range(L - 1, -1, -1):
-        g = _act_bwd(g, hs[li], critic_acts[li])
-        db_refs[li][...] += jnp.sum(g, axis=0, keepdims=True)
-        dw_refs[li][...] += jax.lax.dot_general(
-            qeffs[li], g, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        g = jax.lax.dot_general(
-            g, c_wb[2 * li][...], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if qat:
-            g = _ste_site_mask(g, ss[li], quant, deltas_ref[L + li],
-                               zs_ref[L + li], n_bits=n_bits,
-                               fxp32_phase1=fxp32_phase1)
+    _net_bwd(g, c_wb[0::2], ss, qeffs, hs, critic_acts, sites, L,
+             dw_refs, db_refs)
 
     # ---- epilogue on the last block: Adam + target soft update ------------
     @pl.when(i == n_blocks - 1)
     def _epilogue():
-        for li in range(L):
-            t_w = ct_w0_full_ref if li == 0 else ct_hi[2 * (li - 1)]
-            t_b = tb0_ref if li == 0 else ct_hi[2 * (li - 1) + 1]
-            _adam_soft_epilogue(
-                hyper_ref, c_wb[2 * li], dw_refs[li][...], m_wb[2 * li],
-                v_wb[2 * li], t_w, out_p[2 * li], out_m[2 * li],
-                out_v[2 * li], out_t[2 * li], fxp_weights=fxp_weights)
-            _adam_soft_epilogue(
-                hyper_ref, c_wb[2 * li + 1], db_refs[li][...],
-                m_wb[2 * li + 1], v_wb[2 * li + 1], t_b, out_p[2 * li + 1],
-                out_m[2 * li + 1], out_v[2 * li + 1], out_t[2 * li + 1],
-                fxp_weights=fxp_weights)
+        _step_epilogue(hyper_ref, c_wb, dw_refs, db_refs, m_wb, v_wb, ct_wb,
+                       outs, fxp_weights=fxp_weights)
 
 
 def _ddpg_actor_step_kernel(phase_ref, *refs, n_layers: int, bm: int,
@@ -637,12 +637,12 @@ def _ddpg_actor_step_kernel(phase_ref, *refs, n_layers: int, bm: int,
                             n_blocks: int):
     """Launch 2 of the fused DDPG step: the whole actor BP/WU.
 
-    Actor fwd with monitors/residuals, the UPDATED critic's fwd on
-    (obs, actor(obs)) — first layer split host-side so the in-kernel action
-    feeds it — with critic-site monitors, the policy-gradient cotangent
-    dq = -w/sum_w, a dx-only backward through the critic (STE at its
-    sites), then the actor's dW/db chain accumulated across blocks and the
-    same Adam + soft-update epilogue on the last block.
+    Actor fwd with monitors/residuals, the UPDATED critic's fwd on the
+    in-kernel concat (obs, actor(obs)) with critic-site monitors, the
+    policy-gradient cotangent dq = -w/sum_w, a dx-only backward through the
+    critic (STE at its sites), then the actor's dW/db chain accumulated
+    across blocks and the same Adam + soft-update epilogue on the last
+    block.
     """
     L = n_layers
     pos = 0
@@ -658,23 +658,18 @@ def _ddpg_actor_step_kernel(phase_ref, *refs, n_layers: int, bm: int,
     m_wb = take(2 * L)
     v_wb = take(2 * L)
     at_wb = take(2 * L)                  # actor target (soft-update operand)
-    cw0_obs_ref, cw0_act_ref, cb0_ref = take(3)
-    c_hi = take(2 * (L - 1))             # updated critic layers 1..L-1
+    c_wb = take(2 * L)                   # the critic launch 1 just updated
     deltas_ref, zs_ref, hyper_ref = take(3)
-    out_p = take(2 * L)
-    out_m = take(2 * L)
-    out_v = take(2 * L)
-    out_t = take(2 * L)
-    mins_ref, maxs_ref, part_ref = take(3)
+    outs = [take(2 * L) for _ in range(4)]   # params, m, v, targets
+    stats_ref, = take(1)
     acc_ref, = take(1)
     dw_refs = take(L)
     db_refs = take(L)
     assert pos == len(refs)
 
     i = pl.program_id(0)
-    quant = phase_ref[0] > 0
-    row_idx = jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
-    row_ok = (i * bm + row_idx) < m_valid
+    sites = _Sites(phase_ref[0] > 0, deltas_ref, zs_ref, qat=qat,
+                   n_bits=n_bits, fxp32_phase1=fxp32_phase1)
 
     @pl.when(i == 0)
     def _zero_accumulators():
@@ -685,107 +680,33 @@ def _ddpg_actor_step_kernel(phase_ref, *refs, n_layers: int, bm: int,
     obs = obs_ref[...]
     w = aux_ref[:, 2:3]
 
-    # ---- actor forward: monitors + residuals ------------------------------
-    x = obs
-    a_ss, a_qs, a_hs = [], [], []
-    for li in range(L):
-        mn, mx = _monitor_minmax(x, actor_in_dims[li], row_ok)
-        mins_ref[0, li] = mn
-        maxs_ref[0, li] = mx
-        a_ss.append(x)
-        if qat:
-            x = _site_project(x, quant, deltas_ref[li], zs_ref[li],
-                              n_bits=n_bits, fxp32_phase1=fxp32_phase1)
-        qe, x = _dense_fwd([x], [a_wb[2 * li]], a_wb[2 * li + 1], acc_ref,
-                           actn=actor_acts[li], quant=quant)
-        a_qs.append(qe[0])
-        a_hs.append(x)
-    a = x   # (bm, 128); columns >= act_dim exactly zero
-
-    # ---- updated-critic forward on (obs, a): split first layer ------------
-    # the l0 site monitor sees the concat input: combine the two segments'
-    # masked extrema — identical to one min/max over the concat
-    mn_o, mx_o = _monitor_minmax(obs, obs_dim, row_ok)
-    mn_a, mx_a = _monitor_minmax(a, act_dim, row_ok)
-    mins_ref[0, L] = jnp.minimum(mn_o, mn_a)
-    maxs_ref[0, L] = jnp.maximum(mx_o, mx_a)
-    if qat:
-        obs_q = _site_project(obs, quant, deltas_ref[L], zs_ref[L],
-                              n_bits=n_bits, fxp32_phase1=fxp32_phase1)
-        a_q = _site_project(a, quant, deltas_ref[L], zs_ref[L],
-                            n_bits=n_bits, fxp32_phase1=fxp32_phase1)
-    else:
-        obs_q, a_q = obs, a
-    c_ss = [None]   # l0's site backward runs on the action segment directly
-    c_hs = []
-    _, x = _dense_fwd([obs_q, a_q], [cw0_obs_ref, cw0_act_ref], cb0_ref,
-                      acc_ref, actn=critic_acts[0], quant=quant)
-    c_hs.append(x)
-    for li in range(1, L):
-        mn, mx = _monitor_minmax(x, critic_in_dims[li], row_ok)
-        mins_ref[0, L + li] = mn
-        maxs_ref[0, L + li] = mx
-        c_ss.append(x)
-        if qat:
-            x = _site_project(x, quant, deltas_ref[L + li], zs_ref[L + li],
-                              n_bits=n_bits, fxp32_phase1=fxp32_phase1)
-        _, x = _dense_fwd([x], [c_hi[2 * (li - 1)]], c_hi[2 * (li - 1) + 1],
-                          acc_ref, actn=critic_acts[li], quant=quant)
-        c_hs.append(x)
-    q = x[:, 0:1]
-    part_ref[0, 0] = jnp.sum(w * q)   # aloss = -(sum w q) / sum_w, on host
+    # ---- actor forward, then the updated critic on (obs, a) ---------------
+    a, a_ss, a_qs, a_hs, mins, maxs = _net_fwd(
+        obs, a_wb, acc_ref, actor_acts, sites, 0,
+        monitor=(actor_in_dims, i * bm, m_valid))
+    q, c_ss, _, c_hs, c_mins, c_maxs = _net_fwd(
+        _append_lanes(obs, a, obs_dim), c_wb, acc_ref, critic_acts, sites, L,
+        monitor=(critic_in_dims, i * bm, m_valid))
+    # aloss = -(sum w q) / sum_w, on host
+    stats_ref[...] = _stats_tile([mins + c_mins, maxs + c_maxs,
+                                  [jnp.sum(w * q[:, 0:1])]])
 
     # ---- backward: policy-gradient cotangent, dx-only through the critic --
     dval = (-hyper_ref[_H_INVW]) * w
     col_l = jax.lax.broadcasted_iota(jnp.int32, c_hs[-1].shape, 1)
     g = jnp.where(col_l == 0, dval, 0.0)
-    for li in range(L - 1, 0, -1):
-        g = _act_bwd(g, c_hs[li], critic_acts[li])
-        g = jax.lax.dot_general(
-            g, c_hi[2 * (li - 1)][...], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if qat:
-            g = _ste_site_mask(g, c_ss[li], quant, deltas_ref[L + li],
-                               zs_ref[L + li], n_bits=n_bits,
-                               fxp32_phase1=fxp32_phase1)
-    g = _act_bwd(g, c_hs[0], critic_acts[0])
-    # da = g @ W0_act^T: exactly the action-column block of the full-concat
-    # dx (padded rows of the split weight are zero, so padded action
-    # columns get exactly zero gradient)
-    g = jax.lax.dot_general(
-        g, cw0_act_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    if qat:
-        g = _ste_site_mask(g, a, quant, deltas_ref[L], zs_ref[L],
-                           n_bits=n_bits, fxp32_phase1=fxp32_phase1)
+    g = _net_bwd(g, c_wb[0::2], c_ss, None, c_hs, critic_acts, sites, L)
+    # da: the action lanes of the concat input's cotangent
+    g = _take_lanes(g, obs_dim, act_dim)
 
     # ---- actor backward with dW/db accumulation ---------------------------
-    for li in range(L - 1, -1, -1):
-        g = _act_bwd(g, a_hs[li], actor_acts[li])
-        db_refs[li][...] += jnp.sum(g, axis=0, keepdims=True)
-        dw_refs[li][...] += jax.lax.dot_general(
-            a_qs[li], g, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        g = jax.lax.dot_general(
-            g, a_wb[2 * li][...], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if qat:
-            g = _ste_site_mask(g, a_ss[li], quant, deltas_ref[li],
-                               zs_ref[li], n_bits=n_bits,
-                               fxp32_phase1=fxp32_phase1)
+    _net_bwd(g, a_wb[0::2], a_ss, a_qs, a_hs, actor_acts, sites, 0,
+             dw_refs, db_refs)
 
     @pl.when(i == n_blocks - 1)
     def _epilogue():
-        for li in range(L):
-            _adam_soft_epilogue(
-                hyper_ref, a_wb[2 * li], dw_refs[li][...], m_wb[2 * li],
-                v_wb[2 * li], at_wb[2 * li], out_p[2 * li], out_m[2 * li],
-                out_v[2 * li], out_t[2 * li], fxp_weights=fxp_weights)
-            _adam_soft_epilogue(
-                hyper_ref, a_wb[2 * li + 1], db_refs[li][...],
-                m_wb[2 * li + 1], v_wb[2 * li + 1], at_wb[2 * li + 1],
-                out_p[2 * li + 1], out_m[2 * li + 1], out_v[2 * li + 1],
-                out_t[2 * li + 1], fxp_weights=fxp_weights)
+        _step_epilogue(hyper_ref, a_wb, dw_refs, db_refs, m_wb, v_wb, at_wb,
+                       outs, fxp_weights=fxp_weights)
 
 
 def _const_spec(a):
@@ -796,159 +717,98 @@ def _batch_spec(bm, a):
     return pl.BlockSpec((bm, a.shape[1]), lambda i, ph: (i, 0))
 
 
-def ddpg_critic_step_pallas(phase, xc, nobs, aux, at_wb, tw0_obs, tw0_act,
-                            tb0, ct_hi, ct_w0_full, c_wb, m_wb, v_wb,
-                            deltas, zs, hyper, *, actor_acts, critic_acts,
-                            critic_in_dims, m_valid: int, bm: int,
-                            n_bits: int, qat: bool, fxp32_phase1: bool,
-                            fxp_weights: bool, interpret: bool):
+def _step_call(kern, phase, batch, consts, smem, p_wb, *, bm: int,
+               interpret: bool):
+    """The pallas_call shared by both step launches: batch arrays blocked
+    by row, every parameter leaf VMEM-resident (constant index map), the
+    SMEM scalars, and as outputs the new params, moments and targets of
+    the trained net plus the per-block stats tiles."""
+    n_blocks = batch[0].shape[0] // bm
+    max_np = max(a.shape[1] for a in consts)
+    args = [*batch, *consts, *smem]
+    in_specs = ([_batch_spec(bm, a) for a in batch]
+                + [_const_spec(a) for a in consts]
+                + [pl.BlockSpec(memory_space=pltpu.SMEM)] * len(smem))
+    out_specs = [_const_spec(a) for _ in range(4) for a in p_wb]
+    out_shape = [jax.ShapeDtypeStruct(a.shape, jnp.float32)
+                 for _ in range(4) for a in p_wb]
+    out_specs.append(_stats_spec())
+    out_shape.append(_stats_shape(n_blocks))
+    ws = p_wb[0::2]
+    scratch = ([pltpu.VMEM((bm, max_np), jnp.float32)]
+               + [pltpu.VMEM(w.shape, jnp.float32) for w in ws]
+               + [pltpu.VMEM((1, w.shape[1]), jnp.float32) for w in ws])
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(n_blocks,),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        scratch_shapes=scratch,
+    )
+    outs = pl.pallas_call(
+        functools.partial(kern, n_blocks=n_blocks),
+        grid_spec=grid_spec,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(phase, *args)
+    n = len(p_wb)
+    return ([list(outs[k * n:(k + 1) * n]) for k in range(4)]
+            + [outs[4 * n]])
+
+
+def ddpg_critic_step_pallas(phase, xc, nobs, aux, at_wb, ct_wb, c_wb, m_wb,
+                            v_wb, deltas, zs, hyper, *, obs_dim: int,
+                            actor_acts, critic_acts, critic_in_dims,
+                            m_valid: int, bm: int, n_bits: int, qat: bool,
+                            fxp32_phase1: bool, fxp_weights: bool,
+                            interpret: bool):
     """Launch 1 pallas_call: fused critic fwd+bwd+Adam+soft-update.
 
     All shapes pre-padded.  xc (Mp, 128) concat(obs, act); nobs (Mp, 128);
-    aux (Mp, 128) with [reward, done, w] in cols 0..2.  at_wb / c_wb /
-    m_wb / v_wb: interleaved (w0, b0, w1, b1, ...) padded leaves.  tw0_obs /
-    tw0_act: the target critic's first-layer weight split by input rows
-    (obs rows / action rows, each padded to the lane-aligned xc layout);
-    ct_w0_full is the same weight unsplit — the soft-update operand.
+    aux (Mp, 128) with [reward, done, w] in cols 0..2.  at_wb / ct_wb /
+    c_wb / m_wb / v_wb: interleaved (w0, b0, w1, b1, ...) padded leaves of
+    the target actor, target critic, critic and its Adam moments.
     deltas/zs: (2L,) f32 SMEM (actor sites then critic sites); hyper:
     (HYPER_LEN,) f32 SMEM (see the layout constants above).
 
-    Returns (new_c_wb, new_m_wb, new_v_wb, new_ct_wb, mins, maxs, partials)
-    with mins/maxs (n_blocks, L) critic-site extrema and partials
-    (n_blocks, 2) = per-block [sum w*(q-y)^2, sum w*y].
+    Returns (new_c_wb, new_m_wb, new_v_wb, new_ct_wb, stats): the stats
+    tiles hold the L critic-site extrema and the partials
+    [sum w*(q-y)^2, sum w*y] (`block_stats(stats, L, 2)`).
     """
-    L = len(c_wb) // 2
-    mp = xc.shape[0]
-    n_blocks = mp // bm
-    max_np = max(w.shape[1] for w in c_wb[0::2])
-
-    args, in_specs = [], []
-    for a in (xc, nobs, aux):
-        args.append(a)
-        in_specs.append(_batch_spec(bm, a))
-    for a in (*at_wb, tw0_obs, tw0_act, tb0, *ct_hi, ct_w0_full,
-              *c_wb, *m_wb, *v_wb):
-        args.append(a)
-        in_specs.append(_const_spec(a))
-    for a in (deltas, zs, hyper):
-        args.append(a)
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-
-    out_specs, out_shape = [], []
-    for _ in range(4):                       # out_p, out_m, out_v, out_t
-        for a in c_wb:
-            out_specs.append(_const_spec(a))
-            out_shape.append(jax.ShapeDtypeStruct(a.shape, jnp.float32))
-    for width in (L, L, 2):                  # mins, maxs, partials
-        out_specs.append(pl.BlockSpec((1, width), lambda i, ph: (i, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((n_blocks, width),
-                                              jnp.float32))
-
-    scratch = [pltpu.VMEM((bm, max_np), jnp.float32)]
-    scratch += [pltpu.VMEM(w.shape, jnp.float32) for w in c_wb[0::2]]
-    scratch += [pltpu.VMEM((1, w.shape[1]), jnp.float32)
-                for w in c_wb[0::2]]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_blocks,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=scratch,
-    )
     kern = functools.partial(
-        _ddpg_critic_step_kernel, n_layers=L, bm=bm, m_valid=m_valid,
-        actor_acts=tuple(actor_acts), critic_acts=tuple(critic_acts),
-        critic_in_dims=tuple(critic_in_dims), n_bits=n_bits, qat=qat,
-        fxp32_phase1=fxp32_phase1, fxp_weights=fxp_weights,
-        n_blocks=n_blocks)
-    outs = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(phase, *args)
-    new_p = list(outs[0:2 * L])
-    new_m = list(outs[2 * L:4 * L])
-    new_v = list(outs[4 * L:6 * L])
-    new_t = list(outs[6 * L:8 * L])
-    mins, maxs, part = outs[8 * L:8 * L + 3]
-    return new_p, new_m, new_v, new_t, mins, maxs, part
+        _ddpg_critic_step_kernel, n_layers=len(c_wb) // 2, bm=bm,
+        m_valid=m_valid, obs_dim=obs_dim, actor_acts=tuple(actor_acts),
+        critic_acts=tuple(critic_acts), critic_in_dims=tuple(critic_in_dims),
+        n_bits=n_bits, qat=qat, fxp32_phase1=fxp32_phase1,
+        fxp_weights=fxp_weights)
+    return _step_call(kern, phase, (xc, nobs, aux),
+                      (*at_wb, *ct_wb, *c_wb, *m_wb, *v_wb),
+                      (deltas, zs, hyper), c_wb, bm=bm, interpret=interpret)
 
 
-def ddpg_actor_step_pallas(phase, obs, aux, a_wb, m_wb, v_wb, at_wb,
-                           cw0_obs, cw0_act, cb0, c_hi, deltas, zs, hyper,
-                           *, obs_dim: int, act_dim: int, actor_acts,
-                           critic_acts, actor_in_dims, critic_in_dims,
-                           m_valid: int, bm: int, n_bits: int, qat: bool,
-                           fxp32_phase1: bool, fxp_weights: bool,
-                           interpret: bool):
+def ddpg_actor_step_pallas(phase, obs, aux, a_wb, m_wb, v_wb, at_wb, c_wb,
+                           deltas, zs, hyper, *, obs_dim: int, act_dim: int,
+                           actor_acts, critic_acts, actor_in_dims,
+                           critic_in_dims, m_valid: int, bm: int,
+                           n_bits: int, qat: bool, fxp32_phase1: bool,
+                           fxp_weights: bool, interpret: bool):
     """Launch 2 pallas_call: fused actor fwd+bwd+Adam+soft-update through
-    the freshly updated critic (cw0_obs/cw0_act/cb0/c_hi are launch 1's
-    outputs, first layer re-split host-side by obs/action input rows).
+    the freshly updated critic (c_wb: launch 1's new params).
 
-    Returns (new_a_wb, new_m_wb, new_v_wb, new_at_wb, mins, maxs, partials)
-    with mins/maxs (n_blocks, 2L): cols 0..L-1 actor sites, L..2L-1 the
-    critic sites as seen by the actor-loss pass; partials (n_blocks, 1)
-    = per-block sum w*q.
+    Returns (new_a_wb, new_m_wb, new_v_wb, new_at_wb, stats): the stats
+    tiles hold 2L site extrema (lanes 0..L-1 actor sites, L..2L-1 the
+    critic sites as seen by the actor-loss pass) and the partial sum w*q
+    (`block_stats(stats, 2 * L, 1)`).
     """
-    L = len(a_wb) // 2
-    mp = obs.shape[0]
-    n_blocks = mp // bm
-    max_np = max(w.shape[1] for w in (*a_wb[0::2], cw0_obs, *c_hi[0::2]))
-
-    args, in_specs = [], []
-    for a in (obs, aux):
-        args.append(a)
-        in_specs.append(_batch_spec(bm, a))
-    for a in (*a_wb, *m_wb, *v_wb, *at_wb, cw0_obs, cw0_act, cb0, *c_hi):
-        args.append(a)
-        in_specs.append(_const_spec(a))
-    for a in (deltas, zs, hyper):
-        args.append(a)
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-
-    out_specs, out_shape = [], []
-    for _ in range(4):                       # out_p, out_m, out_v, out_t
-        for a in a_wb:
-            out_specs.append(_const_spec(a))
-            out_shape.append(jax.ShapeDtypeStruct(a.shape, jnp.float32))
-    for width in (2 * L, 2 * L, 1):          # mins, maxs, partials
-        out_specs.append(pl.BlockSpec((1, width), lambda i, ph: (i, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((n_blocks, width),
-                                              jnp.float32))
-
-    scratch = [pltpu.VMEM((bm, max_np), jnp.float32)]
-    scratch += [pltpu.VMEM(w.shape, jnp.float32) for w in a_wb[0::2]]
-    scratch += [pltpu.VMEM((1, w.shape[1]), jnp.float32)
-                for w in a_wb[0::2]]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_blocks,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=scratch,
-    )
     kern = functools.partial(
-        _ddpg_actor_step_kernel, n_layers=L, bm=bm, m_valid=m_valid,
-        obs_dim=obs_dim, act_dim=act_dim, actor_acts=tuple(actor_acts),
-        critic_acts=tuple(critic_acts),
+        _ddpg_actor_step_kernel, n_layers=len(a_wb) // 2, bm=bm,
+        m_valid=m_valid, obs_dim=obs_dim, act_dim=act_dim,
+        actor_acts=tuple(actor_acts), critic_acts=tuple(critic_acts),
         actor_in_dims=tuple(actor_in_dims),
         critic_in_dims=tuple(critic_in_dims), n_bits=n_bits, qat=qat,
-        fxp32_phase1=fxp32_phase1, fxp_weights=fxp_weights,
-        n_blocks=n_blocks)
-    outs = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(phase, *args)
-    new_p = list(outs[0:2 * L])
-    new_m = list(outs[2 * L:4 * L])
-    new_v = list(outs[4 * L:6 * L])
-    new_t = list(outs[6 * L:8 * L])
-    mins, maxs, part = outs[8 * L:8 * L + 3]
-    return new_p, new_m, new_v, new_t, mins, maxs, part
+        fxp32_phase1=fxp32_phase1, fxp_weights=fxp_weights)
+    return _step_call(kern, phase, (obs, aux),
+                      (*a_wb, *m_wb, *v_wb, *at_wb, *c_wb),
+                      (deltas, zs, hyper), a_wb, bm=bm, interpret=interpret)

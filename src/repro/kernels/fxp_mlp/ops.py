@@ -23,8 +23,10 @@ from typing import NamedTuple, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.kernels._compat import mlp_flops, round_up as _round_up
-from repro.kernels.fxp_mlp.kernel import fxp_mlp_bwd_pallas, fxp_mlp_pallas
+from repro.kernels._compat import interpret_mode, mlp_flops, round_up as _round_up
+from repro.kernels.fxp_mlp.kernel import (
+    HYPER_LEN, block_stats, ddpg_actor_step_pallas, ddpg_critic_step_pallas,
+    fxp_mlp_bwd_pallas, fxp_mlp_pallas)
 
 Array = jax.Array
 
@@ -99,7 +101,7 @@ def fxp_mlp_forward(x: Array, weights: tuple, biases: tuple,
         f"{n_layers} weights vs {len(biases)} biases vs "
         f"{len(activations)} activations")
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
 
     orig_shape = x.shape
     n_out = weights[-1].shape[-1]
@@ -109,14 +111,15 @@ def fxp_mlp_forward(x: Array, weights: tuple, biases: tuple,
     deltas, zs = _norm_quant_params(deltas, zs, n_layers, qat)
     phase = jnp.asarray(quant_phase, jnp.int32).reshape(1)
 
-    y, mins, maxs = fxp_mlp_pallas(
+    y, stats = fxp_mlp_pallas(
         phase, x2, wp, bp, deltas, zs,
         activations=tuple(activations), in_dims=in_dims, m_valid=m, bm=bm,
         n_bits=n_bits, qat=qat, fxp32_phase1=fxp32_phase1,
         interpret=interpret)
 
     y = y[:m, :n_out].reshape(*orig_shape[:-1], n_out)
-    return y, jnp.min(mins, axis=0), jnp.max(maxs, axis=0)
+    mins, maxs, _ = block_stats(stats, n_layers)
+    return y, mins, maxs
 
 
 class _TrainSpec(NamedTuple):
@@ -140,12 +143,11 @@ def _train_fwd_call(spec: _TrainSpec, phase_f, x, weights, biases,
         m_valid=m, bm=bm, n_bits=spec.n_bits, qat=spec.qat,
         fxp32_phase1=spec.fxp32_phase1, interpret=spec.interpret,
         save_residuals=save_residuals)
-    yp, mins, maxs = outs[:3]
+    yp, stats = outs[:2]
     n_out = spec.dims[-1]
     y = yp[:m, :n_out].reshape(*x.shape[:-1], n_out)
-    site_mins = jnp.min(mins, axis=0)
-    site_maxs = jnp.max(maxs, axis=0)
-    return y, site_mins, site_maxs, yp, x2, wp, outs[3:], m, bm
+    site_mins, site_maxs, _ = block_stats(stats, len(weights))
+    return y, site_mins, site_maxs, yp, x2, wp, outs[2:], m, bm
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -224,7 +226,7 @@ def fxp_mlp_train(x: Array, weights: tuple, biases: tuple,
         f"{n_layers} weights vs {len(biases)} biases vs "
         f"{len(activations)} activations")
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     assert weights[0].shape[0] == x.shape[-1], (
         f"layer-0 input dim {weights[0].shape[0]} != x feature dim "
         f"{x.shape[-1]}")
@@ -312,20 +314,6 @@ def _pad_batch(a: Array, mp: int) -> Array:
                    ((0, mp - b), (0, _round_up(k, 128) - k)))
 
 
-def _split_w0(w0p: Array, obs_dim: int, act_dim: int) -> tuple[Array, Array]:
-    """Split a padded critic first-layer weight by input rows so the kernel
-    can feed it two lane-aligned segments (obs block, action block) instead
-    of one concat: rows >= obs_dim zeroed for the obs half, action rows
-    moved up to rows 0..act_dim-1 for the action half.  dot(obs_seg, W_obs)
-    + dot(act_seg, W_act) == dot(concat, W) by block structure."""
-    row = jax.lax.broadcasted_iota(jnp.int32, w0p.shape, 0)
-    w_obs = jnp.where(row < obs_dim, w0p, 0.0)
-    w_act = jnp.pad(
-        jax.lax.dynamic_slice_in_dim(w0p, obs_dim, act_dim, axis=0),
-        ((0, w0p.shape[0] - act_dim), (0, 0)))
-    return w_obs, w_act
-
-
 class TrainStepOut(NamedTuple):
     """Everything `ddpg._update_fused_step` needs back from the 2 launches."""
 
@@ -372,11 +360,9 @@ def fxp_mlp_train_step(obs, action, reward, done, next_obs, w,
     static floats so their complements fold in double precision, matching
     the host path bit-for-bit.
     """
-    from repro.kernels.fxp_mlp.kernel import (
-        HYPER_LEN, ddpg_actor_step_pallas, ddpg_critic_step_pallas)
     assert HYPER_LEN == 12
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
 
     a_ws, a_bs = actor_wb
     c_ws, c_bs = critic_wb
@@ -406,8 +392,6 @@ def fxp_mlp_train_step(obs, action, reward, done, next_obs, w,
     cm_p = _pad_wb(*critic_m)
     cv_p = _pad_wb(*critic_v)
 
-    tw0_obs, tw0_act = _split_w0(ct_wbp[0], obs_dim, act_dim)
-
     inv_w = 1.0 / jnp.maximum(jnp.sum(w.astype(jnp.float32)), 1.0)
     # (1 - tau) folded in Python double then cast, exactly like the host
     # tree.map soft update's weak-typed constant
@@ -423,25 +407,26 @@ def fxp_mlp_train_step(obs, action, reward, done, next_obs, w,
     deltas2, zs2 = _norm_quant_params(deltas, zs, 2 * L, qat)
     phase = jnp.asarray(quant_phase, jnp.int32).reshape(1)
 
-    ncp, ncm, ncv, nct, mins1, maxs1, part1 = ddpg_critic_step_pallas(
-        phase, xc_p, nobs_p, aux_p, at_wbp, tw0_obs, tw0_act, ct_wbp[1],
-        ct_wbp[2:], ct_wbp[0], c_wbp, cm_p, cv_p, deltas2, zs2, hyper_c,
-        actor_acts=actor_acts, critic_acts=critic_acts,
-        critic_in_dims=critic_in_dims, m_valid=b_rows, bm=bm,
-        n_bits=n_bits, qat=qat, fxp32_phase1=fxp32_phase1,
-        fxp_weights=fxp_weights, interpret=interpret)
+    ncp, ncm, ncv, nct, stats1 = ddpg_critic_step_pallas(
+        phase, xc_p, nobs_p, aux_p, at_wbp, ct_wbp, c_wbp, cm_p, cv_p,
+        deltas2, zs2, hyper_c, obs_dim=obs_dim, actor_acts=actor_acts,
+        critic_acts=critic_acts, critic_in_dims=critic_in_dims,
+        m_valid=b_rows, bm=bm, n_bits=n_bits, qat=qat,
+        fxp32_phase1=fxp32_phase1, fxp_weights=fxp_weights,
+        interpret=interpret)
 
-    # launch 2 sees the UPDATED critic (first layer re-split)
-    cw0_obs, cw0_act = _split_w0(ncp[0], obs_dim, act_dim)
-
-    nap, nam, nav, nat, mins2, maxs2, part2 = ddpg_actor_step_pallas(
-        phase, obs_p, aux_p, a_wbp, am_p, av_p, at_wbp, cw0_obs, cw0_act,
-        ncp[1], ncp[2:], deltas2, zs2, hyper_a, obs_dim=obs_dim,
+    # launch 2 sees the UPDATED critic
+    nap, nam, nav, nat, stats2 = ddpg_actor_step_pallas(
+        phase, obs_p, aux_p, a_wbp, am_p, av_p, at_wbp, ncp, deltas2, zs2,
+        hyper_a, obs_dim=obs_dim,
         act_dim=act_dim, actor_acts=actor_acts, critic_acts=critic_acts,
         actor_in_dims=actor_in_dims, critic_in_dims=critic_in_dims,
         m_valid=b_rows, bm=bm, n_bits=n_bits, qat=qat,
         fxp32_phase1=fxp32_phase1, fxp_weights=fxp_weights,
         interpret=interpret)
+
+    c_mins, c_maxs, part1 = block_stats(stats1, L, 2)
+    a_mins, a_maxs, part2 = block_stats(stats2, 2 * L, 1)
 
     def unpad(wbp, ws_ref, bs_ref):
         ws = tuple(wbp[2 * i][:w.shape[0], :w.shape[1]]
@@ -459,11 +444,11 @@ def fxp_mlp_train_step(obs, action, reward, done, next_obs, w,
         actor_v=unpad(nav, a_ws, a_bs),
         critic_m=unpad(ncm, c_ws, c_bs),
         critic_v=unpad(ncv, c_ws, c_bs),
-        closs_sum=jnp.sum(part1[:, 0]),
-        y_sum=jnp.sum(part1[:, 1]),
-        q_sum=jnp.sum(part2[:, 0]),
-        c_mins=jnp.min(mins1, axis=0),
-        c_maxs=jnp.max(maxs1, axis=0),
-        a_mins=jnp.min(mins2, axis=0),
-        a_maxs=jnp.max(maxs2, axis=0),
+        closs_sum=part1[0],
+        y_sum=part1[1],
+        q_sum=part2[0],
+        c_mins=c_mins,
+        c_maxs=c_maxs,
+        a_mins=a_mins,
+        a_maxs=a_maxs,
     )

@@ -5,9 +5,10 @@ the updated running min/max — the software image of the BRAM-side range
 monitor sitting between the accumulator and the activation memory.
 
 Layout: x is reshaped to (R, 128) rows (lane-aligned); the grid walks row
-blocks of 8 sequentially ("arbitrary"), min/max accumulate in SMEM-like
-(1,1) outputs revisited by every step.  Tail padding is masked with the
-running extrema so it never contaminates the ranges.
+blocks of 8 sequentially ("arbitrary"), min/max accumulate in (8, 128)
+output tiles revisited by every step, every entry holding the running
+value (Mosaic stores vectors, not scalars, into VMEM).  Tail padding is
+masked with the running extrema so it never contaminates the ranges.
 """
 from __future__ import annotations
 
@@ -19,8 +20,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.fixedpoint import FXP32
-
-from repro.kernels._compat import CompilerParams
 
 Array = jax.Array
 
@@ -44,15 +43,15 @@ def _mq_kernel(x_ref, amin_ref, amax_ref, phase_ref, nvalid_ref,
 
     @pl.when(i == 0)
     def _init():
-        nmin_ref[0, 0] = amin_ref[0]
-        nmax_ref[0, 0] = amax_ref[0]
+        nmin_ref[...] = jnp.full(nmin_ref.shape, amin_ref[0], jnp.float32)
+        nmax_ref[...] = jnp.full(nmax_ref.shape, amax_ref[0], jnp.float32)
 
     quant = phase_ref[0] > 0
     # freeze monitoring once quantization starts (Algorithm 1)
-    nmin_ref[0, 0] = jnp.where(quant, nmin_ref[0, 0],
-                               jnp.minimum(nmin_ref[0, 0], block_min))
-    nmax_ref[0, 0] = jnp.where(quant, nmax_ref[0, 0],
-                               jnp.maximum(nmax_ref[0, 0], block_max))
+    nmin_ref[...] = jnp.where(quant, nmin_ref[...],
+                              jnp.minimum(nmin_ref[...], block_min))
+    nmax_ref[...] = jnp.where(quant, nmax_ref[...],
+                              jnp.maximum(nmax_ref[...], block_max))
 
     # ---- projection, selected by phase --------------------------------------
     # full phase: Q15.16 lattice
@@ -93,15 +92,15 @@ def monitor_quant_pallas(x2: Array, a_min: Array, a_max: Array,
         ],
         out_specs=[
             pl.BlockSpec((_BR, _BC), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            pl.BlockSpec((_BR, _BC), lambda i: (0, 0)),
+            pl.BlockSpec((_BR, _BC), lambda i: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((r, _BC), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
+            jax.ShapeDtypeStruct((_BR, _BC), jnp.float32),
+            jax.ShapeDtypeStruct((_BR, _BC), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(x2, a_min, a_max, phase, n_valid)

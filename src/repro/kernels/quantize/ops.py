@@ -7,6 +7,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels._compat import interpret_mode
 from repro.kernels.quantize.kernel import _BC, _BR, monitor_quant_pallas
 
 Array = jax.Array
@@ -22,7 +23,7 @@ def monitor_quant(x: Array, a_min: Array, a_max: Array, quant_phase: Array,
     ranges update only while quant_phase is False.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     shape = x.shape
     n = x.size
     flat = x.astype(jnp.float32).reshape(-1)

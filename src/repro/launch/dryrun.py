@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from repro.configs import registry
 from repro.core.parallelism import rules_for
 from repro.launch import specs as S
-from repro.launch.mesh import make_debug_mesh, make_production_mesh, mesh_context
+from repro.launch.mesh import make_debug_mesh, make_production_mesh
 from repro.models.config import ALL_SHAPES, ModelConfig, ShapeConfig
 from repro.optim import adam
 from repro.serve.engine import make_prefill, make_serve_step
@@ -44,14 +44,6 @@ FULL_ATTENTION_ONLY = {"internlm2-1.8b", "qwen2-0.5b", "deepseek-7b",
 ENCODER_ONLY = {"hubert-xlarge"}
 
 
-
-def cost_analysis_dict(compiled) -> dict:
-    """compiled.cost_analysis() across jax versions: 0.4.x returns a list of
-    per-program dicts, newer jax returns the dict directly."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
 
 def skip_reason(cfg: ModelConfig, shape: ShapeConfig) -> str | None:
     if cfg.name in ENCODER_ONLY and shape.kind == "decode":
@@ -152,14 +144,14 @@ def run_cell(arch: str, shape: ShapeConfig, *, multi_pod: bool, qat: bool,
     mesh = (make_debug_mesh(multi_pod=multi_pod) if debug_mesh
             else make_production_mesh(multi_pod=multi_pod))
     t0 = time.time()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         jitted, args = build_cell(cfg, shape, mesh, qat=qat)
         lowered = jitted.lower(*args)
         t1 = time.time()
         compiled = lowered.compile()
         t2 = time.time()
         mem = compiled.memory_analysis()
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis()
         coll = collective_bytes(compiled.as_text())
     n_dev = mesh.devices.size
     rec.update(

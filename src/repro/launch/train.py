@@ -60,12 +60,11 @@ def main(argv=None):
     rules = None
     mesh_ctx = None
     if args.mesh != "none":
-        from repro.launch.mesh import (make_debug_mesh, make_production_mesh,
-                                       mesh_context)
+        from repro.launch.mesh import make_debug_mesh, make_production_mesh
         mesh = (make_debug_mesh() if args.mesh == "debug"
                 else make_production_mesh())
         rules = rules_for(mesh, "train")
-        mesh_ctx = mesh_context(mesh)
+        mesh_ctx = jax.set_mesh(mesh)
         mesh_ctx.__enter__()
 
     opt_cfg = adam.AdamConfig(

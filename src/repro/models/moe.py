@@ -20,14 +20,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core.parallelism import (Logical, ShardingRules, ambient_mesh,
-                                    constrain)
-
-# jax >= 0.6 promotes shard_map to the top level; older releases keep it in
-# jax.experimental.
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
+from repro.core.parallelism import Logical, ShardingRules, constrain
 from repro.models.config import ModelConfig
 from repro.models.layers import LayerQAT, _act, _uniform_init
 
@@ -93,8 +86,8 @@ def moe_forward(x: Array, p: Params, cfg: ModelConfig,
     mesh = None
     if rules is not None:
         try:
-            am = ambient_mesh()
-            if am is not None and not am.empty and "model" in am.axis_names:
+            am = jax.sharding.get_abstract_mesh()
+            if not am.empty and "model" in am.axis_names:
                 mesh = am
         except (ValueError, RuntimeError):
             mesh = None
@@ -281,7 +274,7 @@ def _moe_forward_sharded(x: Array, p: Params, cfg: ModelConfig,
         return y.reshape(xl.shape), aux, h_min, h_max
 
     bspec = P(batch_axes if len(batch_axes) > 1 else batch_axes[0], None, None)
-    y, aux, h_min, h_max = _shard_map(
+    y, aux, h_min, h_max = jax.shard_map(
         body, mesh=mesh,
         in_specs=(bspec, P(None, None), P("model", None, "data"),
                   P("model", None, "data"), P("model", "data", None),

@@ -11,6 +11,7 @@ Request lifecycle::
                                   ONE device call (ddpg.act_batch,
                                   lowered once per (bucket, mode))
                                         │ optional mesh batch-sharding
+                                        │ (shard_map: B/n rows per device)
                                         ▼
                     futures resolve ◀── scatter rows back to requests
 
@@ -90,12 +91,29 @@ class PolicyEngine(StreamEngine):
         self.batcher_config = batcher
         self.mesh = mesh
         self._sharding = NamedSharding(mesh, P("data")) if mesh is not None else None
+        # batches whose bucket does not divide by the mesh size run whole on
+        # one device; counted so a deployment can see its idle devices
+        self.unsharded_batches = 0
         n = len(ddpg.ACTOR_ACTS)
         dims = [int(actor["l0"]["w"].shape[0])]
         dims += [int(actor[f"l{i}"]["w"].shape[1]) for i in range(n)]
-        self._fns = {}
+        self._fns, self._sharded_fns = {}, {}
         for mode in modes:
-            self._fns[mode] = jax.jit(functools.partial(ddpg.act_batch, mode=mode))
+            fn = functools.partial(ddpg.act_batch, mode=mode)
+            self._fns[mode] = jax.jit(fn)
+            if mesh is not None:
+                # the compiler cannot partition a Pallas (Mosaic) kernel, so
+                # each device runs the act on its own B/n rows; weights and
+                # quant params are replicated
+                self._sharded_fns[mode] = jax.jit(
+                    jax.shard_map(
+                        fn,
+                        mesh=mesh,
+                        in_specs=(P(), P("data"), P()),
+                        out_specs=P("data"),
+                        check_vma=False,
+                    )
+                )
         self._qat_probe_fn = None
         self._qat_ranges_recorded = False
         obs = obs if obs is not None else Observability()
@@ -140,9 +158,13 @@ class PolicyEngine(StreamEngine):
         if mode not in self._fns:
             raise ValueError(f"mode {mode!r} not in enabled modes {self.modes}")
         x = jnp.asarray(x_padded)
-        if self._sharding is not None and x.shape[0] % self.mesh.size == 0:
-            x = jax.device_put(x, self._sharding)
-        return self._fns[mode](self.actor, x, self.frozen)
+        if self._sharding is None:
+            return self._fns[mode](self.actor, x, self.frozen)
+        if x.shape[0] % self.mesh.size:
+            self.unsharded_batches += 1
+            return self._fns[mode](self.actor, x, self.frozen)
+        x = jax.device_put(x, self._sharding)
+        return self._sharded_fns[mode](self.actor, x, self.frozen)
 
     def run_batch(self, obs) -> np.ndarray:
         """One engine pass over (n, obs_dim) observations: pad to a bucket,
@@ -243,6 +265,7 @@ class PolicyEngine(StreamEngine):
             "p50_ms": m.latency_ms(0.50),
             "p99_ms": m.latency_ms(0.99),
             "batch_occupancy": m.occupancy(),
+            "unsharded_batches": self.unsharded_batches,
             "mode_histogram": m.mode_histogram(),
             "cost_model": self.cost_model.source,
             "dispatch_audit": self._audit.snapshot(),

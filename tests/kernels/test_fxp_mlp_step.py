@@ -2,9 +2,10 @@
 
 Acceptance contract for the whole-update kernel:
   * tracks the 8-launch `backend="pallas"` path tightly in the monitor
-    phase (the only drift source is the split first-layer critic dot and
-    in-kernel block-summed reductions — ~1 f32 ulp pre-projection, at most
-    one Q15.16 lattice quantum after weight projection);
+    phase: both paths contract the same padded shapes (the in-kernel
+    actions are lane-rotated into the critic's concat input rather than
+    fed through a split first-layer weight, whose two partial dots round
+    differently on XLA:CPU), so the bound is one Q15.16 lattice quantum;
   * ~1e-3 rel tolerance in the quantized phase over multi-step runs (the
     same STE/bf16-hi rationale as the fused-VJP parity pins — in practice
     the lattice re-snap keeps it bit-exact, see the drift test);
@@ -14,7 +15,6 @@ Acceptance contract for the whole-update kernel:
     over 50 steps.
 """
 import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp

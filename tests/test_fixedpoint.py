@@ -83,7 +83,7 @@ def test_fxp_matmul_raw_exact_vs_int64():
     shift = fxp.FXP32.frac_bits
     oracle = np.clip((acc + (1 << (shift - 1))) >> shift,
                      fxp.FXP32.raw_min, fxp.FXP32.raw_max).astype(np.int32)
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         got = np.asarray(fxp.fxp_matmul_raw(
             jnp.asarray(ar, jnp.int32), jnp.asarray(wr, jnp.int32),
             fxp.FXP32, fxp.FXP32, fxp.FXP32))

@@ -48,8 +48,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys; sys.path.insert(0, r"{src}")
 import jax
 from repro.configs import registry
-from repro.launch.dryrun import build_cell, cost_analysis_dict
-from repro.launch.mesh import make_debug_mesh, mesh_context
+from repro.launch.dryrun import build_cell
+from repro.launch.mesh import make_debug_mesh
 from repro.models.config import ShapeConfig
 
 arch, kind = sys.argv[1], sys.argv[2]
@@ -58,10 +58,10 @@ shape = {{"train": ShapeConfig("t", "train", 256, 8),
           "prefill": ShapeConfig("p", "prefill", 512, 4),
           "decode": ShapeConfig("d", "decode", 512, 8)}}[kind]
 mesh = make_debug_mesh(multi_pod=(sys.argv[3] == "multi"))
-with mesh_context(mesh):
+with jax.set_mesh(mesh):
     jitted, args = build_cell(cfg, shape, mesh, qat=True)
     compiled = jitted.lower(*args).compile()
-    print("COMPILED", cost_analysis_dict(compiled).get("flops", 0.0))
+    print("COMPILED", compiled.cost_analysis().get("flops", 0.0))
 """
 
 
