@@ -718,11 +718,13 @@ def _batch_spec(bm, a):
 
 
 def _step_call(kern, phase, batch, consts, smem, p_wb, *, bm: int,
-               interpret: bool):
+               interpret: bool, name: str):
     """The pallas_call shared by both step launches: batch arrays blocked
     by row, every parameter leaf VMEM-resident (constant index map), the
     SMEM scalars, and as outputs the new params, moments and targets of
-    the trained net plus the per-block stats tiles."""
+    the trained net plus the per-block stats tiles.  `name` names the
+    launch's instruction in the compiled program, and so in a device
+    trace: `%fxp_mlp_train_step_critic.N`, `%fxp_mlp_train_step_actor.N`."""
     n_blocks = batch[0].shape[0] // bm
     max_np = max(a.shape[1] for a in consts)
     args = [*batch, *consts, *smem]
@@ -752,6 +754,7 @@ def _step_call(kern, phase, batch, consts, smem, p_wb, *, bm: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name=name,
     )(phase, *args)
     n = len(p_wb)
     return ([list(outs[k * n:(k + 1) * n]) for k in range(4)]
@@ -785,7 +788,8 @@ def ddpg_critic_step_pallas(phase, xc, nobs, aux, at_wb, ct_wb, c_wb, m_wb,
         fxp_weights=fxp_weights)
     return _step_call(kern, phase, (xc, nobs, aux),
                       (*at_wb, *ct_wb, *c_wb, *m_wb, *v_wb),
-                      (deltas, zs, hyper), c_wb, bm=bm, interpret=interpret)
+                      (deltas, zs, hyper), c_wb, bm=bm, interpret=interpret,
+                      name="fxp_mlp_train_step_critic")
 
 
 def ddpg_actor_step_pallas(phase, obs, aux, a_wb, m_wb, v_wb, at_wb, c_wb,
@@ -811,4 +815,5 @@ def ddpg_actor_step_pallas(phase, obs, aux, a_wb, m_wb, v_wb, at_wb, c_wb,
         fxp32_phase1=fxp32_phase1, fxp_weights=fxp_weights)
     return _step_call(kern, phase, (obs, aux),
                       (*a_wb, *m_wb, *v_wb, *at_wb, *c_wb),
-                      (deltas, zs, hyper), a_wb, bm=bm, interpret=interpret)
+                      (deltas, zs, hyper), a_wb, bm=bm, interpret=interpret,
+                      name="fxp_mlp_train_step_actor")

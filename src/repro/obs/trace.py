@@ -16,6 +16,14 @@ Every emitted event is a *complete* event (``"ph": "X"`` with ``ts`` +
 construction — tests/obs/test_trace.py pins well-formedness (one JSON
 object per line, non-negative durations, events orderable by ``ts``).
 
+An enabled tracer's `span` also holds a `jax.profiler.TraceAnnotation` of
+the same name open, so under a `jax.profiler` capture the engines' and the
+training loop's spans land on the profile's host plane, on the device
+operations' clock (docs/observability.md).  `complete` records spans after
+the fact (a request's lifetime, from its queued `t_submit`): those stay in
+the JSONL export only, since the profiler takes no span whose start has
+passed.
+
 Disabled tracing costs one attribute check and a shared no-op context
 manager per span site — no event dicts, no timestamps, no lock traffic —
 which is what lets the engines keep their spans inline on the hot path.
@@ -27,6 +35,8 @@ import json
 import os
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 
 class _NullSpan:
@@ -48,21 +58,25 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """Context manager recording one complete event on exit."""
+    """Context manager recording one complete event on exit, inside a
+    profiler annotation of the same name."""
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict):
         self._tracer = tracer
         self.name, self.cat, self.args = name, cat, args
         self._t0 = 0.0
+        self._annotation = TraceAnnotation(name)
 
     def __enter__(self) -> "_Span":
+        self._annotation.__enter__()
         self._t0 = self._tracer._clock()
         return self
 
     def __exit__(self, *exc) -> bool:
         self._tracer._record(self.name, self.cat, self._t0, self._tracer._clock(), self.args)
+        self._annotation.__exit__(*exc)
         return False
 
     def set(self, **args) -> None:

@@ -28,11 +28,20 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.obs.trace import NULL_TRACER
 from repro.rl import ddpg, replay
 from repro.rl.envs.base import EnvState, env_init, init_fleet, step_fleet
 from repro.rl.noise import NoiseProcess, NoiseState
 
 Array = jax.Array
+
+# The phases of one timestep, in order.  Each is a `jax.named_scope` around
+# its region of the scanned window (and of evaluation's act and env step),
+# so it reaches the compiled program as a component of every instruction's
+# `op_name`; `obs.phases.op_phases` maps a compiled module's instructions
+# onto these names.  `train_host` names its host spans with the same words.
+PHASES = ("act", "env", "replay_add", "replay_sample", "update")
+ACT, ENV, REPLAY_ADD, REPLAY_SAMPLE, UPDATE = PHASES
 
 
 @dataclasses.dataclass(frozen=True)
@@ -140,8 +149,10 @@ def _policy_env_step(
     """One greedy act → vmapped env-step over a fleet; auto-reset keeps
     done lanes in lockstep (training), `autoreset=False` leaves terminal
     states in place (evaluation stops counting via its alive mask)."""
-    action = ddpg.act(agent, obs, cfg=dcfg)
-    env_state, next_obs, reward, done = step_fleet(env, env_state, action, autoreset=autoreset)
+    with jax.named_scope(ACT):
+        action = ddpg.act(agent, obs, cfg=dcfg)
+    with jax.named_scope(ENV):
+        env_state, next_obs, reward, done = step_fleet(env, env_state, action, autoreset=autoreset)
     return env_state, next_obs, reward, done, action
 
 
@@ -151,22 +162,25 @@ def _one_timestep(
     key, k_noise, k_sample = jax.random.split(ts.key, 3)
 
     # 1. actor forward (inference) + exploration noise  [FPGA FP + PRNG]
-    nz, action = _act_explore(
-        ts.agent, ts.obs, ts.noise, k_noise, proc=_noise_proc(cfg, dcfg), dcfg=dcfg
-    )
+    with jax.named_scope(ACT):
+        nz, action = _act_explore(
+            ts.agent, ts.obs, ts.noise, k_noise, proc=_noise_proc(cfg, dcfg), dcfg=dcfg
+        )
 
     # 2. environment transition (vmapped fleet)          [host CPU in paper]
-    env_state, next_obs, reward, done = step_fleet(env, ts.env_state, action)
+    with jax.named_scope(ENV):
+        env_state, next_obs, reward, done = step_fleet(env, ts.env_state, action)
 
     # 3. store the fleet's transitions                   [host replay memory]
-    buf = replay.add_batch(
-        ts.buf,
-        {"obs": ts.obs, "action": action, "reward": reward, "next_obs": next_obs, "done": done},
-    )
+    with jax.named_scope(REPLAY_ADD):
+        buf = replay.add_batch(
+            ts.buf,
+            {"obs": ts.obs, "action": action, "reward": reward, "next_obs": next_obs, "done": done},
+        )
 
     # 4. sample batch + 5. critic/actor BP+WU            [FPGA training]
-    batch = replay.sample(buf, k_sample, dcfg.batch_size)
-    do_update = buf.size >= cfg.warmup_steps
+    with jax.named_scope(REPLAY_SAMPLE):
+        batch = replay.sample(buf, k_sample, dcfg.batch_size)
 
     def run_update(agent):
         new_agent, m = ddpg.update(agent, batch, dcfg)
@@ -178,7 +192,9 @@ def _one_timestep(
         }
         return agent, zero
 
-    agent, metrics = jax.lax.cond(do_update, run_update, skip_update, ts.agent)
+    with jax.named_scope(UPDATE):
+        do_update = buf.size >= cfg.warmup_steps
+        agent, metrics = jax.lax.cond(do_update, run_update, skip_update, ts.agent)
     metrics["reward"] = jnp.mean(reward)
     metrics["did_update"] = do_update.astype(jnp.int32)
     ts = TrainState(
@@ -307,9 +323,11 @@ def train_host(
     is whatever its dispatcher picks; `dcfg.backend` still drives acting.
 
     `tracer` (optional) is an `obs.Tracer`: when enabled, every timestep
-    emits its Fig.-9 segments as spans (`loop.act` / `loop.env` /
-    `loop.replay` / `loop.update`) — layered over a learner's own engine
-    spans, this is the full host-loop picture in one Perfetto timeline.
+    emits its phases as spans named from `PHASES` (`act` / `env` /
+    `replay_add` / `replay_sample` / `update`, category `loop`) — layered
+    over a learner's own engine spans, this is the full host-loop picture
+    in one Perfetto timeline, and under a `jax.profiler` capture the same
+    names sit on the device trace's clock.
 
     `observability` (optional) is an `obs.Observability` bundle: its
     tracer is used when `tracer` isn't given, its HTTP endpoint
@@ -331,6 +349,7 @@ def train_host(
     if learner is not None:
         learner.load_state(ts.agent)
 
+    tracer = NULL_TRACER if tracer is None else tracer
     times = {"env": 0.0, "runtime": 0.0, "accelerator": 0.0}
     key = ts.key
     agent, env_state, obs, buf, nz = (ts.agent, ts.env_state, ts.obs, ts.buf, ts.noise)
@@ -339,59 +358,59 @@ def train_host(
             key, k_noise, k_sample = jax.random.split(key, 3)
 
             t0 = time.perf_counter()
-            nz, action = act_jit(agent, obs, nz, k_noise)
-            jax.block_until_ready(action)
+            with tracer.span(ACT, cat="loop", step=step):
+                nz, action = act_jit(agent, obs, nz, k_noise)
+                jax.block_until_ready(action)
             t1 = time.perf_counter()
 
             # the env fleet steps OUTSIDE the jitted region (eager vmap):
             # the paper's host-side simulator boundary
-            env_state, next_obs, reward, done = step_fleet(env, env_state, action)
-            jax.block_until_ready(next_obs)
+            with tracer.span(ENV, cat="loop", step=step):
+                env_state, next_obs, reward, done = step_fleet(env, env_state, action)
+                jax.block_until_ready(next_obs)
             t2 = time.perf_counter()
 
             # replay add + batch sample + "PCIe import" (device transfer)
-            buf = add_jit(
-                buf,
-                {
-                    "obs": obs,
-                    "action": action,
-                    "reward": reward,
-                    "next_obs": next_obs,
-                    "done": done,
-                },
-            )
-            batch = sample_jit(buf, k_sample)
-            if learner is None:
-                batch = jax.device_put(batch)
-            else:
-                # the learner's queue holds HOST arrays (its "PCIe import"
-                # happens inside run_update and is billed to the
-                # accelerator segment there) — pulling to host here,
-                # instead of a device_put the engine would immediately
-                # undo, keeps the timing breakdown honest and skips a
-                # wasted round trip
-                batch = jax.device_get(batch)
-            jax.block_until_ready(batch)
+            with tracer.span(REPLAY_ADD, cat="loop", step=step):
+                buf = add_jit(
+                    buf,
+                    {
+                        "obs": obs,
+                        "action": action,
+                        "reward": reward,
+                        "next_obs": next_obs,
+                        "done": done,
+                    },
+                )
+                jax.block_until_ready(buf.ptr)
+            with tracer.span(REPLAY_SAMPLE, cat="loop", step=step):
+                batch = sample_jit(buf, k_sample)
+                if learner is None:
+                    batch = jax.device_put(batch)
+                else:
+                    # the learner's queue holds HOST arrays (its "PCIe import"
+                    # happens inside run_update and is billed to the
+                    # accelerator segment there) — pulling to host here,
+                    # instead of a device_put the engine would immediately
+                    # undo, keeps the timing breakdown honest and skips a
+                    # wasted round trip
+                    batch = jax.device_get(batch)
+                jax.block_until_ready(batch)
             t3 = time.perf_counter()
 
             if int(buf.size) >= cfg.warmup_steps:
-                if learner is not None:
-                    learner.run_update(batch)    # blocks until applied
-                    agent = learner.state
-                else:
-                    agent, _ = upd_jit(agent, batch)
-                    jax.block_until_ready(agent.step)
+                with tracer.span(UPDATE, cat="loop", step=step):
+                    if learner is not None:
+                        learner.run_update(batch)    # blocks until applied
+                        agent = learner.state
+                    else:
+                        agent, _ = upd_jit(agent, batch)
+                        jax.block_until_ready(agent.step)
             t4 = time.perf_counter()
 
             times["accelerator"] += (t1 - t0) + (t4 - t3)
             times["env"] += t2 - t1
             times["runtime"] += t3 - t2
-            if tracer is not None and tracer.enabled:
-                tracer.complete("loop.act", t0, t1, cat="loop", step=step)
-                tracer.complete("loop.env", t1, t2, cat="loop", step=step)
-                tracer.complete("loop.replay", t2, t3, cat="loop", step=step)
-                if t4 > t3:
-                    tracer.complete("loop.update", t3, t4, cat="loop", step=step)
             obs = next_obs
     finally:
         if observability is not None:

@@ -159,3 +159,39 @@ def test_context_manager_lands_trace_on_exception(tmp_path):
         pass
     # the whole point: an aborted run still left its trace on disk
     assert [e["name"] for e in read_jsonl(path)] == ["before-crash"]
+
+
+# --------------------------------------------------------------------- #
+# spans on the profiler's clock
+# --------------------------------------------------------------------- #
+
+def _host_events(log_dir) -> set:
+    """The names of the events on a profile's host planes."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"), recursive=True)
+    data = ProfileData.from_file(path)
+    return {ev.name for plane in data.planes if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events}
+
+
+def test_enabled_span_lands_on_the_profile_host_plane(tmp_path):
+    import jax
+
+    on, off = Tracer(), Tracer(enabled=False)
+    with jax.profiler.trace(str(tmp_path)):
+        with on.span("replay_sample.enabled", cat="loop"):
+            pass
+        with off.span("replay_sample.disabled", cat="loop"):
+            pass
+        on.complete("request.after_the_fact", 0.0, 1.0)
+    names = _host_events(tmp_path)
+    assert "replay_sample.enabled" in names
+    assert "replay_sample.disabled" not in names
+    # a span recorded after the fact stays in the JSONL export only
+    assert "request.after_the_fact" not in names
+    assert [e["name"] for e in on.events()] == ["replay_sample.enabled",
+                                                "request.after_the_fact"]
