@@ -8,6 +8,11 @@ chain and the buffer never leaves the device.  ``add``/``add_batch`` store a
 batch of transitions (``add_batch`` takes the same dict layout ``sample``
 returns and ``ddpg.update`` consumes, making store/sample symmetric);
 ``sample`` draws a uniform random batch.
+
+A single-row add writes each field with a slice write at ``ptr`` rather than
+a scatter: a scatter pins the ring row-major (``{1,0}``), while the sample's
+batch gather reads it column-major (``{0,1}``), so on a TPU XLA would copy
+every 10^6-row field into that layout each timestep just to pick the batch.
 """
 from __future__ import annotations
 
@@ -15,6 +20,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 Array = jax.Array
 
@@ -52,19 +58,25 @@ def add(buf: ReplayBuffer, obs, action, reward, next_obs, done) -> ReplayBuffer:
     and `.at[idx].set` leaves the winner among duplicate writes
     unspecified, i.e. the surviving rows would be arbitrary, not the
     newest.  `ptr` still advances by the full B (mod cap), so the write
-    cursor lands exactly past the newest retained row.
+    cursor lands exactly past the newest retained row.  One kept row sits
+    at `ptr < cap` and never wraps, so it is a slice write (module doc).
     """
     b = obs.shape[0]
     cap = buf.obs.shape[0]
     keep = min(b, cap)                       # static: shapes are concrete
-    tail = lambda x: x[b - keep :]            # newest `keep` rows win
-    idx = (buf.ptr + (b - keep) + jnp.arange(keep)) % cap
+    if keep == 1:
+        def put(field, x):
+            row = jnp.asarray(x[b - 1 :], field.dtype)
+            return lax.dynamic_update_slice_in_dim(field, row, buf.ptr, 0)
+    else:
+        idx = (buf.ptr + (b - keep) + jnp.arange(keep)) % cap
+        put = lambda field, x: field.at[idx].set(x[b - keep :])  # newest `keep` rows win
     return ReplayBuffer(
-        obs=buf.obs.at[idx].set(tail(obs)),
-        action=buf.action.at[idx].set(tail(action)),
-        reward=buf.reward.at[idx].set(tail(reward)),
-        next_obs=buf.next_obs.at[idx].set(tail(next_obs)),
-        done=buf.done.at[idx].set(tail(done)),
+        obs=put(buf.obs, obs),
+        action=put(buf.action, action),
+        reward=put(buf.reward, reward),
+        next_obs=put(buf.next_obs, next_obs),
+        done=put(buf.done, done),
         ptr=(buf.ptr + b) % cap,
         size=jnp.minimum(buf.size + b, cap),
     )

@@ -4,7 +4,9 @@ compiled ahead of time for a described TPU v5e (no chip attached).
 Interpret mode cannot see what Mosaic refuses (scalar stores into VMEM,
 boolean selects, block shapes off the (8, 128) tiling); the chip's compiler
 can, and it is installed here.  Each test compiles with `interpret=False`
-and counts the Pallas kernels (`tpu_custom_call`) in the compiled program.
+and counts the Pallas kernels (`tpu_custom_call`) in the compiled program;
+one more compiles the replay's store-then-sample scan and counts its
+relayout copies.
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and the test workers import every file.
 """
@@ -23,7 +25,7 @@ from repro.kernels.fxp_mlp.ops import (fxp_mlp_forward, fxp_mlp_train,
                                        fxp_mlp_train_step)
 from repro.kernels.quantize.ops import monitor_quant
 from repro.optim import adam
-from repro.rl import ddpg
+from repro.rl import ddpg, replay
 
 OBS, ACT = 17, 6                                # halfcheetah
 ACTOR = (OBS, *ddpg.HIDDEN, ACT)                # 17-400-300-6
@@ -114,6 +116,37 @@ def test_dense_layer_compiles(one_chip):
     compiled = fn.lower(_sds(one_chip, (7, OBS)), _sds(one_chip, (OBS, 400)),
                         _sds(one_chip, (400,))).compile()
     assert _kernels(compiled) == 1
+
+
+def test_single_row_replay_keeps_its_layout(one_chip):
+    """A one-env step stores one row into a 10^6-row ring and samples a
+    128-row batch that only the update's branch reads.  The batch gather
+    wants each field column-major; a scatter store pins the scan's carry
+    row-major, and XLA then copies every field into the gather's layout
+    each step (512 MB each, lanes padded to 128).  The slice store leaves
+    the carry in the gather's layout: no 10^6-row array is copied."""
+    cap = 1_000_000
+    buf = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
+                       jax.eval_shape(functools.partial(replay.init, cap, OBS, ACT)))
+
+    def step(carry, row):
+        buf, key = carry
+        key, k_sample = jax.random.split(key)
+        buf = replay.add_batch(buf, row)
+        batch = replay.sample(buf, k_sample, 128)
+        loss = jax.lax.cond(buf.size >= 2,
+                            lambda b: sum(jnp.sum(v.astype(jnp.float32)) for v in b.values()),
+                            lambda b: jnp.float32(0), batch)
+        return (buf, key), loss
+
+    rows = {"obs": _sds(one_chip, (8, 1, OBS)), "action": _sds(one_chip, (8, 1, ACT)),
+            "reward": _sds(one_chip, (8, 1)), "next_obs": _sds(one_chip, (8, 1, OBS)),
+            "done": _sds(one_chip, (8, 1), jnp.bool_)}
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    fn = jax.jit(lambda buf, key, rows: jax.lax.scan(step, (buf, key), rows), donate_argnums=0)
+    text = fn.lower(buf, _sds(one_chip, key.shape, key.dtype), rows).compile().as_text()
+    copies = re.findall(rf"= \S*\[{cap},[^\n]* copy(?:-start)?\(", text)
+    assert copies == []
 
 
 def test_monitor_quant_compiles(one_chip):

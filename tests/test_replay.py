@@ -1,6 +1,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.rl import replay
 
@@ -158,3 +159,36 @@ def test_add_batch_matches_add_bitwise():
     out, _ = jax.jit(lambda b, ks: jax.lax.scan(body, b, ks))(
         b1, jax.random.split(jax.random.key(1), 6))
     assert int(out.size) == 8 and int(out.ptr) == (5 + 6 * 4) % 8
+
+
+@pytest.mark.parametrize("b", [1, 4, 7, 10])
+def test_add_matches_a_plain_ring(b):
+    """Adds of B rows, jitted as the loops run them, against a numpy ring
+    that stores one row at a time, bit for bit after every add.  Capacity 7
+    is prime, so 4-row adds start at every slot; B = 1 (the slice store)
+    writes every slot from 0 to cap-1 twice, overwriting a full ring; B = 7
+    and 10 (= cap + 3) take the scatter path."""
+    cap, obs_dim, act_dim = 7, 3, 2
+    rng = np.random.default_rng(b)
+    add = jax.jit(replay.add_batch)
+    buf = replay.init(cap, obs_dim, act_dim)
+    ring = {"obs": np.zeros((cap, obs_dim), np.float32),
+            "action": np.zeros((cap, act_dim), np.float32),
+            "reward": np.zeros(cap, np.float32),
+            "next_obs": np.zeros((cap, obs_dim), np.float32),
+            "done": np.zeros(cap, bool)}
+    ptr = size = 0
+    for _ in range(2 * cap // b + 2):
+        rows = {"obs": rng.standard_normal((b, obs_dim)).astype(np.float32),
+                "action": rng.standard_normal((b, act_dim)).astype(np.float32),
+                "reward": rng.standard_normal(b).astype(np.float32),
+                "next_obs": rng.standard_normal((b, obs_dim)).astype(np.float32),
+                "done": rng.integers(0, 2, b).astype(bool)}
+        buf = add(buf, {k: jnp.asarray(v) for k, v in rows.items()})
+        for i in range(b):
+            for k in ring:
+                ring[k][ptr] = rows[k][i]
+            ptr, size = (ptr + 1) % cap, min(size + 1, cap)
+        for k in ring:
+            np.testing.assert_array_equal(np.asarray(getattr(buf, k)), ring[k], k)
+        assert (int(buf.ptr), int(buf.size)) == (ptr, size)
