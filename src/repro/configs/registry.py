@@ -9,7 +9,7 @@ from repro.models.config import ModelConfig
 ARCH_IDS = [
     "gemma3_1b", "internlm2_1_8b", "qwen2_0_5b", "deepseek_7b", "rwkv6_1_6b",
     "dbrx_132b", "moonshot_v1_16b_a3b", "phi3_vision_4_2b", "hubert_xlarge",
-    "recurrentgemma_2b", "fixar_ddpg",
+    "recurrentgemma_2b", "granite_4_0_h_micro", "fixar_ddpg",
 ]
 
 # external ids (as given in the assignment) -> module names
@@ -24,6 +24,7 @@ ALIASES = {
     "phi-3-vision-4.2b": "phi3_vision_4_2b",
     "hubert-xlarge": "hubert_xlarge",
     "recurrentgemma-2b": "recurrentgemma_2b",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
 }
 
 

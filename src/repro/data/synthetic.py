@@ -9,6 +9,13 @@ same global batch.
 The token stream is a mixture of Zipf-distributed unigrams and deterministic
 n-gram structure, so LM losses actually *decrease* during smoke training
 (pure uniform noise would pin the loss at log V).
+
+Packed documents (`DataConfig.packed`): each row is a stream of documents
+whose lengths are log-normal (median `doc_len_median`, `doc_len_sigma`,
+clipped to [`doc_len_min`, `doc_len_max`]), packed greedily in order and the
+last one cut at the row's end.  The batch then carries `segment_ids` (the
+document index of each token) and `labels` are -100 where the next token
+starts a new document, and at the row's end.
 """
 from __future__ import annotations
 
@@ -28,6 +35,23 @@ class DataConfig:
     seed: int = 0
     zipf_a: float = 1.2
     structure_period: int = 8   # deterministic n-gram backbone
+    packed: bool = False        # packed documents with segment_ids
+    doc_len_median: float = 1024.0
+    doc_len_sigma: float = 1.2
+    doc_len_min: int = 32
+    doc_len_max: int = 8192
+
+
+def segment_ids(cfg: DataConfig, key: Array, batch: int, seq_len: int) -> Array:
+    """(batch, seq_len) int32 document index of each position: documents of
+    log-normal length packed in order, the last one cut at the row's end."""
+    n = -(-seq_len // cfg.doc_len_min)          # enough documents to fill a row
+    z = jax.random.normal(key, (batch, n))
+    lens = jnp.clip(jnp.round(cfg.doc_len_median * jnp.exp(cfg.doc_len_sigma * z)),
+                    cfg.doc_len_min, cfg.doc_len_max).astype(jnp.int32)
+    ends = jnp.cumsum(lens, axis=1)
+    pos = jnp.arange(seq_len, dtype=jnp.int32)
+    return jax.vmap(lambda e: jnp.searchsorted(e, pos, side="right"))(ends).astype(jnp.int32)
 
 
 def _batch_key(cfg: DataConfig, step: int) -> Array:
@@ -53,6 +77,15 @@ def make_batch(cfg: DataConfig, model_cfg: ModelConfig, shape: ShapeConfig,
     batch: dict[str, Array] = {}
     if shape.kind == "decode":
         return {"tokens": toks[:, :1]}
+    if cfg.packed and model_cfg.frontend == "none":
+        seg = segment_ids(cfg, jax.random.fold_in(key, 3), b, s)
+        same = seg[:, 1:] == seg[:, :-1]
+        labels = jnp.where(same, toks[:, 1:s], -100)
+        batch = {"tokens": toks[:, :s], "segment_ids": seg}
+        if shape.kind == "train":
+            batch["labels"] = jnp.concatenate(
+                [labels, jnp.full((b, 1), -100, labels.dtype)], axis=1)
+        return batch
     if model_cfg.frontend != "audio_stub":
         batch["tokens"] = toks[:, :s]
     if model_cfg.frontend == "vision_stub":

@@ -1,8 +1,9 @@
 """Model configuration schema for the assigned architecture zoo.
 
-One `ModelConfig` describes any of the 10 assigned architectures (plus the
+One `ModelConfig` describes any of the assigned architectures (plus the
 reduced smoke variants).  Heterogeneous layer stacks (gemma3's 5:1
-local:global, recurrentgemma's 2:1 RG-LRU:local-attn) are expressed as a
+local:global, recurrentgemma's 2:1 RG-LRU:local-attn, granite-4.0-h's
+9:1 Mamba-2:attention) are expressed as a
 `block_pattern` cycled over the depth; the transformer assembly scans over
 whole pattern periods and unrolls the remainder (MaxText-style stacked-param
 scan, see transformer.py).
@@ -19,6 +20,7 @@ ATTN_GLOBAL = "global"        # full (causal or bidir) attention + MLP
 ATTN_LOCAL = "local"          # sliding-window attention + MLP
 RWKV6 = "rwkv6"               # RWKV-6 time-mix + channel-mix
 RGLRU = "rglru"               # RecurrentGemma recurrent block + MLP
+MAMBA2 = "mamba2"             # Mamba-2 (SSD) mixer + MLP (granite-4.0-h)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -48,7 +50,20 @@ class ModelConfig:
     # --- recurrent (rwkv6 / rglru) ---
     rnn_state_dim: Optional[int] = None     # rglru recurrent width
     rwkv_head_dim: int = 64
-    conv1d_width: int = 4                   # rglru temporal conv
+    conv1d_width: int = 4                   # rglru / mamba2 temporal conv
+    # --- Mamba-2 (SSD) mixer: d_inner = mamba_n_heads * mamba_head_dim ---
+    mamba_n_heads: int = 0
+    mamba_head_dim: int = 64
+    mamba_d_state: int = 128
+    mamba_n_groups: int = 1
+    mamba_chunk: int = 256                  # SSD chunk length
+    # --- granite-style scalings (defaults: the usual transformer) ---
+    rope: bool = True                       # False: no positional encoding
+    attn_scale: Optional[float] = None      # softmax scale; None: 1/sqrt(hd)
+    embed_scale: Optional[float] = None     # None: sqrt(d_model)
+    residual_scale: float = 1.0             # each branch x this before its add
+    logits_scaling: float = 1.0             # logits divided by this
+    norm_eps: float = 1e-6
     # --- frontend stubs (vlm/audio): embeddings arrive precomputed ---
     frontend: str = "none"                  # none | vision_stub | audio_stub
     frontend_dim: int = 0                   # incoming embedding width
@@ -57,8 +72,11 @@ class ModelConfig:
     dtype: str = "bfloat16"
     # "dots" (checkpoint_dots) measured strictly better than "full" on the
     # roofline: full remat re-executes the psum-bearing ops in the backward
-    # pass (gemma3 train: collective 3.76 -> 1.80 s, compute -21%, §Perf-6)
-    remat: str = "dots"                     # none | dots | full
+    # pass (gemma3 train: collective 3.76 -> 1.80 s, compute -21%, §Perf-6).
+    # "block" checkpoints each layer on its own (only layer inputs are kept):
+    # the one period of a long-sequence hybrid holds every layer's internals
+    # under the period-level policies.
+    remat: str = "dots"                     # none | dots | full | block
     # QAT (FIXAR technique as a first-class feature)
     qat: bool = False
     qat_delay: int = 0
@@ -88,24 +106,55 @@ class ModelConfig:
         p = self.block_pattern
         return [p[i % len(p)] for i in range(self.n_layers)]
 
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_n_heads * self.mamba_head_dim
+
+    def _mamba_params(self) -> int:
+        """One Mamba-2 mixer: in_proj to [z | xBC | dt], the depthwise conv
+        with its bias, dt_bias, A_log and D per head, the gated norm, and
+        out_proj."""
+        d, di, h = self.d_model, self.mamba_d_inner, self.mamba_n_heads
+        conv_dim = di + 2 * self.mamba_n_groups * self.mamba_d_state
+        return (d * (di + conv_dim + h) + conv_dim * (self.conv1d_width + 1)
+                + 3 * h + di + di * d)
+
+    @property
+    def _norm_params(self) -> int:
+        return self.d_model * (2 if self.norm == "layernorm" else 1)
+
+    def _layer_params(self, t: str, active: bool) -> int:
+        """One layer of type t with its two pre-norms."""
+        return self._mixer_params(t) + self._mlp_params(
+            self.d_model, self.d_ff, active=active) + 2 * self._norm_params
+
+    def _mixer_params(self, t: str) -> int:
+        d, hd = self.d_model, self.hd
+        n_q, n_kv = self.n_heads, self.n_kv_heads
+        if t in (ATTN_GLOBAL, ATTN_LOCAL):
+            return d * hd * (n_q + 2 * n_kv) + n_q * hd * d
+        if t == RWKV6:
+            # time-mix: r,k,v,g,o projections + decay lora; channel-mix
+            return 5 * d * d
+        if t == RGLRU:
+            rnn = self.rnn_state_dim or d
+            return 2 * d * rnn + rnn * d
+        if t == MAMBA2:
+            return self._mamba_params()
+        raise ValueError(t)
+
+    def _head_params(self) -> int:
+        """The embedding and the output head (one matrix when tied; a
+        vocabulary slice counts its rows), and the final norm."""
+        d = self.d_model
+        total = 2 * d * self.vocab_size if not self.tie_embeddings \
+            else d * self.vocab_size
+        return total + self._norm_params
+
     def params_per_token(self) -> int:
         """Active parameter count per token (for 6·N·D MODEL_FLOPS)."""
-        d, f, hd = self.d_model, self.d_ff, self.hd
-        n_q, n_kv = self.n_heads, self.n_kv_heads
-        total = 0
-        for t in self.layer_types():
-            if t in (ATTN_GLOBAL, ATTN_LOCAL):
-                attn = d * hd * (n_q + 2 * n_kv) + n_q * hd * d
-                total += attn + self._mlp_params(d, f, active=True)
-            elif t == RWKV6:
-                # time-mix: r,k,v,g,o projections + decay lora; channel-mix
-                total += 5 * d * d + self._mlp_params(d, f, active=True)
-            elif t == RGLRU:
-                rnn = self.rnn_state_dim or d
-                total += 2 * d * rnn + rnn * d + self._mlp_params(d, f, active=True)
-        total += 2 * d * self.vocab_size if not self.tie_embeddings \
-            else d * self.vocab_size
-        return total
+        return (sum(self._layer_params(t, active=True) for t in self.layer_types())
+                + self._head_params())
 
     def _mlp_params(self, d, f, active=False):
         per_expert = (3 if self.mlp_type == "glu" else 2) * d * f
@@ -115,21 +164,8 @@ class ModelConfig:
         return per_expert * k + d * self.n_experts  # + router
 
     def total_params(self) -> int:
-        d, f = self.d_model, self.d_ff
-        hd, n_q, n_kv = self.hd, self.n_heads, self.n_kv_heads
-        total = 0
-        for t in self.layer_types():
-            if t in (ATTN_GLOBAL, ATTN_LOCAL):
-                total += d * hd * (n_q + 2 * n_kv) + n_q * hd * d
-                total += self._mlp_params(d, f, active=False)
-            elif t == RWKV6:
-                total += 5 * d * d + self._mlp_params(d, f, active=False)
-            elif t == RGLRU:
-                rnn = self.rnn_state_dim or d
-                total += 2 * d * rnn + rnn * d + self._mlp_params(d, f, active=False)
-        total += 2 * d * self.vocab_size if not self.tie_embeddings \
-            else d * self.vocab_size
-        return total
+        return (sum(self._layer_params(t, active=False) for t in self.layer_types())
+                + self._head_params())
 
 
 @dataclasses.dataclass(frozen=True)

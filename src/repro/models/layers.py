@@ -31,6 +31,7 @@ ATTN_SITES = ("attn_in", "attn_o_in", "mlp_in", "mlp_down_in")
 MOE_SITES = ("attn_in", "attn_o_in", "router_in", "expert_in", "expert_down_in")
 RWKV_SITES = ("tmix_in", "cmix_in")
 RGLRU_SITES = ("rnn_in", "mlp_in", "mlp_down_in")
+MAMBA2_SITES = ("mamba_in", "mamba_out_in", "mlp_in", "mlp_down_in")
 HEAD_SITES = ("head_in",)
 
 
@@ -111,7 +112,9 @@ def norm_specs(cfg: ModelConfig) -> Params:
     return p
 
 
-def apply_norm(x: Array, p: Params, cfg: ModelConfig, eps: float = 1e-6) -> Array:
+def apply_norm(x: Array, p: Params, cfg: ModelConfig,
+               eps: Optional[float] = None) -> Array:
+    eps = cfg.norm_eps if eps is None else eps
     xf = x.astype(jnp.float32)
     if cfg.norm == "layernorm":
         mu = jnp.mean(xf, -1, keepdims=True)
@@ -213,8 +216,10 @@ def _qkv(x, p, cfg: ModelConfig, qat: LayerQAT):
     return q, k, v
 
 
-def _mask(q_pos: Array, k_pos: Array, cfg: ModelConfig, local: bool) -> Array:
-    """(…, Sq, Sk) boolean mask. q_pos/k_pos: (..., S)."""
+def _mask(q_pos: Array, k_pos: Array, cfg: ModelConfig, local: bool,
+          q_seg: Optional[Array] = None, k_seg: Optional[Array] = None) -> Array:
+    """(…, Sq, Sk) boolean mask. q_pos/k_pos: (..., S).  With segment
+    (document) ids (B, Sq) / (B, Sk), a query sees only its own document."""
     m = jnp.ones(q_pos.shape[:-1] + (q_pos.shape[-1], k_pos.shape[-1]),
                  jnp.bool_)
     qp = q_pos[..., :, None]
@@ -223,7 +228,13 @@ def _mask(q_pos: Array, k_pos: Array, cfg: ModelConfig, local: bool) -> Array:
         m = jnp.logical_and(m, kp <= qp)
     if local:
         m = jnp.logical_and(m, kp > qp - cfg.window)
+    if q_seg is not None:
+        m = jnp.logical_and(m, q_seg[:, :, None] == k_seg[:, None, :])
     return m
+
+
+def _scale(cfg: ModelConfig, hd: int) -> float:
+    return 1.0 / math.sqrt(hd) if cfg.attn_scale is None else cfg.attn_scale
 
 
 def _sdpa(q, k, v, mask, cfg: ModelConfig, rules) -> Array:
@@ -234,7 +245,7 @@ def _sdpa(q, k, v, mask, cfg: ModelConfig, rules) -> Array:
     g = hq // hk
     qg = q.reshape(b, sq, hk, g, hd)
     scores = jnp.einsum("bskgh,btkh->bkgst", qg, k).astype(jnp.float32)
-    scores = scores / math.sqrt(hd)
+    scores = scores * _scale(cfg, hd)
     if mask.ndim == 2:
         mask = mask[None]
     scores = jnp.where(mask[:, None, None], scores, -1e30)
@@ -262,7 +273,7 @@ def _banded_local_sdpa(q, k, v, cfg: ModelConfig) -> Array:
         [jnp.concatenate([jnp.zeros_like(vc[:, :1]), vc[:, :-1]], 1), vc], 2)
 
     scores = jnp.einsum("znakgh,znmkh->znkgam", qc, kk).astype(jnp.float32)
-    scores = scores / math.sqrt(hd)
+    scores = scores * _scale(cfg, hd)
     a_idx = jnp.arange(w)[:, None]
     m_idx = jnp.arange(2 * w)[None, :]
     band = jnp.logical_and(m_idx <= w + a_idx, m_idx > a_idx)
@@ -279,14 +290,19 @@ def _banded_local_sdpa(q, k, v, cfg: ModelConfig) -> Array:
 def attn_forward(x: Array, p: Params, cfg: ModelConfig, *, local: bool,
                  positions: Array, rules: Optional[ShardingRules],
                  qat: LayerQAT, chunk: int = 0, unroll: bool = False,
-                 cache: Optional[dict[str, Array]] = None
+                 cache: Optional[dict[str, Array]] = None,
+                 segment_ids: Optional[Array] = None
                  ) -> tuple[Array, Optional[dict[str, Array]]]:
     """Full-sequence attention (train / prefill). x: (B, S, d).
 
-    `chunk` bounds the score-matrix working set by scanning query chunks;
-    `unroll=True` replaces the scan with a python loop (identical math, no
-    while-loop — used by the roofline harness, where cost_analysis must see
-    every chunk).
+    `chunk` bounds the score-matrix working set by scanning query chunks
+    (under `cfg.remat == "block"` each is rematerialized in the backward
+    pass, so that no chunk's scores outlive it); `unroll=True` replaces
+    the scan with a python loop (identical math, no while-loop — used by
+    the roofline harness, where cost_analysis must see every chunk).
+
+    `segment_ids` (B, S) (packed documents): a query attends only to keys
+    of its own document.
 
     `cache` (prefill): a decode-shaped KV cache ({"k","v"}: (B, T, Hk, hd));
     the prompt's roped K / raw V are written into the exact slots
@@ -294,8 +310,9 @@ def attn_forward(x: Array, p: Params, cfg: ModelConfig, *, local: bool,
     absolute positions for global), so decode can continue at pos = S.
     Returns (y, written_cache) — cache is None when none was passed."""
     q, k, v = _qkv(x, p, cfg, qat)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    if cfg.rope:
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
     q = constrain(q, rules, "batch", "seq", "q_heads", "head_dim")
     k = constrain(k, rules, "batch", "seq", "kv_heads", "head_dim")
 
@@ -318,30 +335,38 @@ def attn_forward(x: Array, p: Params, cfg: ModelConfig, *, local: bool,
                      v[:, s_all - keep:].astype(cache["v"].dtype))}
 
     s = x.shape[1]
+    seg = segment_ids
     if local and s >= 2 * cfg.window and s % cfg.window == 0 \
-            and positions.ndim == 1:
+            and positions.ndim == 1 and seg is None:
         out = _banded_local_sdpa(q, k, v, cfg)
     elif chunk and s > chunk:
         n_chunks = s // chunk
         assert s % chunk == 0
 
         def body(carry, qc_pc):
-            qc, pc = qc_pc
-            m = _mask(pc, positions, cfg, local)
+            qc, pc, sc = qc_pc
+            m = _mask(pc, positions, cfg, local, sc, seg)
             oc = _sdpa(qc, k, v, m, cfg, rules)
             return carry, oc
 
-        qs = q.reshape(x.shape[0], n_chunks, chunk, *q.shape[2:]).swapaxes(0, 1)
+        if cfg.remat == "block":
+            body = jax.checkpoint(body)
+
+        split = lambda a: a.reshape(x.shape[0], n_chunks, chunk,
+                                    *a.shape[2:]).swapaxes(0, 1)
+        qs = split(q)
         ps = positions.reshape(n_chunks, chunk) if positions.ndim == 1 else \
-            positions.reshape(x.shape[0], n_chunks, chunk).swapaxes(0, 1)
+            split(positions)
+        ss = split(seg) if seg is not None else None
         if unroll:
-            outs = jnp.stack([body(None, (qs[i], ps[i]))[1]
+            outs = jnp.stack([body(None, (qs[i], ps[i],
+                                          None if ss is None else ss[i]))[1]
                               for i in range(n_chunks)])
         else:
-            _, outs = jax.lax.scan(body, None, (qs, ps))
+            _, outs = jax.lax.scan(body, None, (qs, ps, ss))
         out = outs.swapaxes(0, 1).reshape(x.shape[0], s, cfg.n_heads, cfg.hd)
     else:
-        m = _mask(positions, positions, cfg, local)
+        m = _mask(positions, positions, cfg, local, seg, seg)
         out = _sdpa(q, k, v, m, cfg, rules)
 
     out = qat.site("attn_o_in", out.reshape(x.shape[0], s, -1))
@@ -370,8 +395,9 @@ def attn_decode(x: Array, p: Params, cfg: ModelConfig, *, local: bool,
     pos = jnp.asarray(pos, jnp.int32)
     per_row = pos.ndim == 1
     positions = pos[:, None] if per_row else jnp.full((x.shape[0], 1), pos, jnp.int32)
-    q = rope(q, positions, cfg.rope_theta)
-    k_new = rope(k_new, positions, cfg.rope_theta)
+    if cfg.rope:
+        q = rope(q, positions, cfg.rope_theta)
+        k_new = rope(k_new, positions, cfg.rope_theta)
 
     t = cache["k"].shape[1]
     ring = local and t <= cfg.window
@@ -480,7 +506,8 @@ def embed_specs(cfg: ModelConfig) -> Params:
 def embed_tokens(tokens: Array, p: Params, cfg: ModelConfig,
                  rules: Optional[ShardingRules]) -> Array:
     x = p["embedding"].astype(cfg.compute_dtype)[tokens]
-    x = x * jnp.asarray(math.sqrt(cfg.d_model), cfg.compute_dtype)
+    scale = math.sqrt(cfg.d_model) if cfg.embed_scale is None else cfg.embed_scale
+    x = x * jnp.asarray(scale, cfg.compute_dtype)
     return constrain(x, rules, "batch", "seq", "embed")
 
 
@@ -488,5 +515,12 @@ def lm_head(x: Array, p: Params, cfg: ModelConfig,
             rules: Optional[ShardingRules], qat: LayerQAT) -> Array:
     x = qat.site("head_in", x)
     w = (p["embedding"].T if cfg.tie_embeddings else p["head"])
-    logits = x @ w.astype(cfg.compute_dtype)
+    logits = scale_logits(x @ w.astype(cfg.compute_dtype), cfg)
     return constrain(logits, rules, "batch", "seq", "vocab")
+
+
+def scale_logits(logits: Array, cfg: ModelConfig) -> Array:
+    """Granite divides the head's output by `logits_scaling`."""
+    if cfg.logits_scaling == 1.0:
+        return logits
+    return logits * jnp.asarray(1.0 / cfg.logits_scaling, logits.dtype)

@@ -28,10 +28,11 @@ import jax.numpy as jnp
 
 from repro.core.parallelism import Logical, ShardingRules, constrain
 from repro.models import frontend as fe
+from repro.models import mamba2 as mamba_mod
 from repro.models import moe as moe_mod
 from repro.models import rglru as rglru_mod
 from repro.models import rwkv6 as rwkv_mod
-from repro.models.config import ATTN_GLOBAL, ATTN_LOCAL, RGLRU, RWKV6, ModelConfig
+from repro.models.config import ATTN_GLOBAL, ATTN_LOCAL, MAMBA2, RGLRU, RWKV6, ModelConfig
 from repro.models import layers as L
 
 Array = jax.Array
@@ -50,6 +51,8 @@ def block_sites(cfg: ModelConfig, bt: str) -> tuple[str, ...]:
         return L.RWKV_SITES
     if bt == RGLRU:
         return L.RGLRU_SITES
+    if bt == MAMBA2:
+        return L.MAMBA2_SITES
     raise ValueError(bt)
 
 
@@ -65,6 +68,9 @@ def block_init(key, cfg: ModelConfig, bt: str) -> Params:
     if bt == RGLRU:
         return {"ln1": L.norm_init(cfg), "rnn": rglru_mod.rglru_init(k2, cfg),
                 "ln2": L.norm_init(cfg), "ffn": L.mlp_init(k4, cfg)}
+    if bt == MAMBA2:
+        return {"ln1": L.norm_init(cfg), "mamba": mamba_mod.mamba2_init(k2, cfg),
+                "ln2": L.norm_init(cfg), "ffn": L.mlp_init(k4, cfg)}
     raise ValueError(bt)
 
 
@@ -78,6 +84,9 @@ def block_specs(cfg: ModelConfig, bt: str) -> Params:
                 "rwkv": rwkv_mod.rwkv_specs(cfg)}
     if bt == RGLRU:
         return {"ln1": L.norm_specs(cfg), "rnn": rglru_mod.rglru_specs(cfg),
+                "ln2": L.norm_specs(cfg), "ffn": L.mlp_specs(cfg)}
+    if bt == MAMBA2:
+        return {"ln1": L.norm_specs(cfg), "mamba": mamba_mod.mamba2_specs(cfg),
                 "ln2": L.norm_specs(cfg), "ffn": L.mlp_specs(cfg)}
     raise ValueError(bt)
 
@@ -140,30 +149,59 @@ def ranges_specs(cfg: ModelConfig) -> Params:
 # ---------------------------------------------------------------------------
 
 
-def block_forward(x: Array, bp: Params, cfg: ModelConfig, bt: str, *,
-                  positions: Array, rules: Optional[ShardingRules],
-                  qat: L.LayerQAT, state: Optional[dict] = None,
-                  attn_chunk: int = 0, unroll: bool = False
-                  ) -> tuple[Array, Optional[dict], Array]:
-    """Returns (x_out, new_state, aux_loss)."""
+def _residual(x: Array, h: Array, cfg: ModelConfig) -> Array:
+    """x + h, the branch scaled first where the config says (granite)."""
+    if cfg.residual_scale != 1.0:
+        h = h * jnp.asarray(cfg.residual_scale, h.dtype)
+    return x + h
+
+
+def _ffn(x: Array, bp: Params, cfg: ModelConfig, rules, qat) -> tuple[Array, Array]:
+    """The feed-forward half of a block, with its norm and residual."""
     aux = jnp.float32(0)
-    if state is None and _needs_state(bt):
-        # training / stateless prefill: fresh zero recurrent state
-        state = _block_state_init(cfg, bt, x.shape[0], 0, for_decode=False)
-    if bt in (ATTN_GLOBAL, ATTN_LOCAL):
-        h = L.apply_norm(x, bp["ln1"], cfg)
-        h, state = L.attn_forward(h, bp["attn"], cfg,
-                                  local=(bt == ATTN_LOCAL),
-                                  positions=positions, rules=rules, qat=qat,
-                                  chunk=attn_chunk, unroll=unroll,
-                                  cache=state)
-        x = x + h
+    with jax.named_scope("ffn"):
         h = L.apply_norm(x, bp["ln2"], cfg)
         if cfg.is_moe:
             h, aux = moe_mod.moe_forward(h, bp["ffn"], cfg, rules, qat)
         else:
             h = L.mlp_forward(h, bp["ffn"], cfg, rules, qat)
-        return x + h, state, aux
+        return _residual(x, h, cfg), aux
+
+
+def block_forward(x: Array, bp: Params, cfg: ModelConfig, bt: str, *,
+                  positions: Array, rules: Optional[ShardingRules],
+                  qat: L.LayerQAT, state: Optional[dict] = None,
+                  attn_chunk: int = 0, unroll: bool = False,
+                  segment_ids: Optional[Array] = None
+                  ) -> tuple[Array, Optional[dict], Array]:
+    """Returns (x_out, new_state, aux_loss).  Attention blocks lie in the
+    `attn` scope, Mamba-2 mixers in `mamba_proj`/`ssd`, feed-forward halves
+    in `ffn` (train/step.PHASES)."""
+    aux = jnp.float32(0)
+    if state is None and _needs_state(bt):
+        # training / stateless prefill: fresh zero recurrent state
+        state = _block_state_init(cfg, bt, x.shape[0], 0, for_decode=False)
+    if bt in (ATTN_GLOBAL, ATTN_LOCAL):
+        with jax.named_scope("attn"):
+            h = L.apply_norm(x, bp["ln1"], cfg)
+            h, state = L.attn_forward(h, bp["attn"], cfg,
+                                      local=(bt == ATTN_LOCAL),
+                                      positions=positions, rules=rules, qat=qat,
+                                      chunk=attn_chunk, unroll=unroll,
+                                      cache=state, segment_ids=segment_ids)
+            x = _residual(x, h, cfg)
+        x, aux = _ffn(x, bp, cfg, rules, qat)
+        return x, state, aux
+    if bt == MAMBA2:
+        if state is not None:
+            raise NotImplementedError("Mamba-2 blocks have no decode state")
+        with jax.named_scope("mamba_proj"):
+            h = L.apply_norm(x, bp["ln1"], cfg)
+        h = mamba_mod.mamba2_forward(h, bp["mamba"], cfg, rules, qat, segment_ids)
+        with jax.named_scope("mamba_proj"):
+            x = _residual(x, h, cfg)
+        x, aux = _ffn(x, bp, cfg, rules, qat)
+        return x, state, aux
     if bt == RWKV6:
         h = L.apply_norm(x, bp["ln1"], cfg)
         h, state = rwkv_mod.time_mix(h, bp["rwkv"], cfg, state, rules, qat,
@@ -193,6 +231,8 @@ def _block_state_init(cfg: ModelConfig, bt: str, batch: int, max_seq: int,
         return rwkv_mod.init_state(cfg, batch)
     if bt == RGLRU:
         return rglru_mod.init_state(cfg, batch)
+    if bt == MAMBA2:
+        raise NotImplementedError("Mamba-2 blocks have no decode state")
     if for_decode:  # attention KV cache; local layers use a window ring
         t = min(max_seq, cfg.window) if bt == ATTN_LOCAL else max_seq
         return {"k": jnp.zeros((batch, t, cfg.n_kv_heads, cfg.hd),
@@ -219,7 +259,7 @@ def _block_state_specs(cfg: ModelConfig, bt: str, for_decode: bool):
 
 
 def _remat_wrap(fn, cfg: ModelConfig, enable: bool):
-    if not enable or cfg.remat == "none":
+    if not enable or cfg.remat in ("none", "block"):
         return fn
     if cfg.remat == "dots":
         return jax.checkpoint(
@@ -242,14 +282,27 @@ def forward(params: Params, batch: dict[str, Array], cfg: ModelConfig, *,
     blocks write KV caches (prefill mode).
     """
     qat_on = ranges is not None
-    if "tokens" in batch:
-        x = L.embed_tokens(batch["tokens"], params["embed"], cfg, rules)
-        b, s = batch["tokens"].shape
-    else:  # audio frontend: embeddings only
-        b, s, _ = batch["frontend"].shape
-        x = jnp.zeros((b, s, cfg.d_model), cfg.compute_dtype)
-    x = fe.apply_frontend(x, params["frontend"], batch, cfg, rules)
+    with jax.named_scope("embed"):
+        if "tokens" in batch:
+            x = L.embed_tokens(batch["tokens"], params["embed"], cfg, rules)
+            b, s = batch["tokens"].shape
+        else:  # audio frontend: embeddings only
+            b, s, _ = batch["frontend"].shape
+            x = jnp.zeros((b, s, cfg.d_model), cfg.compute_dtype)
+        x = fe.apply_frontend(x, params["frontend"], batch, cfg, rules)
     positions = jnp.arange(s, dtype=jnp.int32)
+    segment_ids = batch.get("segment_ids")
+
+    def apply_block(x, bp, bt, stats, st):
+        """One block; returns (x, state, aux, its updated QAT ranges)."""
+        qat = L.LayerQAT(stats, quant_phase, cfg.qat_bits)
+        x, st, a = block_forward(x, bp, cfg, bt, positions=positions, rules=rules,
+                                 qat=qat, state=st, attn_chunk=attn_chunk,
+                                 unroll=unroll, segment_ids=segment_ids)
+        return x, st, a, qat.collect()
+
+    if remat and cfg.remat == "block":
+        apply_block = jax.checkpoint(apply_block, static_argnums=(2,))
 
     m = len(cfg.block_pattern)
     has_states = states is not None
@@ -263,14 +316,10 @@ def forward(params: Params, batch: dict[str, Array], cfg: ModelConfig, *,
             bps, rngs, sts = xs
             new_rngs, new_sts = [], []
             for i, bt in enumerate(slot_types):
-                qat = L.LayerQAT(rngs[i] if qat_on else None, quant_phase,
-                                 cfg.qat_bits)
-                x, st, a = block_forward(
-                    x, bps[i], cfg, bt, positions=positions, rules=rules,
-                    qat=qat, state=sts[i], attn_chunk=attn_chunk,
-                    unroll=unroll)
+                x, st, a, rng = apply_block(x, bps[i], bt,
+                                            rngs[i] if qat_on else None, sts[i])
                 aux = aux + a
-                new_rngs.append(qat.collect())
+                new_rngs.append(rng)
                 new_sts.append(st)
             x = constrain(x, rules, "batch", "seq", "embed")
             ys = (new_rngs if qat_on else None,
@@ -301,38 +350,31 @@ def forward(params: Params, batch: dict[str, Array], cfg: ModelConfig, *,
     # ---- tail layers (unrolled) ---------------------------------------------
     for i in range(cfg.n_tail):
         bt = cfg.block_pattern[i]
-        qat = L.LayerQAT(
-            _index_ranges(ranges["tail"][i], 0) if qat_on else None,
-            quant_phase, cfg.qat_bits)
         st = states["tail"][i] if has_states else None
-        x, st, a = block_forward(x, params["tail"][i], cfg, bt,
-                                 positions=positions, rules=rules, qat=qat,
-                                 state=st, attn_chunk=attn_chunk,
-                                 unroll=unroll)
+        x, st, a, rng = apply_block(
+            x, params["tail"][i], bt,
+            _index_ranges(ranges["tail"][i], 0) if qat_on else None, st)
         aux_total = aux_total + a
         if qat_on:
-            new_ranges["tail"].append(_unindex_ranges(qat.collect()))
+            new_ranges["tail"].append(_unindex_ranges(rng))
         if has_states:
             new_states["tail"].append(st)
 
     # ---- head ----------------------------------------------------------------
-    x = L.apply_norm(x, params["final_norm"], cfg)
-    qat = L.LayerQAT(_index_ranges(ranges["head"], 0) if qat_on else None,
-                     quant_phase, cfg.qat_bits)
-    if skip_head:
-        # chunked-CE path (§Perf-7): the caller fuses head matmul + loss per
-        # sequence chunk so the (B,S,V) logits never materialize at once.
-        # The head QAT site still applies to the hidden stream here.
-        x = qat.site("head_in", x.reshape(-1, x.shape[-1])).reshape(x.shape)
-        if qat_on:
-            new_ranges["head"] = _unindex_ranges(qat.collect())
-        return x, {"ranges": new_ranges, "states": new_states,
-                   "aux": aux_total}
-    logits = L.lm_head(x, params["embed"], cfg, rules, qat)
+    with jax.named_scope("head"):
+        x = L.apply_norm(x, params["final_norm"], cfg)
+        qat = L.LayerQAT(_index_ranges(ranges["head"], 0) if qat_on else None,
+                         quant_phase, cfg.qat_bits)
+        if skip_head:
+            # chunked-CE path (§Perf-7): the caller fuses head matmul + loss
+            # per sequence chunk so the (B,S,V) logits never materialize at
+            # once.  The head QAT site still applies to the hidden stream here.
+            out = qat.site("head_in", x.reshape(-1, x.shape[-1])).reshape(x.shape)
+        else:
+            out = L.lm_head(x, params["embed"], cfg, rules, qat)
     if qat_on:
         new_ranges["head"] = _unindex_ranges(qat.collect())
-    return logits, {"ranges": new_ranges, "states": new_states,
-                    "aux": aux_total}
+    return out, {"ranges": new_ranges, "states": new_states, "aux": aux_total}
 
 
 def _index_ranges(tree, i):
@@ -365,15 +407,18 @@ def loss_fn(params: Params, batch: dict[str, Array], cfg: ModelConfig, *,
                                  ranges=ranges, quant_phase=quant_phase,
                                  remat=remat, attn_chunk=attn_chunk,
                                  unroll=unroll, skip_head=True)
-        w = (params["embed"]["embedding"].T if cfg.tie_embeddings
-             else params["embed"]["head"]).astype(cfg.compute_dtype)
         nc = s // ce_chunk
-        hc = hidden.reshape(hidden.shape[0], nc, ce_chunk, -1).swapaxes(0, 1)
-        lc = labels.reshape(labels.shape[0], nc, ce_chunk).swapaxes(0, 1)
+        with jax.named_scope("head"):
+            w = (params["embed"]["embedding"].T if cfg.tie_embeddings
+                 else params["embed"]["head"]).astype(cfg.compute_dtype)
+            hc = hidden.reshape(hidden.shape[0], nc, ce_chunk, -1).swapaxes(0, 1)
+            lc = labels.reshape(labels.shape[0], nc, ce_chunk).swapaxes(0, 1)
 
+        @jax.named_scope("head")
         def chunk_nll(carry, xl):
             xc, lab = xl
-            logits = constrain(xc @ w, rules, "batch", "seq", "vocab")
+            logits = constrain(L.scale_logits(xc @ w, cfg), rules, "batch", "seq",
+                               "vocab")
             lf = logits.astype(jnp.float32)
             lse = jax.nn.logsumexp(lf, axis=-1)
             tgt = jnp.take_along_axis(
@@ -383,6 +428,8 @@ def loss_fn(params: Params, batch: dict[str, Array], cfg: ModelConfig, *,
             return (nll_sum + jnp.sum((lse - tgt) * v),
                     v_sum + jnp.sum(v)), None
 
+        if cfg.remat == "block":   # no chunk's logits outlive it
+            chunk_nll = jax.checkpoint(chunk_nll)
         init = (jnp.float32(0), jnp.float32(0))
         if unroll:
             carry = init
@@ -396,13 +443,14 @@ def loss_fn(params: Params, batch: dict[str, Array], cfg: ModelConfig, *,
                                  ranges=ranges, quant_phase=quant_phase,
                                  remat=remat, attn_chunk=attn_chunk,
                                  unroll=unroll)
-        valid = (labels >= 0).astype(jnp.float32)
-        lf = logits.astype(jnp.float32)
-        lse = jax.nn.logsumexp(lf, axis=-1)
-        target = jnp.take_along_axis(
-            lf, jnp.maximum(labels, 0)[..., None], axis=-1)[..., 0]
-        nll = (lse - target) * valid
-        loss = jnp.sum(nll) / jnp.maximum(jnp.sum(valid), 1.0)
+        with jax.named_scope("head"):
+            valid = (labels >= 0).astype(jnp.float32)
+            lf = logits.astype(jnp.float32)
+            lse = jax.nn.logsumexp(lf, axis=-1)
+            target = jnp.take_along_axis(
+                lf, jnp.maximum(labels, 0)[..., None], axis=-1)[..., 0]
+            nll = (lse - target) * valid
+            loss = jnp.sum(nll) / jnp.maximum(jnp.sum(valid), 1.0)
     if cfg.is_moe:
         loss = loss + aux_coef * extras["aux"] / max(cfg.n_layers, 1)
     return loss, extras
@@ -465,7 +513,7 @@ def _block_decode(x, bp, cfg, bt, *, cache, pos, rules, qat):
         h = L.apply_norm(x, bp["ln2"], cfg)
         h = L.mlp_forward(h, bp["ffn"], cfg, rules, qat)
         return x + h, cache
-    raise ValueError(bt)
+    raise ValueError(f"no decode path for {bt!r} blocks")
 
 
 def decode_step(params: Params, tokens: Array, cache: Params, pos: Array,

@@ -10,7 +10,9 @@ maps every instruction to a phase, so a trace's operations can be summed
 per phase by instruction name.
 
   * An instruction's phase is the first component of its `op_name` that is
-    one of the phases.
+    one of the phases.  Autodiff renders a scope that it differentiates
+    whole as `jvp(<scope>)`, and its backward as `transpose(jvp(<scope>))`:
+    such a component counts as the scope it wraps.
   * An instruction with none, such as a layout `copy` the compiler
     inserted, takes the phase of its users when they all agree on one.
     Users are followed through `copy-start`/`copy-done` and any other
@@ -27,6 +29,14 @@ UNSCOPED = "unscoped"
 _INSTRUCTION = re.compile(r"\s*(?:ROOT\s+)?%([\w.\-]+) = ")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _NAME = re.compile(r"%([\w.\-]+)")
+_TRANSFORMED = re.compile(r"^(?:jvp|transpose)\((.*)\)$")
+
+
+def _scope(component: str) -> str:
+    """`transpose(jvp(ffn))` -> `ffn`; any other component as it is."""
+    while (m := _TRANSFORMED.match(component)) is not None:
+        component = m.group(1)
+    return component
 
 
 def _group_end(s: str, i: int) -> int:
@@ -63,7 +73,7 @@ def op_phases(hlo_text: str, phases: Iterable[str]) -> dict[str, str]:
             continue
         rhs = line[m.end():]
         meta = _OP_NAME.search(rhs)
-        parts = meta.group(1).split("/") if meta else ()
+        parts = [_scope(p) for p in meta.group(1).split("/")] if meta else ()
         own[m.group(1)] = next((p for p in parts if p in wanted), UNSCOPED)
         for operand in _operands(rhs):
             users.setdefault(operand, []).append(m.group(1))

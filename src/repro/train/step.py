@@ -8,6 +8,15 @@ memories), and the per-layer ranges thread through the layer scan.
 
 Microbatching (gradient accumulation) runs as a `lax.scan` over microbatch
 slices with an f32 grad accumulator — the standard large-batch recipe.
+
+The step's phases are `jax.named_scope`s (`PHASES`), disjoint, so each
+compiled instruction belongs to one (`repro.obs.phases.op_phases`): the
+embedding, the Mamba-2 mixers' projections, conv and gated norm
+(`mamba_proj`) apart from their SSD scan (`ssd`), attention, the
+feed-forward halves (`ffn`), the final norm, head and loss (`head`), and the
+gradients' lattice, clip, Adam and the weight lattice (`optimizer`).  A
+block's norm and residual add fall in its sublayer's phase; the backward
+and rematerialized ops keep their forward phase.
 """
 from __future__ import annotations
 
@@ -25,6 +34,8 @@ from repro.optim import adam
 
 Array = jax.Array
 Params = dict[str, Any]
+
+PHASES = ("embed", "mamba_proj", "ssd", "attn", "ffn", "head", "optimizer")
 
 
 @jax.tree_util.register_dataclass
@@ -89,12 +100,13 @@ def make_train_step(cfg: ModelConfig, opt_cfg: adam.AdamConfig, *,
             grads = jax.tree.map(lambda g: g / n_microbatches, gsum)
             loss = jnp.mean(losses)
 
-        if cfg.qat:  # fxp32 gradient memory
-            grads = quantize_grads(grads)
-        new_params, new_opt, metrics = adam.update(
-            opt_cfg, grads, state.opt, state.params)
-        if cfg.qat:  # fxp32 weight memory
-            new_params = quantize_weights(new_params)
+        with jax.named_scope("optimizer"):
+            if cfg.qat:  # fxp32 gradient memory
+                grads = quantize_grads(grads)
+            new_params, new_opt, metrics = adam.update(
+                opt_cfg, grads, state.opt, state.params)
+            if cfg.qat:  # fxp32 weight memory
+                new_params = quantize_weights(new_params)
 
         metrics = dict(metrics, loss=loss,
                        quant_phase=quant_phase.astype(jnp.int32))

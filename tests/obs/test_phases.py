@@ -74,3 +74,21 @@ def test_phases_name_a_compiled_jax_program():
     phases = op_phases(text, PHASES)
     assert set(phases.values()) <= {"act", "env", UNSCOPED}
     assert {"act", "env"} & set(phases.values())
+
+
+def test_a_differentiated_scope_keeps_its_phase():
+    # autodiff renders a scope it differentiates whole as jvp(<scope>) and
+    # its backward as transpose(jvp(<scope>)): both are the scope's
+    def f(w, x):
+        with jax.named_scope("act"):
+            h = jnp.tanh(x @ w)
+        with jax.named_scope("env"):
+            return jnp.sum(h * h)
+
+    text = jax.jit(jax.grad(f)).lower(jnp.ones((8, 8)), jnp.ones((4, 8))).compile().as_text()
+    assert "transpose(jvp(act))" in text or "jvp(act)" in text
+    phases = op_phases(text, PHASES)
+    assert {"act", "env"} <= set(phases.values())
+    line = "%m = f32[4,8]{1,0} multiply(f32[4,8]{1,0} %a, f32[4,8]{1,0} %b), " \
+        'metadata={op_name="jit(f)/transpose(jvp(env))/mul"}'
+    assert op_phases(line, PHASES)["m"] == "env"

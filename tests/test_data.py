@@ -48,3 +48,24 @@ def test_audio_batch_has_masked_targets():
     assert "tokens" not in b
     frac = float((b["labels"] >= 0).mean())
     assert 0.0 < frac < 0.3
+
+
+def test_packed_documents():
+    cfg = registry.get_smoke("granite_4_0_h_micro")
+    dc = DataConfig(seed=2 ** 31 + 3, packed=True, doc_len_median=20, doc_len_sigma=1.2,
+                    doc_len_min=4, doc_len_max=64)
+    shape = ShapeConfig("t", "train", 256, 4)
+    b = make_batch(dc, cfg, shape, 0)
+    seg, tok, lab = (np.asarray(b[k]) for k in ("segment_ids", "tokens", "labels"))
+    assert seg.shape == tok.shape == lab.shape == (4, 256)
+    assert (np.diff(seg, axis=1) >= 0).all() and (seg[:, 0] == 0).all()
+    lengths = [np.bincount(row)[:-1] for row in seg]    # the last document is cut
+    assert all(((n >= 4) & (n <= 64)).all() for n in lengths)
+    same = seg[:, 1:] == seg[:, :-1]
+    # a label is the next token inside a document, -100 where one starts and at the end
+    assert (lab[:, :-1][same] == tok[:, 1:][same]).all()
+    assert (lab[:, :-1][~same] == -100).all() and (lab[:, -1] == -100).all()
+    again = make_batch(dc, cfg, shape, 0)
+    assert np.array_equal(np.asarray(again["segment_ids"]), seg)
+    other = make_batch(dc, cfg, shape, 1)
+    assert not np.array_equal(np.asarray(other["segment_ids"]), seg)
