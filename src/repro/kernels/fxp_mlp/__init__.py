@@ -69,6 +69,11 @@ Design
   The kernel-computed actions (launch 2) and target actions (launch 1) are
   lane-rotated next to the observations (`pltpu.roll`), so the critic's
   first layer runs the same concat-input dot as the custom-VJP path.
+  `fxp_mlp_train_step_padded` is the step on the kernels' own layout (every
+  leaf zero-padded to lane tiles, and left exactly 0 there), each launch
+  updating its net's params, moments and target in place; the scanned
+  training window carries the agent so (`ddpg.pad_state`), and
+  `fxp_mlp_train_step` pads and unpads around it for one-off callers.
 
 Train-time dispatch (`serve/policy` + `train/learner`) chooses between
 `fused_step` (2 launches, best at large batch), `fused` (the 8-launch
@@ -87,8 +92,9 @@ tests/kernels/test_fxp_mlp_grad.py, whole-step parity + the ≤2-launch
 regression in tests/kernels/test_fxp_mlp_step.py.
 """
 from repro.kernels.fxp_mlp.ops import (fxp_mlp_forward, fxp_mlp_infer,
-                                       fxp_mlp_train, fxp_mlp_train_step)
+                                       fxp_mlp_train, fxp_mlp_train_step,
+                                       fxp_mlp_train_step_padded)
 from repro.kernels.fxp_mlp.ref import ref_fxp_mlp
 
 __all__ = ["fxp_mlp_forward", "fxp_mlp_infer", "fxp_mlp_train",
-           "fxp_mlp_train_step", "ref_fxp_mlp"]
+           "fxp_mlp_train_step", "fxp_mlp_train_step_padded", "ref_fxp_mlp"]

@@ -717,15 +717,26 @@ def _batch_spec(bm, a):
     return pl.BlockSpec((bm, a.shape[1]), lambda i, ph: (i, 0))
 
 
-def _step_call(kern, phase, batch, consts, smem, p_wb, *, bm: int,
+def _step_call(kern, phase, batch, consts, smem, p_wb, updated, *, bm: int,
                interpret: bool, name: str):
     """The pallas_call shared by both step launches: batch arrays blocked
     by row, every parameter leaf VMEM-resident (constant index map), the
     SMEM scalars, and as outputs the new params, moments and targets of
-    the trained net plus the per-block stats tiles.  `name` names the
-    launch's instruction in the compiled program, and so in a device
-    trace: `%fxp_mlp_train_step_critic.N`, `%fxp_mlp_train_step_actor.N`."""
+    the trained net plus the per-block stats tiles.  `consts` is groups of
+    len(p_wb) leaves; `updated` names, for the new params, moments and
+    targets in turn, the group each one replaces, and each output is
+    aliased to its group's buffers: updated in place, so a caller that
+    carries them (as the training loop's scan does) gets no copies around
+    the launch.  Every input leaf is read before its aliased output is
+    written (the epilogue reads a leaf's p, m, v, t and then writes them).
+    `name` names the launch's instruction in the compiled program, and so
+    in a device trace: `%fxp_mlp_train_step_critic.N`,
+    `%fxp_mlp_train_step_actor.N`."""
     n_blocks = batch[0].shape[0] // bm
+    n = len(p_wb)
+    first = 1 + len(batch)    # the scalar-prefetch phase, then the batch
+    aliases = {first + g * n + j: k * n + j
+               for k, g in enumerate(updated) for j in range(n)}
     max_np = max(a.shape[1] for a in consts)
     args = [*batch, *consts, *smem]
     in_specs = ([_batch_spec(bm, a) for a in batch]
@@ -752,11 +763,11 @@ def _step_call(kern, phase, batch, consts, smem, p_wb, *, bm: int,
         grid_spec=grid_spec,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=32 * 2**20),
         interpret=interpret,
         name=name,
+        input_output_aliases=aliases,
     )(phase, *args)
-    n = len(p_wb)
     return ([list(outs[k * n:(k + 1) * n]) for k in range(4)]
             + [outs[4 * n]])
 
@@ -788,7 +799,8 @@ def ddpg_critic_step_pallas(phase, xc, nobs, aux, at_wb, ct_wb, c_wb, m_wb,
         fxp_weights=fxp_weights)
     return _step_call(kern, phase, (xc, nobs, aux),
                       (*at_wb, *ct_wb, *c_wb, *m_wb, *v_wb),
-                      (deltas, zs, hyper), c_wb, bm=bm, interpret=interpret,
+                      (deltas, zs, hyper), c_wb, (2, 3, 4, 1), bm=bm,
+                      interpret=interpret,
                       name="fxp_mlp_train_step_critic")
 
 
@@ -815,5 +827,6 @@ def ddpg_actor_step_pallas(phase, obs, aux, a_wb, m_wb, v_wb, at_wb, c_wb,
         fxp32_phase1=fxp32_phase1, fxp_weights=fxp_weights)
     return _step_call(kern, phase, (obs, aux),
                       (*a_wb, *m_wb, *v_wb, *at_wb, *c_wb),
-                      (deltas, zs, hyper), a_wb, bm=bm, interpret=interpret,
+                      (deltas, zs, hyper), a_wb, (0, 1, 2, 3), bm=bm,
+                      interpret=interpret,
                       name="fxp_mlp_train_step_actor")

@@ -38,25 +38,43 @@ def _row_block(m: int) -> int:
     return min(128, _round_up(m, 8))
 
 
+def pad_weight(w: Array) -> Array:
+    """A (K, N) weight in the kernels' layout: (round_up(K, 128),
+    round_up(N, 128)) float32, zero-filled."""
+    k, n = w.shape
+    return jnp.pad(w.astype(jnp.float32),
+                   ((0, _round_up(k, 128) - k), (0, _round_up(n, 128) - n)))
+
+
+def pad_bias(b: Array) -> Array:
+    """An (N,) bias in the kernels' layout: (1, round_up(N, 128)) float32,
+    zero-filled."""
+    n = b.shape[0]
+    return jnp.pad(b.astype(jnp.float32),
+                   (0, _round_up(n, 128) - n)).reshape(1, -1)
+
+
+def _pad_x(x: Array):
+    """Pad the batch to bm rows and its features to 128 lanes.
+
+    Returns (x2 padded (Mp, K0p), m valid rows, bm row-block)."""
+    k0 = x.shape[-1]
+    x2 = x.reshape(-1, k0).astype(jnp.float32)
+    m = x2.shape[0]
+    bm = _row_block(m)
+    mp = _round_up(m, bm)
+    return jnp.pad(x2, ((0, mp - m), (0, _round_up(k0, 128) - k0))), m, bm
+
+
 def _pad_net(x: Array, weights: Sequence[Array], biases: Sequence[Array]):
     """Pad the batch to bm rows and every feature dim to 128 lanes.
 
     Returns (x2 padded (Mp, K0p), padded weights, padded (1, Np) biases,
     m valid rows, bm row-block).
     """
-    k0 = x.shape[-1]
-    x2 = x.reshape(-1, k0).astype(jnp.float32)
-    m = x2.shape[0]
-    bm = _row_block(m)
-    mp = _round_up(m, bm)
-    x2 = jnp.pad(x2, ((0, mp - m), (0, _round_up(k0, 128) - k0)))
-    wp, bp = [], []
-    for w, b in zip(weights, biases):
-        k, n = w.shape
-        kp, np_ = _round_up(k, 128), _round_up(n, 128)
-        wp.append(jnp.pad(w.astype(jnp.float32), ((0, kp - k), (0, np_ - n))))
-        bp.append(jnp.pad(b.astype(jnp.float32), (0, np_ - n)).reshape(1, np_))
-    return x2, tuple(wp), tuple(bp), m, bm
+    x2, m, bm = _pad_x(x)
+    return (x2, tuple(pad_weight(w) for w in weights),
+            tuple(pad_bias(b) for b in biases), m, bm)
 
 
 def _norm_quant_params(deltas, zs, n_layers: int, qat: bool):
@@ -131,11 +149,15 @@ class _TrainSpec(NamedTuple):
     qat: bool
     fxp32_phase1: bool
     interpret: bool
+    padded: bool         # weights and biases arrive in the kernels' layout
 
 
 def _train_fwd_call(spec: _TrainSpec, phase_f, x, weights, biases,
                     deltas, zs, save_residuals: bool):
-    x2, wp, bp, m, bm = _pad_net(x, weights, biases)
+    if spec.padded:
+        (x2, m, bm), wp, bp = _pad_x(x), tuple(weights), tuple(biases)
+    else:
+        x2, wp, bp, m, bm = _pad_net(x, weights, biases)
     phase = (phase_f > 0).astype(jnp.int32).reshape(1)
     outs = fxp_mlp_pallas(
         phase, x2, wp, bp, deltas, zs,
@@ -189,8 +211,11 @@ def _mlp_train_core_bwd(spec: _TrainSpec, res, cts):
         interpret=spec.interpret)
 
     dx = dxp[:m, :dims[0]].reshape(*gy.shape[:-1], dims[0])
-    dws = tuple(dwps[i][:dims[i], :dims[i + 1]] for i in range(n_layers))
-    dbs = tuple(dbps[i][0, :dims[i + 1]] for i in range(n_layers))
+    if spec.padded:   # cotangents in the layout the primal came in
+        dws, dbs = tuple(dwps), tuple(dbps)
+    else:
+        dws = tuple(dwps[i][:dims[i], :dims[i + 1]] for i in range(n_layers))
+        dbs = tuple(dbps[i][0, :dims[i + 1]] for i in range(n_layers))
     return (jnp.zeros_like(phase_f), dx, dws, dbs,
             jnp.zeros_like(deltas), jnp.zeros_like(zs))
 
@@ -199,18 +224,24 @@ _mlp_train_core.defvjp(_mlp_train_core_fwd, _mlp_train_core_bwd)
 
 
 @functools.partial(jax.jit, static_argnames=("activations", "n_bits", "qat",
-                                             "fxp32_phase1", "interpret"))
+                                             "fxp32_phase1", "interpret",
+                                             "dims"))
 def fxp_mlp_train(x: Array, weights: tuple, biases: tuple,
                   deltas: Optional[Array] = None,
                   zs: Optional[Array] = None, *,
                   activations: Sequence[str], quant_phase: Array,
                   n_bits: int = 16, qat: bool = True,
                   fxp32_phase1: bool = True,
-                  interpret: Optional[bool] = None
+                  interpret: Optional[bool] = None,
+                  dims: Optional[tuple] = None
                   ) -> tuple[Array, Array, Array]:
     """Differentiable fused forward — `fxp_mlp_forward` with a custom VJP.
 
-    Same signature and return value as `fxp_mlp_forward`.  Under `jax.grad`
+    Same signature and return value as `fxp_mlp_forward`.  `dims`, when
+    given, is the unpadded layer widths (K0, N1, ..., NL) and says that
+    `weights`/`biases` already come in the kernels' layout ((Kp, Np) and
+    (1, Np), zero-padded: `pad_weight`/`pad_bias`), so only `x` is padded
+    here and weight cotangents come back in that layout.  Under `jax.grad`
     the fwd rule saves per-layer residuals in the same single launch and the
     bwd rule runs the whole dW/db/dx chain as ONE network-resident backward
     Pallas kernel; without differentiation the primal is the plain fused
@@ -227,14 +258,18 @@ def fxp_mlp_train(x: Array, weights: tuple, biases: tuple,
         f"{len(activations)} activations")
     if interpret is None:
         interpret = interpret_mode()
-    assert weights[0].shape[0] == x.shape[-1], (
-        f"layer-0 input dim {weights[0].shape[0]} != x feature dim "
-        f"{x.shape[-1]}")
-    dims = (int(x.shape[-1]),) + tuple(int(w.shape[-1]) for w in weights)
-    spec = _TrainSpec(activations=tuple(activations), dims=dims,
+    padded = dims is not None
+    if not padded:
+        assert weights[0].shape[0] == x.shape[-1], (
+            f"layer-0 input dim {weights[0].shape[0]} != x feature dim "
+            f"{x.shape[-1]}")
+        dims = (int(x.shape[-1]),) + tuple(int(w.shape[-1]) for w in weights)
+    assert dims[0] == x.shape[-1] and len(dims) == n_layers + 1, (
+        f"dims {dims} do not describe {n_layers} layers on x {x.shape}")
+    spec = _TrainSpec(activations=tuple(activations), dims=tuple(dims),
                       n_bits=int(n_bits), qat=bool(qat),
                       fxp32_phase1=bool(fxp32_phase1),
-                      interpret=bool(interpret))
+                      interpret=bool(interpret), padded=padded)
     deltas, zs = _norm_quant_params(deltas, zs, n_layers, qat)
     # float carrier so the custom_vjp boundary has a float (zero) cotangent
     phase_f = jnp.asarray(quant_phase).astype(jnp.float32).reshape(())
@@ -293,18 +328,18 @@ def fused_cost_hint(dims: Sequence[int], phase: str = "act") -> dict:
 # ---------------------------------------------------------------------------
 
 
-def _pad_wb(ws: Sequence[Array], bs: Sequence[Array]) -> list:
+def pad_wb(ws: Sequence[Array], bs: Sequence[Array]) -> list:
     """Pad per-layer (w, b) leaves to lane tiles, interleaved
     [w0, b0, w1, b1, ...] — the layout the fused-step kernels consume."""
-    out = []
-    for w, b in zip(ws, bs):
-        k, n = w.shape
-        kp, np_ = _round_up(k, 128), _round_up(n, 128)
-        out.append(jnp.pad(w.astype(jnp.float32),
-                           ((0, kp - k), (0, np_ - n))))
-        out.append(jnp.pad(b.astype(jnp.float32),
-                           (0, np_ - n)).reshape(1, np_))
-    return out
+    return [a for w, b in zip(ws, bs) for a in (pad_weight(w), pad_bias(b))]
+
+
+def unpad_wb(wbp: Sequence[Array], dims: Sequence[int]) -> tuple:
+    """Inverse of `pad_wb`: ((w per layer), (b per layer)) cut back to the
+    unpadded widths `dims` (K0, N1, ..., NL)."""
+    n = len(dims) - 1
+    return (tuple(wbp[2 * i][:dims[i], :dims[i + 1]] for i in range(n)),
+            tuple(wbp[2 * i + 1][0, :dims[i + 1]] for i in range(n)))
 
 
 def _pad_batch(a: Array, mp: int) -> Array:
@@ -315,13 +350,18 @@ def _pad_batch(a: Array, mp: int) -> Array:
 
 
 class TrainStepOut(NamedTuple):
-    """Everything `ddpg._update_fused_step` needs back from the 2 launches."""
+    """Everything `ddpg.update` needs back from the 2 launches.
 
-    actor: tuple          # (ws, bs) unpadded
+    The eight parameter fields are, from `fxp_mlp_train_step_padded`, the
+    interleaved [w0, b0, w1, b1, ...] leaves in the kernels' layout
+    (zero-padded, exactly 0 outside the unpadded widths), and from
+    `fxp_mlp_train_step`, ((w per layer), (b per layer)) unpadded."""
+
+    actor: tuple
     critic: tuple
     actor_t: tuple
     critic_t: tuple
-    actor_m: tuple        # ((w moments), (b moments)) unpadded
+    actor_m: tuple        # Adam's moments, laid out like the params
     actor_v: tuple
     critic_m: tuple
     critic_v: tuple
@@ -335,45 +375,49 @@ class TrainStepOut(NamedTuple):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "actor_acts", "critic_acts", "obs_dim", "act_dim", "gamma", "tau",
+    "actor_acts", "critic_acts", "actor_dims", "critic_dims", "gamma", "tau",
     "n_bits", "qat", "fxp32_phase1", "fxp_weights", "interpret"))
-def fxp_mlp_train_step(obs, action, reward, done, next_obs, w,
-                       actor_wb, critic_wb, actor_t_wb, critic_t_wb,
-                       actor_m, actor_v, critic_m, critic_v,
-                       deltas, zs, consts_c, consts_a, quant_phase, *,
-                       actor_acts, critic_acts, obs_dim: int, act_dim: int,
-                       gamma: float, tau: float, n_bits: int = 16,
-                       qat: bool = True, fxp32_phase1: bool = True,
-                       fxp_weights: bool = True,
-                       interpret: Optional[bool] = None) -> TrainStepOut:
-    """One whole DDPG update in TWO Pallas launches.
+def fxp_mlp_train_step_padded(obs, action, reward, done, next_obs, w,
+                              actor_wb, critic_wb, actor_t_wb, critic_t_wb,
+                              actor_m, actor_v, critic_m, critic_v,
+                              deltas, zs, consts_c, consts_a, quant_phase, *,
+                              actor_acts, critic_acts, actor_dims: tuple,
+                              critic_dims: tuple, gamma: float, tau: float,
+                              n_bits: int = 16, qat: bool = True,
+                              fxp32_phase1: bool = True,
+                              fxp_weights: bool = True,
+                              interpret: Optional[bool] = None
+                              ) -> TrainStepOut:
+    """One whole DDPG update in TWO Pallas launches, on and to the
+    kernels' layout.
 
     Launch 1 (critic step): target-actor fwd, target-critic fwd, TD target,
     online-critic fwd with monitors, weighted-MSE backward, Adam, target
     soft update — params, residuals, and grad accumulators all
     network-resident.  Launch 2 (actor step): actor fwd, updated-critic fwd,
     policy-gradient backward (dx-only through the critic), Adam, target soft
-    update.  Every *_wb / moment argument is ((w per layer), (b per layer))
-    of UNPADDED leaves; `consts_c` / `consts_a` are `adam.StepConstants` for
-    the post-increment critic/actor optimizer steps; `w` is the (B,) sample
-    weight vector (ones when the batch carries no mask).  `gamma`/`tau` are
-    static floats so their complements fold in double precision, matching
-    the host path bit-for-bit.
+    update.  Every *_wb / moment argument is the interleaved
+    [w0, b0, w1, b1, ...] leaves of one net zero-padded to lane tiles
+    (`pad_weight`, `pad_bias`), and the eight parameter fields of the
+    result come back the same way: the padded regions stay exactly 0
+    (zero inputs there give zero grads, and Adam and the soft update map
+    zeros to zeros), so a caller can carry them from update to update.
+    `actor_dims` / `critic_dims` are the unpadded widths (K0, N1, ..., NL);
+    the batch arrays are unpadded.  `consts_c` / `consts_a` are
+    `adam.StepConstants` for the post-increment critic/actor optimizer
+    steps; `w` is the (B,) sample weight vector (ones when the batch carries
+    no mask).  `gamma`/`tau` are static floats so their complements fold in
+    double precision, matching the host path bit-for-bit.
     """
     assert HYPER_LEN == 12
     if interpret is None:
         interpret = interpret_mode()
 
-    a_ws, a_bs = actor_wb
-    c_ws, c_bs = critic_wb
-    L = len(a_ws)
+    L = len(actor_wb) // 2
+    obs_dim, act_dim = actor_dims[0], actor_dims[-1]
     b_rows = obs.shape[0]
     bm = _row_block(b_rows)
     mp = _round_up(b_rows, bm)
-
-    actor_in_dims = (obs_dim,) + tuple(int(x.shape[0]) for x in a_ws[1:])
-    critic_in_dims = ((obs_dim + act_dim,)
-                      + tuple(int(x.shape[0]) for x in c_ws[1:]))
 
     obs_p = _pad_batch(obs.astype(jnp.float32), mp)
     nobs_p = _pad_batch(next_obs.astype(jnp.float32), mp)
@@ -382,15 +426,6 @@ def fxp_mlp_train_step(obs, action, reward, done, next_obs, w,
     aux_p = _pad_batch(
         jnp.stack([reward.reshape(-1), done.reshape(-1),
                    w.reshape(-1)], axis=-1), mp)
-
-    a_wbp = _pad_wb(a_ws, a_bs)
-    c_wbp = _pad_wb(c_ws, c_bs)
-    at_wbp = _pad_wb(*actor_t_wb)
-    ct_wbp = _pad_wb(*critic_t_wb)
-    am_p = _pad_wb(*actor_m)
-    av_p = _pad_wb(*actor_v)
-    cm_p = _pad_wb(*critic_m)
-    cv_p = _pad_wb(*critic_v)
 
     inv_w = 1.0 / jnp.maximum(jnp.sum(w.astype(jnp.float32)), 1.0)
     # (1 - tau) folded in Python double then cast, exactly like the host
@@ -408,47 +443,71 @@ def fxp_mlp_train_step(obs, action, reward, done, next_obs, w,
     phase = jnp.asarray(quant_phase, jnp.int32).reshape(1)
 
     ncp, ncm, ncv, nct, stats1 = ddpg_critic_step_pallas(
-        phase, xc_p, nobs_p, aux_p, at_wbp, ct_wbp, c_wbp, cm_p, cv_p,
-        deltas2, zs2, hyper_c, obs_dim=obs_dim, actor_acts=actor_acts,
-        critic_acts=critic_acts, critic_in_dims=critic_in_dims,
-        m_valid=b_rows, bm=bm, n_bits=n_bits, qat=qat,
-        fxp32_phase1=fxp32_phase1, fxp_weights=fxp_weights,
-        interpret=interpret)
+        phase, xc_p, nobs_p, aux_p, actor_t_wb, critic_t_wb, critic_wb,
+        critic_m, critic_v, deltas2, zs2, hyper_c, obs_dim=obs_dim,
+        actor_acts=actor_acts, critic_acts=critic_acts,
+        critic_in_dims=critic_dims[:-1], m_valid=b_rows, bm=bm,
+        n_bits=n_bits, qat=qat, fxp32_phase1=fxp32_phase1,
+        fxp_weights=fxp_weights, interpret=interpret)
 
     # launch 2 sees the UPDATED critic
     nap, nam, nav, nat, stats2 = ddpg_actor_step_pallas(
-        phase, obs_p, aux_p, a_wbp, am_p, av_p, at_wbp, ncp, deltas2, zs2,
-        hyper_a, obs_dim=obs_dim,
+        phase, obs_p, aux_p, actor_wb, actor_m, actor_v, actor_t_wb, ncp,
+        deltas2, zs2, hyper_a, obs_dim=obs_dim,
         act_dim=act_dim, actor_acts=actor_acts, critic_acts=critic_acts,
-        actor_in_dims=actor_in_dims, critic_in_dims=critic_in_dims,
+        actor_in_dims=actor_dims[:-1], critic_in_dims=critic_dims[:-1],
         m_valid=b_rows, bm=bm, n_bits=n_bits, qat=qat,
         fxp32_phase1=fxp32_phase1, fxp_weights=fxp_weights,
         interpret=interpret)
 
     c_mins, c_maxs, part1 = block_stats(stats1, L, 2)
     a_mins, a_maxs, part2 = block_stats(stats2, 2 * L, 1)
-
-    def unpad(wbp, ws_ref, bs_ref):
-        ws = tuple(wbp[2 * i][:w.shape[0], :w.shape[1]]
-                   for i, w in enumerate(ws_ref))
-        bs = tuple(wbp[2 * i + 1][0, :b.shape[0]]
-                   for i, b in enumerate(bs_ref))
-        return ws, bs
-
     return TrainStepOut(
-        actor=unpad(nap, a_ws, a_bs),
-        critic=unpad(ncp, c_ws, c_bs),
-        actor_t=unpad(nat, a_ws, a_bs),
-        critic_t=unpad(nct, c_ws, c_bs),
-        actor_m=unpad(nam, a_ws, a_bs),
-        actor_v=unpad(nav, a_ws, a_bs),
-        critic_m=unpad(ncm, c_ws, c_bs),
-        critic_v=unpad(ncv, c_ws, c_bs),
-        closs_sum=part1[0],
-        y_sum=part1[1],
-        q_sum=part2[0],
-        c_mins=c_mins,
-        c_maxs=c_maxs,
-        a_mins=a_mins,
-        a_maxs=a_maxs,
-    )
+        actor=tuple(nap), critic=tuple(ncp), actor_t=tuple(nat),
+        critic_t=tuple(nct), actor_m=tuple(nam), actor_v=tuple(nav),
+        critic_m=tuple(ncm), critic_v=tuple(ncv),
+        closs_sum=part1[0], y_sum=part1[1], q_sum=part2[0],
+        c_mins=c_mins, c_maxs=c_maxs, a_mins=a_mins, a_maxs=a_maxs)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "actor_acts", "critic_acts", "obs_dim", "act_dim", "gamma", "tau",
+    "n_bits", "qat", "fxp32_phase1", "fxp_weights", "interpret"))
+def fxp_mlp_train_step(obs, action, reward, done, next_obs, w,
+                       actor_wb, critic_wb, actor_t_wb, critic_t_wb,
+                       actor_m, actor_v, critic_m, critic_v,
+                       deltas, zs, consts_c, consts_a, quant_phase, *,
+                       actor_acts, critic_acts, obs_dim: int, act_dim: int,
+                       gamma: float, tau: float, n_bits: int = 16,
+                       qat: bool = True, fxp32_phase1: bool = True,
+                       fxp_weights: bool = True,
+                       interpret: Optional[bool] = None) -> TrainStepOut:
+    """`fxp_mlp_train_step_padded` on unpadded leaves: every *_wb / moment
+    argument, and each of the eight parameter fields of the result, is
+    ((w per layer), (b per layer)) of UNPADDED leaves.  It pads all eight
+    on the way in and slices them back out on the way out, every call; a
+    caller that runs many updates carries the padded leaves instead
+    (`ddpg.pad_state`)."""
+    a_ws, a_bs = actor_wb
+    c_ws, c_bs = critic_wb
+    actor_dims = (obs_dim,) + tuple(int(x.shape[1]) for x in a_ws)
+    critic_dims = (obs_dim + act_dim,) + tuple(int(x.shape[1]) for x in c_ws)
+    assert actor_dims[-1] == act_dim, (actor_dims, act_dim)
+
+    out = fxp_mlp_train_step_padded(
+        obs, action, reward, done, next_obs, w,
+        *(pad_wb(*wb) for wb in (actor_wb, critic_wb, actor_t_wb,
+                                  critic_t_wb, actor_m, actor_v, critic_m,
+                                  critic_v)),
+        deltas, zs, consts_c, consts_a, quant_phase,
+        actor_acts=actor_acts, critic_acts=critic_acts,
+        actor_dims=actor_dims, critic_dims=critic_dims, gamma=gamma, tau=tau,
+        n_bits=n_bits, qat=qat, fxp32_phase1=fxp32_phase1,
+        fxp_weights=fxp_weights, interpret=interpret)
+
+    a, c = actor_dims, critic_dims
+    return out._replace(
+        actor=unpad_wb(out.actor, a), critic=unpad_wb(out.critic, c),
+        actor_t=unpad_wb(out.actor_t, a), critic_t=unpad_wb(out.critic_t, c),
+        actor_m=unpad_wb(out.actor_m, a), actor_v=unpad_wb(out.actor_v, a),
+        critic_m=unpad_wb(out.critic_m, c), critic_v=unpad_wb(out.critic_v, c))

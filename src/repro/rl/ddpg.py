@@ -35,7 +35,8 @@ from repro.core.qat import (FrozenQuant, QATContext, QATState, freeze_quant,
                             quantize_grads)
 from repro.kernels.fxp_matmul.ops import fxp_dense, fxp_dense_chain
 from repro.kernels.fxp_mlp.ops import (fxp_mlp_infer, fxp_mlp_train,
-                                       fxp_mlp_train_step)
+                                       fxp_mlp_train_step_padded, pad_bias,
+                                       pad_weight, unpad_wb)
 from repro.optim import adam, fxp_adam
 from repro.rl.envs.base import EnvSpec
 
@@ -77,6 +78,24 @@ class DDPGState:
     step: Array
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class PaddedDDPGState:
+    """A `DDPGState` in the fused kernels' layout: each weight zero-padded
+    to (round_up(K, 128), round_up(N, 128)) float32 and each bias to
+    (1, round_up(N, 128)), for the params, both targets and all four Adam
+    moments, with the unpadded widths (K0, N1, ..., NL) of each net.
+
+    The fused update (backend "pallas_fused_step") keeps the padded regions
+    exactly 0, so a loop that runs many updates pads once (`pad_state`),
+    carries this through `act` and `update`, and unpads once
+    (`unpad_state`): no update pads or slices the eight parameter sets."""
+
+    agent: DDPGState
+    actor_dims: tuple = dataclasses.field(metadata=dict(static=True))
+    critic_dims: tuple = dataclasses.field(metadata=dict(static=True))
+
+
 def _init_linear(key, fan_in: int, fan_out: int, final: bool = False):
     """DDPG init: uniform(±1/sqrt(fan_in)); final layer uniform(±3e-3)."""
     kw, kb = jax.random.split(key)
@@ -109,26 +128,29 @@ def _dense(x, layer, activation: str, *, backend: str, quant_phase) -> Array:
 
 
 def _fused_mlp(params: Params, x: Array, ctx: Optional[QATContext],
-               *, sites: list[str], activations: tuple[str, ...]) -> Array:
+               *, sites: list[str], activations: tuple[str, ...],
+               dims: Optional[tuple[int, ...]] = None) -> Array:
     """Whole-network forward through the fused kernel (kernels/fxp_mlp):
     one Pallas call, weights VMEM-resident, QAT sites fused in-pipeline.
     Range observations flow back into `ctx` via `observe`, so QAT state
     evolves identically to the per-layer path.  `fxp_mlp_train` carries the
     custom VJP: pure inference runs the plain fused forward, while under
-    `jax.grad` the backward chain is one more network-resident launch."""
+    `jax.grad` the backward chain is one more network-resident launch.
+    `dims` (the unpadded widths) says `params` is in the kernels' layout."""
     n = len(activations)
     ws = tuple(params[f"l{i}"]["w"] for i in range(n))
     bs = tuple(params[f"l{i}"]["b"] for i in range(n))
     if ctx is None or not ctx.state.config.enabled:
         y, _, _ = fxp_mlp_train(x, ws, bs, activations=activations,
-                                quant_phase=jnp.array(False), qat=False)
+                                quant_phase=jnp.array(False), qat=False,
+                                dims=dims)
         return y
     cfg = ctx.state.config
     deltas, zs = ctx.site_quant_params(sites)
     y, mns, mxs = fxp_mlp_train(
         x, ws, bs, deltas, zs, activations=activations,
         quant_phase=ctx.state.quantized_phase, n_bits=cfg.n_bits,
-        fxp32_phase1=cfg.fxp32_phase1)
+        fxp32_phase1=cfg.fxp32_phase1, dims=dims)
     for j, site in enumerate(sites):
         ctx.observe(site, mns[j], mxs[j])
     return y
@@ -185,7 +207,7 @@ def init(key: Array, spec: EnvSpec, cfg: DDPGConfig) -> DDPGState:
         qat=qat, step=jnp.zeros((), jnp.int32))
 
 
-def act(state: DDPGState, obs: Array, *, cfg: DDPGConfig,
+def act(state: DDPGState | PaddedDDPGState, obs: Array, *, cfg: DDPGConfig,
         noise_key: Optional[Array] = None,
         noise: Optional[Array] = None) -> Array:
     """Actor inference (+ the PRNG exploration-noise unit of Fig. 2).
@@ -195,12 +217,19 @@ def act(state: DDPGState, obs: Array, *, cfg: DDPGConfig,
     surface), while `noise` adds a caller-supplied perturbation — the hook
     `rl/loop` uses to thread `rl/noise.NoiseProcess` samples (Gaussian or
     OU, explicit `NoiseState` carry) through the scanned device loop.
-    Either way the perturbation lands pre-clip.
+    Either way the perturbation lands pre-clip.  A `PaddedDDPGState` acts
+    through the fused kernel on its padded weights as they are.
     """
+    padded = isinstance(state, PaddedDDPGState)
+    agent = state.agent if padded else state
     # no-QAT fast path: don't materialize a context (which re-derives quant
     # params from the range tree) when every site would be a pass-through
-    ctx = QATContext(state.qat) if state.qat.config.enabled else None
-    a = actor_forward(state.actor, obs, ctx, backend=cfg.backend)
+    ctx = QATContext(agent.qat) if agent.qat.config.enabled else None
+    if padded:
+        a = _fused_mlp(agent.actor, obs, ctx, sites=ACTOR_SITES,
+                       activations=ACTOR_ACTS, dims=state.actor_dims)
+    else:
+        a = actor_forward(agent.actor, obs, ctx, backend=cfg.backend)
     if noise_key is not None:
         a = a + cfg.exploration_sigma * jax.random.normal(noise_key, a.shape)
     elif noise is not None:
@@ -323,26 +352,70 @@ def _wmean(x: Array, w: Optional[Array]) -> Array:
     return jnp.sum(x * w) / jnp.maximum(jnp.sum(w), 1.0)
 
 
-def _params_to_wb(params: Params, n: int) -> tuple[tuple, tuple]:
-    return (tuple(params[f"l{i}"]["w"] for i in range(n)),
-            tuple(params[f"l{i}"]["b"] for i in range(n)))
-
-
 def _wb_to_params(wb: tuple[tuple, tuple]) -> Params:
     ws, bs = wb
     return {f"l{i}": {"w": w, "b": b} for i, (w, b) in enumerate(zip(ws, bs))}
 
 
-def _update_fused_step(state: DDPGState, batch: dict[str, Array],
-                       cfg: DDPGConfig) -> tuple[DDPGState, dict[str, Array]]:
-    """The whole update in TWO Pallas launches (`fxp_mlp_train_step`):
+def _net_dims(params: Params) -> tuple[int, ...]:
+    """The unpadded widths (K0, N1, ..., NL) of a net's params."""
+    ws = [params[f"l{i}"]["w"] for i in range(len(params))]
+    return (int(ws[0].shape[0]),) + tuple(int(w.shape[1]) for w in ws)
+
+
+def _interleaved(params: Params) -> list:
+    return [params[f"l{i}"][k] for i in range(len(params)) for k in ("w", "b")]
+
+
+def _from_interleaved(wbp) -> Params:
+    return {f"l{i}": {"w": wbp[2 * i], "b": wbp[2 * i + 1]}
+            for i in range(len(wbp) // 2)}
+
+
+def _map_nets(state: DDPGState, actor_fn, critic_fn) -> DDPGState:
+    """`state` with each actor-shaped tree through `actor_fn` and each
+    critic-shaped tree through `critic_fn` (params, target, both moments)."""
+    def opt(o, fn):
+        return adam.AdamState(step=o.step, mu=fn(o.mu), nu=fn(o.nu))
+    return dataclasses.replace(
+        state, actor=actor_fn(state.actor), critic=critic_fn(state.critic),
+        actor_target=actor_fn(state.actor_target),
+        critic_target=critic_fn(state.critic_target),
+        actor_opt=opt(state.actor_opt, actor_fn),
+        critic_opt=opt(state.critic_opt, critic_fn))
+
+
+def pad_state(state: DDPGState) -> PaddedDDPGState:
+    """`state` in the fused kernels' layout."""
+    def pad(p):
+        return {k: {"w": pad_weight(l["w"]), "b": pad_bias(l["b"])}
+                for k, l in p.items()}
+    return PaddedDDPGState(agent=_map_nets(state, pad, pad),
+                           actor_dims=_net_dims(state.actor),
+                           critic_dims=_net_dims(state.critic))
+
+
+def unpad_state(state: PaddedDDPGState) -> DDPGState:
+    """Inverse of `pad_state`."""
+    def unpad(dims):
+        return lambda p: _wb_to_params(unpad_wb(_interleaved(p), dims))
+    return _map_nets(state.agent, unpad(state.actor_dims),
+                     unpad(state.critic_dims))
+
+
+def _update_padded(padded: PaddedDDPGState, batch: dict[str, Array],
+                   cfg: DDPGConfig) -> tuple[PaddedDDPGState, dict[str, Array]]:
+    """The whole update in TWO Pallas launches
+    (`fxp_mlp_train_step_padded`), on and to the fused kernels' layout:
     critic fwd+bwd+Adam+soft-update resident in launch 1, actor ditto in
     launch 2 — residuals in VMEM, gradients accumulated across batch
-    blocks in-kernel, moments/params/targets written in the epilogue.
-    Value semantics (losses, QAT range evolution, optimizer trajectory)
-    track `backend="pallas"`; parity is pinned in
+    blocks in-kernel, moments/params/targets updated in place in the
+    epilogue, and no padding or slicing of the eight parameter sets around
+    them.  Value semantics (losses, QAT range evolution, optimizer
+    trajectory) track `backend="pallas"`; parity is pinned in
     tests/kernels/test_fxp_mlp_step.py.
     """
+    state = padded.agent
     obs, action = batch["obs"], batch["action"]
     reward, next_obs = batch["reward"], batch["next_obs"]
     done = batch["done"].astype(jnp.float32)
@@ -357,7 +430,6 @@ def _update_fused_step(state: DDPGState, batch: dict[str, Array],
     else:
         deltas = zs = None
 
-    n = len(ACTOR_ACTS)
     opt_cfg_c = (fxp_adam.FxpAdamConfig(lr=cfg.critic_lr) if cfg.fxp_weights
                  else adam.AdamConfig(lr=cfg.critic_lr))
     opt_cfg_a = (fxp_adam.FxpAdamConfig(lr=cfg.actor_lr) if cfg.fxp_weights
@@ -365,16 +437,15 @@ def _update_fused_step(state: DDPGState, batch: dict[str, Array],
     consts_c = adam.step_constants(opt_cfg_c, state.critic_opt.step + 1)
     consts_a = adam.step_constants(opt_cfg_a, state.actor_opt.step + 1)
 
-    wb = lambda p: _params_to_wb(p, n)
-    out = fxp_mlp_train_step(
+    out = fxp_mlp_train_step_padded(
         obs, action, reward, done, next_obs, w,
-        wb(state.actor), wb(state.critic),
-        wb(state.actor_target), wb(state.critic_target),
-        wb(state.actor_opt.mu), wb(state.actor_opt.nu),
-        wb(state.critic_opt.mu), wb(state.critic_opt.nu),
+        _interleaved(state.actor), _interleaved(state.critic),
+        _interleaved(state.actor_target), _interleaved(state.critic_target),
+        _interleaved(state.actor_opt.mu), _interleaved(state.actor_opt.nu),
+        _interleaved(state.critic_opt.mu), _interleaved(state.critic_opt.nu),
         deltas, zs, consts_c, consts_a, state.qat.quantized_phase,
         actor_acts=ACTOR_ACTS, critic_acts=CRITIC_ACTS,
-        obs_dim=int(obs.shape[-1]), act_dim=int(action.shape[-1]),
+        actor_dims=padded.actor_dims, critic_dims=padded.critic_dims,
         gamma=cfg.gamma, tau=cfg.tau, n_bits=state.qat.config.n_bits,
         qat=qat_on, fxp32_phase1=state.qat.config.fxp32_phase1,
         fxp_weights=cfg.fxp_weights)
@@ -395,24 +466,34 @@ def _update_fused_step(state: DDPGState, batch: dict[str, Array],
 
     sum_w = jnp.maximum(jnp.sum(w), 1.0)
     new_state = DDPGState(
-        actor=_wb_to_params(out.actor), critic=_wb_to_params(out.critic),
-        actor_target=_wb_to_params(out.actor_t),
-        critic_target=_wb_to_params(out.critic_t),
+        actor=_from_interleaved(out.actor),
+        critic=_from_interleaved(out.critic),
+        actor_target=_from_interleaved(out.actor_t),
+        critic_target=_from_interleaved(out.critic_t),
         actor_opt=adam.AdamState(step=state.actor_opt.step + 1,
-                                 mu=_wb_to_params(out.actor_m),
-                                 nu=_wb_to_params(out.actor_v)),
+                                 mu=_from_interleaved(out.actor_m),
+                                 nu=_from_interleaved(out.actor_v)),
         critic_opt=adam.AdamState(step=state.critic_opt.step + 1,
-                                  mu=_wb_to_params(out.critic_m),
-                                  nu=_wb_to_params(out.critic_v)),
+                                  mu=_from_interleaved(out.critic_m),
+                                  nu=_from_interleaved(out.critic_v)),
         qat=qat_final, step=state.step + 1)
     metrics = {"critic_loss": out.closs_sum / sum_w,
                "actor_loss": -(out.q_sum / sum_w),
                "q_mean": out.y_sum / sum_w}
-    return new_state, metrics
+    return dataclasses.replace(padded, agent=new_state), metrics
 
 
-def update(state: DDPGState, batch: dict[str, Array], cfg: DDPGConfig
-           ) -> tuple[DDPGState, dict[str, Array]]:
+def _update_fused_step(state: DDPGState, batch: dict[str, Array],
+                       cfg: DDPGConfig) -> tuple[DDPGState, dict[str, Array]]:
+    """`_update_padded` on an unpadded agent: pad all eight parameter sets,
+    update, unpad — every call."""
+    new_state, metrics = _update_padded(pad_state(state), batch, cfg)
+    return unpad_state(new_state), metrics
+
+
+def update(state: DDPGState | PaddedDDPGState, batch: dict[str, Array],
+           cfg: DDPGConfig) -> tuple[DDPGState | PaddedDDPGState,
+                                     dict[str, Array]]:
     """One FIXAR timestep's training work: critic BP/WU then actor BP/WU
     (operation sequence of Fig. 3), QAT-aware, fixed-point weights.
 
@@ -428,7 +509,15 @@ def update(state: DDPGState, batch: dict[str, Array], cfg: DDPGConfig
     The padded rows do still flow through the QAT range monitors (min/max
     extrema; all-zero pad rows only widen a range that excludes 0, which
     mid-training activations essentially never do).
+
+    A `PaddedDDPGState` (backend "pallas_fused_step" only) is updated and
+    returned in the fused kernels' layout.
     """
+    if isinstance(state, PaddedDDPGState):
+        if cfg.backend != "pallas_fused_step":
+            raise ValueError("a PaddedDDPGState trains only with "
+                             "backend='pallas_fused_step'")
+        return _update_padded(state, batch, cfg)
     if cfg.backend == "pallas_fused_step":
         return _update_fused_step(state, batch, cfg)
     if cfg.backend not in ("jnp", "pallas"):

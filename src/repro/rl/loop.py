@@ -96,7 +96,7 @@ def _noise_proc(cfg: TrainConfig, dcfg: ddpg.DDPGConfig) -> NoiseProcess:
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class TrainState:
-    agent: ddpg.DDPGState
+    agent: ddpg.DDPGState    # a ddpg.PaddedDDPGState inside the fused-step window's scan
     env_state: EnvState      # fleet-batched (leading n_envs axis)
     obs: Array               # (n_envs, obs_dim)
     buf: replay.ReplayBuffer
@@ -212,12 +212,23 @@ def _train_window(
     with `env`/`cfg`/`dcfg`/`window` as static keys: repeated windows (and
     every driver sharing this helper) hit the cache instead of re-tracing
     the scanned body — the retrace regression is pinned in
-    tests/test_loop.py."""
+    tests/test_loop.py.
+
+    With the two-launch fused update (`pallas_fused_step`) the agent is
+    padded into the kernels' layout once here and unpadded once at the
+    end: the scan carries it padded, so no timestep pads or slices the
+    eight parameter sets around the launches."""
+    padded = dcfg.backend == "pallas_fused_step"
+    if padded:
+        ts = dataclasses.replace(ts, agent=ddpg.pad_state(ts.agent))
+
     def body(carry, _):
         carry, m = _one_timestep(carry, env, cfg, dcfg)
         return carry, (m["reward"], m["did_update"])
 
     ts, (rewards, updates) = jax.lax.scan(body, ts, None, length=window)
+    if padded:
+        ts = dataclasses.replace(ts, agent=ddpg.unpad_state(ts.agent))
     return ts, {"reward": jnp.mean(rewards), "updates": jnp.sum(updates)}
 
 

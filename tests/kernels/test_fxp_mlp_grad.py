@@ -21,7 +21,7 @@ import numpy as np
 import pytest
 
 from repro.core import fixedpoint as fxp
-from repro.kernels.fxp_mlp.ops import fxp_mlp_train
+from repro.kernels.fxp_mlp.ops import fxp_mlp_train, pad_bias, pad_weight
 from repro.kernels.fxp_mlp.ref import ref_fxp_mlp
 from repro.rl import ddpg
 from repro.rl.envs.locomotion import make
@@ -90,6 +90,42 @@ def test_fused_vjp_matches_oracle_autodiff(net, quant):
     # the fused bwd keeps f32 STE — tolerance covers that gap
     tol = dict(rtol=5e-3, atol=2e-2) if quant else dict(rtol=2e-4, atol=2e-5)
     _assert_tree_close(got, want, **tol, err_msg=f"quant={quant}")
+
+
+@pytest.mark.parametrize("net", NETS, ids=[n[0] for n in NETS])
+@pytest.mark.parametrize("quant", [False, True])
+def test_prepadded_weights_give_the_same_bits(net, quant):
+    """`fxp_mlp_train(..., dims=...)` on weights already in the kernels'
+    layout (how acting reads the training window's padded carry): the same
+    y, site extrema and gradients as on the unpadded weights, the weight
+    gradients in the padded layout with their padding exactly 0."""
+    _, dims, acts = net
+    ws, bs = _make_net(dims)
+    wps = tuple(pad_weight(w) for w in ws)
+    bps = tuple(pad_bias(b) for b in bs)
+    x = jax.random.normal(jax.random.key(12), (32, dims[0])) * 2
+    _, _, deltas, zs = _site_params(len(ws))
+    kw = dict(activations=acts, quant_phase=jnp.array(quant))
+
+    def run(ws, bs, x, dims=None):
+        def loss(ws, bs, x):
+            y, mns, mxs = fxp_mlp_train(x, ws, bs, deltas, zs, dims=dims, **kw)
+            return jnp.sum(jnp.sin(y)), (y, mns, mxs)
+        return jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(
+            ws, bs, x)
+
+    (_, want), (gw, gb, gx) = run(ws, bs, x)
+    (_, got), (pgw, pgb, pgx) = run(wps, bps, x, tuple(dims))
+    bits = lambda a: np.asarray(a).view(np.uint32)
+    for g, w in zip(got + (pgx,), want + (gx,)):
+        np.testing.assert_array_equal(bits(g), bits(w))
+    for i, (k, n) in enumerate(zip(dims[:-1], dims[1:])):
+        np.testing.assert_array_equal(bits(pgw[i][:k, :n]), bits(gw[i]))
+        np.testing.assert_array_equal(bits(pgb[i][0, :n]), bits(gb[i]))
+        assert pgw[i].shape == wps[i].shape and pgb[i].shape == bps[i].shape
+        pad_w, pad_b = np.array(pgw[i]), np.array(pgb[i])
+        pad_w[:k, :n], pad_b[0, :n] = 0.0, 0.0
+        assert not pad_w.any() and not pad_b.any()
 
 
 @pytest.mark.parametrize("quant", [False, True])

@@ -12,7 +12,11 @@ Acceptance contract for the whole-update kernel:
   * zero-weight (pad-mask) rows contribute EXACTLY zero gradient;
   * launch-count regression: one `ddpg.update` traces ≤ 2 pallas calls;
   * in-kernel Adam (the epilogue's `leaf_update`) bit-matches host Adam
-    over 50 steps.
+    over 50 steps;
+  * the agent carried in the kernels' padded layout from update to update
+    (`fxp_mlp_train_step_padded`, the training window's scan) gives the
+    bits of padding and unpadding it around every update, and its padded
+    regions stay exactly 0.
 """
 import dataclasses
 
@@ -21,8 +25,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.optim import adam
-from repro.rl import ddpg
+from repro.core import fixedpoint as fxp
+from repro.kernels.fxp_mlp.ops import (fxp_mlp_train_step,
+                                       fxp_mlp_train_step_padded, pad_wb,
+                                       unpad_wb)
+from repro.optim import adam, fxp_adam
+from repro.rl import ddpg, envs, loop
 from repro.rl.envs.base import EnvSpec
 
 SPEC = EnvSpec(name="step_test", obs_dim=17, act_dim=6)
@@ -258,3 +266,128 @@ def test_fused_step_backend_guard_message():
     state = ddpg.init(jax.random.key(0), SPEC, cfg)
     with pytest.raises(ValueError, match="pallas_fused_step"):
         ddpg.update(state, _batch(0, 8), cfg)
+
+
+# --------------------------------------------------------------------- #
+# the agent carried in the kernels' padded layout
+# --------------------------------------------------------------------- #
+
+def _bits(a):
+    return np.asarray(a, np.float32).view(np.uint32)
+
+
+def _assert_same_bits(a, b, what):
+    np.testing.assert_array_equal(_bits(a), _bits(b), err_msg=what)
+
+
+def _nets(state):
+    """The eight parameter sets in `fxp_mlp_train_step`'s order, each
+    ((w per layer), (b per layer))."""
+    n = len(ddpg.ACTOR_ACTS)
+    wb = lambda p: (tuple(p[f"l{i}"]["w"] for i in range(n)),
+                    tuple(p[f"l{i}"]["b"] for i in range(n)))
+    return [wb(p) for p in (state.actor, state.critic, state.actor_target,
+                            state.critic_target, state.actor_opt.mu,
+                            state.actor_opt.nu, state.critic_opt.mu,
+                            state.critic_opt.nu)]
+
+
+@pytest.mark.parametrize("quantized", [False, True],
+                         ids=["monitor", "quantized"])
+def test_padded_carry_matches_the_unpadded_wrapper_bit_for_bit(quantized):
+    """4 updates at B = 128 through `fxp_mlp_train_step_padded` with all
+    eight parameter sets carried padded from update to update, against the
+    same updates through `fxp_mlp_train_step`, which pads and unpads them
+    every call: every unpadded leaf (params, targets, both moments), the
+    loss partials and the site extrema are the same bits, and every padded
+    row and column of every carried leaf is exactly +0."""
+    state = ddpg.init(jax.random.key(1), SPEC, ddpg.DDPGConfig())
+    a = (SPEC.obs_dim, *ddpg.HIDDEN, SPEC.act_dim)
+    c = (SPEC.obs_dim + SPEC.act_dim, *ddpg.HIDDEN, 1)
+    dims = [a, c, a, c, a, a, c, c]
+    # per-site affine params of ranges no site input stays inside, so the
+    # quantized phase clips as well as rounds
+    deltas, zs = fxp.affine_params(jnp.full((6,), -1.5), jnp.full((6,), 2.5),
+                                   16)
+    kw = dict(actor_acts=ddpg.ACTOR_ACTS, critic_acts=ddpg.CRITIC_ACTS,
+              gamma=0.99, tau=0.005)
+    unpadded = _nets(state)
+    carried = [pad_wb(*wb) for wb in unpadded]
+    for t in range(4):
+        b = _batch(200 + t, 128)
+        consts = adam.step_constants(fxp_adam.FxpAdamConfig(lr=1e-3),
+                                     jnp.asarray(t + 1, jnp.int32))
+        args = (b["obs"], b["action"], b["reward"], b["done"],
+                b["next_obs"], jnp.ones((128,), jnp.float32))
+        tail = (deltas, zs, consts, consts, jnp.array(quantized))
+        # the wrapper's body outside its own jit: its pads and slices run op
+        # by op around the very program the carried path runs, so XLA:CPU
+        # cannot fuse the interpreted kernel's arithmetic differently in
+        # the two (which moves moments by an ulp or so)
+        ref = fxp_mlp_train_step.__wrapped__(
+            *args, *unpadded, *tail, obs_dim=SPEC.obs_dim,
+            act_dim=SPEC.act_dim, **kw)
+        out = fxp_mlp_train_step_padded(*args, *carried, *tail,
+                                        actor_dims=a, critic_dims=c, **kw)
+        unpadded, carried = list(ref[:8]), list(out[:8])
+        for k, (wbp, d, (ws, bs)) in enumerate(zip(carried, dims, unpadded)):
+            got_ws, got_bs = unpad_wb(wbp, d)
+            for i in range(len(ws)):
+                _assert_same_bits(got_ws[i], ws[i], f"step {t} set {k} w{i}")
+                _assert_same_bits(got_bs[i], bs[i], f"step {t} set {k} b{i}")
+            for i, leaf in enumerate(wbp):
+                rows, cols = ((d[i // 2], d[i // 2 + 1]) if i % 2 == 0
+                              else (1, d[i // 2 + 1]))
+                pad = np.asarray(leaf).copy()
+                pad[:rows, :cols] = 0.0
+                assert not _bits(pad).any(), f"step {t} set {k} leaf {i}"
+        for name in ("closs_sum", "y_sum", "q_sum", "c_mins", "c_maxs",
+                     "a_mins", "a_maxs"):
+            _assert_same_bits(getattr(out, name), getattr(ref, name),
+                              f"step {t} {name}")
+    # the updates moved every set, so the carry was exercised
+    assert float(jnp.max(jnp.abs(unpadded[4][0][1]))) > 0.0
+
+
+def test_train_window_carries_the_agent_padded_bit_for_bit():
+    """The scanned window with the fused update carries the agent padded
+    (one pad at entry, one unpad at exit); the same window with the agent
+    carried unpadded, each update padding and unpadding it, returns the
+    same `TrainState` bit for bit (agent, replay rows, env, noise, key)
+    and the same window metrics, across the QAT delay into the quantized
+    phase."""
+    env = envs.make("halfcheetah")
+    dcfg = ddpg.DDPGConfig(batch_size=32, backend="pallas_fused_step",
+                           qat_delay=5)
+    cfg = loop.TrainConfig(total_steps=14, warmup_steps=4,
+                           replay_capacity=64, eval_every=14, seed=3)
+    ts = loop.init_train_state(env, cfg, dcfg)
+
+    @jax.jit
+    def unpadded_window(ts):
+        def body(carry, _):
+            carry, m = loop._one_timestep(carry, env, cfg, dcfg)
+            return carry, (m["reward"], m["did_update"])
+
+        ts, (rewards, updates) = jax.lax.scan(body, ts, None, length=14)
+        return ts, {"reward": jnp.mean(rewards), "updates": jnp.sum(updates)}
+
+    ref_ts, ref_stats = unpadded_window(jax.tree.map(jnp.copy, ts))
+    got_ts, got_stats = loop._train_window(ts, env=env, cfg=cfg, dcfg=dcfg,
+                                           window=14)
+    assert int(got_stats["updates"]) == 11
+    assert bool(got_ts.agent.qat.quantized_phase)
+
+    def leaves(tree):
+        out = []
+        for leaf in jax.tree.leaves(tree):
+            if jnp.issubdtype(leaf.dtype, jax.dtypes.prng_key):
+                leaf = jax.random.key_data(leaf)
+            out.append(np.asarray(leaf))
+        return out
+
+    got, ref = leaves((got_ts, got_stats)), leaves((ref_ts, ref_stats))
+    assert len(got) == len(ref)
+    for i, (a, b) in enumerate(zip(got, ref)):
+        assert a.shape == b.shape and a.dtype == b.dtype, i
+        assert a.tobytes() == b.tobytes(), i

@@ -20,7 +20,7 @@ from repro.obs.phases import UNSCOPED, op_phases
 from repro.rl import ddpg, envs, loop
 
 CAPACITY, BATCH, WINDOW = 1_000_000, 128, 1000
-OBS_DIM = 17
+OBS_DIM, ACT_DIM = 17, 6
 UPDATE_ROW = -(-max(ddpg.HIDDEN) // 128) * 128  # the widest layer's row, in whole lanes
 
 _INSTRUCTION = re.compile(r"\s*(?:ROOT\s+)?%([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\(")
@@ -192,3 +192,55 @@ def test_evaluation_is_labelled_act_and_env():
     keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0), 2))
     text = loop._eval_episodes.lower(agent, keys, env=env, dcfg=dcfg).compile().as_text()
     assert set(op_phases(text, loop.PHASES).values()) >= {loop.ACT, loop.ENV}
+
+
+def _f32_dims(shape: str):
+    """The dimensions of an instruction's single float32 result, or None."""
+    arrays = _ARRAY.findall(shape)
+    if len(arrays) != 1 or arrays[0][0] != "f32":
+        return None
+    return tuple(int(d) for d in filter(None, arrays[0][1].split(",")))
+
+
+def _leaf_shapes() -> tuple[set, set]:
+    """(unpadded, padded) shapes of the agent's parameter leaves: each
+    weight and bias as `ddpg` holds it, and as the fused kernels take it
+    (lanes rounded up to 128; a bias as (1, N) and as the (N,) it is
+    padded from)."""
+    up = lambda n: -(-n // 128) * 128
+    unpadded, padded = set(), set()
+    for dims in ((OBS_DIM, *ddpg.HIDDEN, ACT_DIM), (OBS_DIM + ACT_DIM, *ddpg.HIDDEN, 1)):
+        for k, n in zip(dims[:-1], dims[1:]):
+            unpadded |= {(k, n), (n,)}
+            padded |= {(up(k), up(n)), (1, up(n)), (up(n),)}
+    return unpadded, padded
+
+
+def test_update_and_act_neither_pad_nor_unpad_the_agent(window_text):
+    """The window carries the agent in the fused kernels' layout: no
+    timestep pads a parameter leaf into it or slices one back out, in the
+    update's branch or in acting.  (Padding all eight parameter sets
+    around every update shows here as 40 pads and 32 slices, and acting
+    on unpadded weights as 5 more pads.)"""
+    phases = op_phases(window_text, loop.PHASES)
+    unpadded, padded = _leaf_shapes()
+    found = [(n, op, shape) for n, (_, op, shape, _) in _scan_body(window_text).items()
+             if phases[n] in (loop.UPDATE, loop.ACT)
+             and ((op == "pad" and _f32_dims(shape) in padded)
+                  or (op == "slice" and _f32_dims(shape) in unpadded))]
+    assert found == []
+
+
+def test_working_instructions_a_timestep(window_text):
+    """The instructions that run once a timestep and do work (fused
+    computations count once, as their fusion; parameters, tuples,
+    constants, bitcasts and the loop's own control are left out): 413 at
+    the benchmark cell's shapes, of which the update's branch holds 220
+    (both launches, the batch's pads, the QAT and Adam scalars) and acting
+    75.  Padding and unpadding the agent around every update instead
+    compiles to 536 (update 334, act 82)."""
+    phases = op_phases(window_text, loop.PHASES)
+    working = [n for n, (_, op, _, _) in _scan_body(window_text).items() if op not in _NO_WORK]
+    assert len(working) <= 450, len(working)
+    per_phase = {p: sum(phases[n] == p for n in working) for p in (loop.UPDATE, loop.ACT)}
+    assert per_phase[loop.UPDATE] <= 240 and per_phase[loop.ACT] <= 78, per_phase
