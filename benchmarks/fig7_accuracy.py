@@ -45,12 +45,12 @@ def run(env_name: str, steps: int, seed: int = 1) -> dict:
             kw["qat_delay"] = int(0.4 * steps)
         dcfg = ddpg.DDPGConfig(batch_size=64, actor_lr=3e-4, critic_lr=1e-3,
                                exploration_sigma=0.15, **kw)
-        cfg = loop.LoopConfig(total_steps=steps, warmup_steps=500,
-                              eval_every=max(steps // 8, 1000),
-                              replay_capacity=min(steps, 100_000),
-                              eval_episodes=4, seed=seed)
+        cfg = loop.TrainConfig(total_steps=steps, warmup_steps=500,
+                               eval_every=max(steps // 8, 1000),
+                               replay_capacity=min(steps, 100_000),
+                               eval_episodes=4, seed=seed)
         t0 = time.perf_counter()
-        _, hist = loop.train_fused(env, cfg, dcfg, chunk=1000)
+        _, hist = loop.train_device(env, cfg, dcfg)
         dt = time.perf_counter() - t0
         curves[name] = {"step": hist["step"], "reward": hist["eval_reward"]}
         emit(f"fig7/{env_name}/{name}", dt * 1e6 / steps,

@@ -29,10 +29,10 @@ def run(env_name: str, steps: int) -> dict:
     out = {}
     for bs in BATCHES:
         dcfg = ddpg.DDPGConfig(batch_size=bs, qat_delay=steps // 2)
-        cfg = loop.LoopConfig(total_steps=steps, warmup_steps=min(600, steps),
-                              replay_capacity=20_000, eval_every=10 ** 9)
+        cfg = loop.TrainConfig(total_steps=steps, warmup_steps=min(600, steps),
+                               replay_capacity=20_000, eval_every=min(500, steps))
         t0 = time.perf_counter()
-        loop.train_fused(env, cfg, dcfg, chunk=min(500, steps))
+        loop.train_device(env, cfg, dcfg, eval_fn=lambda *a: 0.0)
         dt = time.perf_counter() - t0
         ips = steps / dt
         out[bs] = ips

@@ -25,8 +25,8 @@ def run(env_name: str, steps: int) -> dict:
     out = {}
     for bs in BATCHES:
         dcfg = ddpg.DDPGConfig(batch_size=bs)
-        cfg = loop.LoopConfig(total_steps=steps, warmup_steps=20,
-                              replay_capacity=8_192, eval_every=10 ** 9)
+        cfg = loop.TrainConfig(total_steps=steps, warmup_steps=20,
+                               replay_capacity=8_192, eval_every=10 ** 9)
         _, rep = loop.train_host(env, cfg, dcfg)
         t = rep["times"]
         total = sum(t.values())

@@ -3,12 +3,13 @@ half-precision mode must cost ~half the MAC work of full-precision mode.
 
 Plus the network-resident fused MLP comparison: the whole paper-actor
 forward in ONE Pallas call (kernels/fxp_mlp) vs the 3-call per-layer
-`fxp_dense` chain, both precision phases, the acting-path IPS for each DDPG
-backend at TWO batch sizes (so `CostModel.from_bench` can separate launch
-overhead from per-item rate), and the *training*-step comparison — the
-Fig. 8-comparable line: `ddpg.update()` through the fused kernel's custom
-VJP (fwd + bwd Pallas launches) vs the jnp autodiff backend, in updates/sec
-and trained-samples/sec.  Results land in `BENCH_fused_mlp.json` at the
+`fxp_dense` chain (serving's `layer` mode), both precision phases, the
+acting-path IPS of each serve path at TWO batch sizes (so
+`CostModel.from_bench` can separate launch overhead from per-item rate),
+and the *training*-step comparison — the Fig. 8-comparable line:
+`ddpg.update()` through each training backend (jnp autodiff, the fused
+kernel's custom VJP, the two-launch fused step), in updates/sec and
+trained-samples/sec.  Results land in `BENCH_fused_mlp.json` at the
 repo root so the perf trajectory is tracked across PRs.
 
 On CPU (interpret) we measure wall time AND verify the structural 2× via
@@ -43,14 +44,14 @@ FUSED_JSON = _REPO / "BENCH_fused_mlp.json"
 SMOKE_FUSED_JSON = _REPO / "results" / "bench" / "smoke" / FUSED_JSON.name
 ACTOR_BATCHES = (64, 256)        # two points -> slope/intercept separation
 SMOKE_ACTOR_BATCHES = (8, 32)
-TRAIN_BATCHES = (32, 128)        # same two-point idea for the train fit
+TRAIN_BATCHES = (32, 128)        # two points, as the train section's schema asks
 SMOKE_TRAIN_BATCHES = (8, 16)
 
 
 def _count_pallas_calls(fn, *args) -> int:
     """Traced pallas_call count, recursing into cond/pjit sub-jaxprs —
-    the per-layer path traces BOTH precision kernels per layer (lax.cond),
-    the fused path traces exactly one (plus one backward under grad)."""
+    the per-layer path traces one kernel per layer, the fused path exactly
+    one (plus one backward under grad)."""
     def subs(v):
         vals = v if isinstance(v, (tuple, list)) else [v]
         for item in vals:
@@ -85,13 +86,9 @@ def _dummy_batch(spec, n, key=0):
 
 
 def bench_train_step(report: dict, env, cfg, state, smoke: bool) -> None:
-    """Training-step throughput through the fused kernel's custom VJP vs
-    jnp autodiff — FIXAR's headline is *training* IPS (Fig. 8).
-
-    Measured at TWO batch sizes (`ips_by_batch`) so
-    `CostModel.from_bench` can fit the train-phase affine coefficients
-    (slope = per-item rate, intercept = fwd+bwd launch overhead) the same
-    way `actor_ips_by_batch` feeds the acting-path fit."""
+    """Training-step throughput of each DDPG training backend — FIXAR's
+    headline is *training* IPS (Fig. 8) — at TWO batch sizes
+    (`ips_by_batch`), with the launch count of one update."""
     from repro.rl import ddpg
 
     train_batches = SMOKE_TRAIN_BATCHES if smoke else TRAIN_BATCHES
@@ -143,7 +140,7 @@ def bench_fused_mlp(smoke: bool = False) -> dict:
     """Fused whole-network kernel vs the per-layer fxp_dense chain."""
     from repro.rl import ddpg
     from repro.rl.envs.locomotion import make
-    from repro.core.qat import QATContext
+    from repro.core.qat import QATContext, freeze_quant
 
     env = make("halfcheetah")
     dims = [env.spec.obs_dim, *ddpg.HIDDEN, env.spec.act_dim]
@@ -161,6 +158,15 @@ def bench_fused_mlp(smoke: bool = False) -> dict:
                                       backend=backend)
         return f
 
+    def layer(qat_state):
+        """The per-layer chain, serving's `layer` mode."""
+        frozen = freeze_quant(qat_state, ddpg.ACTOR_SITES)
+
+        @jax.jit
+        def f(params, x):
+            return ddpg.act_batch(params, x, frozen, mode="layer")
+        return f
+
     report = {
         "schema": "fixar/fused_mlp_bench/v4",
         "config": {"batch": primary, "batches": list(batches), "net": dims,
@@ -172,11 +178,10 @@ def bench_fused_mlp(smoke: bool = False) -> dict:
     }
 
     # traced-call structure: fused = 1 kernel for the whole network;
-    # per-layer = 2 kernels traced per layer (cond), len(dims)-1 executed
+    # per-layer = one kernel per layer, len(dims)-1 executed
     fused_calls = _count_pallas_calls(forward("pallas", state.qat),
                                       state.actor, obs)
-    layer_calls = _count_pallas_calls(forward("pallas_layer", state.qat),
-                                      state.actor, obs)
+    layer_calls = _count_pallas_calls(layer(state.qat), state.actor, obs)
     report["pallas_calls_traced"] = {
         "fused": fused_calls,
         "perlayer": layer_calls,
@@ -192,9 +197,8 @@ def bench_fused_mlp(smoke: bool = False) -> dict:
                                   config=dataclasses.replace(
                                       state.qat.config, delay=5))
         res = {}
-        for mode, backend in (("fused", "pallas"),
-                              ("perlayer", "pallas_layer")):
-            f = forward(backend, qat)
+        for mode, f in (("fused", forward("pallas", qat)),
+                        ("perlayer", layer(qat))):
             us = time_fn(lambda f=f: f(state.actor, obs),
                          iters=fwd_iters, warmup=fwd_warmup)
             res[f"{mode}_us"] = us
@@ -207,10 +211,17 @@ def bench_fused_mlp(smoke: bool = False) -> dict:
 
     # acting-path IPS (the env-interaction side of the training loop) at
     # two batch sizes: the pair lets CostModel.from_bench fit BOTH the
-    # launch overhead (intercept) and the per-item rate (slope)
-    for backend in ("jnp", "pallas", "pallas_layer"):
+    # launch overhead (intercept) and the per-item rate (slope); the
+    # per-layer chain keeps its `pallas_layer` key
+    def act_fn(backend):
+        if backend == "pallas_layer":
+            f = layer(state.qat)
+            return lambda s, o: f(s.actor, o)
         bcfg = dataclasses.replace(cfg, backend=backend)
-        act = jax.jit(lambda s, o: ddpg.act(s, o, cfg=bcfg))
+        return jax.jit(lambda s, o: ddpg.act(s, o, cfg=bcfg))
+
+    for backend in ("jnp", "pallas", "pallas_layer"):
+        act = act_fn(backend)
         per_batch = {}
         for b in batches:
             ob = obs[:b]

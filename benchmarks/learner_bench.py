@@ -3,8 +3,8 @@
 Measures the batched learner engine the way the paper reports its headline:
 trained samples per second (FIXAR's 25293.3 IPS is *training* throughput,
 delivered by intra-batch parallelism), plus the streaming-side numbers the
-paper's FPGA never had to expose — update-request p50/p99 latency, batch
-occupancy, and the train-phase adaptive dispatcher's mode choices.
+paper's FPGA never had to expose — update-request p50/p99 latency and batch
+occupancy, for one single-backend engine per DDPG training backend.
 
 Writes `BENCH_learner.json` at the repo root (tracked across PRs, next to
 BENCH_fused_mlp.json / BENCH_serve_policy.json) and emits the harness CSV
@@ -30,9 +30,10 @@ import numpy as np
 from benchmarks.common import emit
 
 LEARNER_JSON = _REPO / "BENCH_learner.json"
-FUSED_JSON = _REPO / "BENCH_fused_mlp.json"
 SMOKE_DIR = _REPO / "results" / "bench" / "smoke"
-DISPATCH_BATCHES = [1, 8, 32, 128, 512]
+# the `modes` section's keys (the names the learner schema requires) and the
+# DDPG training backend each one times
+BACKENDS = {"fused_step": "pallas_fused_step", "fused": "pallas", "jnp": "jnp"}
 
 
 def _replay_batch(rng, n, obs_dim, act_dim):
@@ -46,11 +47,12 @@ def _replay_batch(rng, n, obs_dim, act_dim):
 
 
 def bench_learner(quick: bool = False, smoke: bool = False) -> dict:
+    import dataclasses
+
     import jax
     from repro.rl import ddpg
     from repro.rl.envs.locomotion import make
-    from repro.serve.policy import BatcherConfig, CostModel
-    from repro.serve.policy.dispatch import TRAIN_MODES
+    from repro.serve.policy import BatcherConfig
     from repro.train.learner import LearnerEngine
 
     quick = quick or smoke
@@ -66,28 +68,20 @@ def bench_learner(quick: bool = False, smoke: bool = False) -> dict:
     rng = np.random.default_rng(0)
     big_batch = _replay_batch(rng, big, dims[0], dims[-1])
 
-    # the train-phase dispatcher calibrates from the kernel bench (run.py
-    # orders kernel -> serve -> learner so this JSON is fresh)
-    cm = CostModel.from_bench(
-        SMOKE_DIR / FUSED_JSON.name if smoke else FUSED_JSON)
-
     report = {
-        # v2: adaptive carries dispatch_audit + qat_telemetry (the
-        # engine's registry-backed stats sections)
         "schema": "fixar/learner_bench/v2",
         "config": {"net": dims, "buckets": list(buckets), "big_batch": big,
                    "quick": quick, "smoke": smoke,
                    "backend": jax.default_backend(),
                    "qat": "quantized_phase"},
         "modes": {},
-        "dispatch": {},
         "adaptive": {},
     }
 
-    # ---- per-mode updates/sec + latency (forced dispatch) -----------------
-    for mode in TRAIN_MODES:
+    # ---- per-backend updates/sec + latency ---------------------------------
+    for mode, backend in BACKENDS.items():
         eng = LearnerEngine.from_ddpg(
-            state, cfg, force_mode=mode,
+            state, dataclasses.replace(cfg, backend=backend),
             batcher=BatcherConfig(buckets=buckets))
         eng.warmup(buckets=(buckets[0], big))
         eng.load_state(state)   # fixed starting state for every mode
@@ -120,21 +114,9 @@ def bench_learner(quick: bool = False, smoke: bool = False) -> dict:
              float(np.percentile(lat_us, 50)),
              f"p99_us={np.percentile(lat_us, 99):.0f}")
 
-    # ---- dispatcher choices per phase: the phase axis made visible --------
-    report["dispatch"] = {
-        "act": {str(b): cm.choose(b, dims, phase="act")
-                for b in DISPATCH_BATCHES},
-        "train": {str(b): cm.choose(b, dims, phase="train")
-                  for b in DISPATCH_BATCHES},
-        "calibration_source": cm.source,
-    }
-    d = report["dispatch"]
-    emit("train/learner/dispatch", 0.0,
-         ";".join(f"b{b}={d['train'][str(b)]}" for b in DISPATCH_BATCHES))
-
-    # ---- adaptive end-to-end: concurrent producers through the queue ------
-    # traced + audited: registry-backed stats, predicted-vs-measured
-    # audit per update, QAT range/saturation probes off the live state
+    # ---- end to end: concurrent producers through the queue ---------------
+    # traced: registry-backed stats and QAT range/saturation probes off the
+    # live state
     from repro.obs import Observability
     # trace path decided up front so the tracer self-flushes on close():
     # an aborted bench still leaves its (partial) trace on disk
@@ -144,7 +126,7 @@ def bench_learner(quick: bool = False, smoke: bool = False) -> dict:
     obsb = Observability.tracing(trace_path=str(trace_path),
                                  qat_probe_every=2)
     eng = LearnerEngine.from_ddpg(
-        state, cfg, cost_model=cm,
+        state, cfg,
         batcher=BatcherConfig(buckets=buckets, max_wait_ms=2.0),
         obs=obsb)
     try:
@@ -189,7 +171,6 @@ def bench_learner(quick: bool = False, smoke: bool = False) -> dict:
         "p99_ms": st["p99_ms"],
         "batch_occupancy": st["batch_occupancy"],
         "mode_histogram": st["mode_histogram"],   # already phase-keyed
-        "dispatch_audit": st["dispatch_audit"],
         "qat_telemetry": st["qat_telemetry"],
     }
     emit("train/learner/adaptive", 0.0,
@@ -197,10 +178,6 @@ def bench_learner(quick: bool = False, smoke: bool = False) -> dict:
          f"train_ips_wall={st['train_ips_wall']:.0f};"
          f"p50_ms={st['p50_ms']:.2f};p99_ms={st['p99_ms']:.2f};"
          f"occupancy={st['batch_occupancy']:.2f}")
-    drift = st["dispatch_audit"]["drift_factor"]
-    emit("train/learner/dispatch_audit", 0.0,
-         f"drift_factor={drift:.2f};stale={st['dispatch_audit']['stale']};"
-         f"batches={st['dispatch_audit']['batches']}")
 
     target = SMOKE_DIR / LEARNER_JSON.name if smoke else LEARNER_JSON
     target.parent.mkdir(parents=True, exist_ok=True)

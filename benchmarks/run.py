@@ -40,9 +40,9 @@ def main(argv=None) -> None:
                             lm_bench, loop_bench, serve_bench, tableii_compare)
 
     if args.smoke:
-        # calibration order: kernel FIRST — both dispatchers (serve's
-        # act-phase, learner's train-phase) calibrate from the fresh
-        # BENCH_fused_mlp.json; lm last (no calibration dependency)
+        # calibration order: kernel FIRST — serving's dispatcher calibrates
+        # from the fresh BENCH_fused_mlp.json; the rest have no calibration
+        # dependency
         kernel_bench.main(["--smoke"])
         serve_bench.main(["--smoke"])
         learner_bench.main(["--smoke"])
@@ -57,8 +57,6 @@ def main(argv=None) -> None:
         # BENCH_fused_mlp.json when both run
         serve_bench.main(["--quick"] if args.quick else [])
     if want("learner"):
-        # same calibration dependency as serve (train-phase fit from the
-        # kernel bench's "train" section)
         learner_bench.main(["--quick"] if args.quick else [])
     if want("loop"):
         loop_bench.main(["--quick"] if args.quick else [])

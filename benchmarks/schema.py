@@ -2,8 +2,8 @@
 
 `BENCH_fused_mlp.json`, `BENCH_serve_policy.json`, `BENCH_learner.json`,
 `BENCH_device_loop.json`, and `BENCH_serve_lm.json` are consumed
-programmatically — `CostModel.from_bench` calibrates both the serving
-(act-phase) and learner (train-phase) dispatchers from the kernel bench,
+programmatically — `CostModel.from_bench` calibrates the serving
+dispatcher from the kernel bench,
 and the CI bench job diffs the serving/training/loop/LM numbers across PRs
 — so format drift must fail the build instead of silently degrading the
 cost model to its defaults.  This module is the single source of truth for
@@ -203,12 +203,12 @@ SERVE_POLICY_SCHEMA = {
 }
 
 # the learner bench: the training-throughput twin of the serving artifact
-# (updates/sec, train IPS, latency percentiles, per-phase dispatch tables
-# and the adaptive engine's mode histogram keyed by phase)
+# (updates/sec, train IPS, latency percentiles per training backend, and
+# the streaming engine's mode histogram keyed by phase)
 LEARNER_SCHEMA = {
     "$schema": "http://json-schema.org/draft-07/schema#",
     "type": "object",
-    "required": ["schema", "config", "modes", "dispatch", "adaptive"],
+    "required": ["schema", "config", "modes", "adaptive"],
     "properties": {
         # v2: adaptive grows dispatch_audit + qat_telemetry (engine stats
         # sections; the mode histogram was already phase-keyed)
@@ -236,21 +236,12 @@ LEARNER_SCHEMA = {
                              "p99_ms", "updates"],
             },
         },
-        "dispatch": {
-            "type": "object",
-            "required": ["act", "train", "calibration_source"],
-            "properties": {
-                "act": {"type": "object", "additionalProperties": _STR},
-                "train": {"type": "object", "additionalProperties": _STR},
-                "calibration_source": _STR,
-            },
-        },
         "adaptive": {
             "type": "object",
             "required": ["requests", "updates", "transitions",
                          "updates_per_s_wall", "train_ips_wall", "p50_ms",
                          "p99_ms", "batch_occupancy", "mode_histogram",
-                         "dispatch_audit", "qat_telemetry"],
+                         "qat_telemetry"],
             "properties": {
                 "mode_histogram": {       # per-phase: {"train": {mode: n}}
                     "type": "object",

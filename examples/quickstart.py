@@ -26,14 +26,14 @@ def main():
         qat_delay=int(0.4 * total_steps),
         qat_bits=16,
     )
-    cfg = loop.LoopConfig(total_steps=total_steps, warmup_steps=500,
-                          eval_every=2_000, replay_capacity=20_000,
-                          eval_episodes=4, seed=0)
+    cfg = loop.TrainConfig(total_steps=total_steps, warmup_steps=500,
+                           eval_every=2_000, replay_capacity=20_000,
+                           eval_episodes=4, seed=0)
 
     print(f"training DDPG on {env.spec.name} "
           f"(obs={env.spec.obs_dim}, act={env.spec.act_dim}), "
           f"quantization delay={dcfg.qat_delay} steps")
-    ts, hist = loop.train_fused(env, cfg, dcfg, chunk=1000)
+    ts, hist = loop.train_device(env, cfg, dcfg)
     for s, r, ips in zip(hist["step"], hist["eval_reward"], hist["ips"]):
         phase = "fxp16-activations" if s >= dcfg.qat_delay else "fxp32"
         print(f"  step {s:6d}  eval_reward {r:8.1f}  ips {ips:7.1f}  [{phase}]")

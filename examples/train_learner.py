@@ -3,11 +3,10 @@
 Simulates the training workload FIXAR's headline number comes from (many
 update batches driven through the fused kernels with intra-batch
 parallelism): producer threads submit replay batches and trajectory
-chunks; the update batcher coalesces them into padded buckets; the
-train-phase adaptive dispatcher picks per micro-batch between the
-2-launch whole-update kernel (`fused_step`: fwd+bwd+Adam+soft-update
-resident per loss), the fused custom-VJP pair (`fused`), and jnp
-autodiff; every update applies sequentially to one training state.
+chunks; the update batcher coalesces them into padded buckets; every
+micro-batch trains through the backend the DDPG config names (jnp
+autodiff here; `backend="pallas_fused_step"` runs the 2-launch
+whole-update kernel) and applies sequentially to one training state.
 
     PYTHONPATH=src python examples/train_learner.py
 """
@@ -23,11 +22,9 @@ import jax
 import numpy as np
 
 from repro.rl import ddpg
-from repro.serve.policy import BatcherConfig, CostModel
+from repro.serve.policy import BatcherConfig
 from repro.rl.envs.locomotion import make
 from repro.train.learner import LearnerEngine
-
-REPO = pathlib.Path(__file__).resolve().parents[1]
 
 
 def replay_batch(rng, n, obs_dim, act_dim):
@@ -44,19 +41,11 @@ def main():
     env = make("halfcheetah")
     cfg = ddpg.DDPGConfig(qat_delay=0)  # quantized phase from step 0
     state = ddpg.init(jax.random.key(0), env.spec, cfg)
-
-    # train-phase dispatch calibrated from the tracked kernel bench
-    cm = CostModel.from_bench(REPO / "BENCH_fused_mlp.json")
     engine = LearnerEngine.from_ddpg(
-        state, cfg, cost_model=cm,
-        batcher=BatcherConfig(buckets=(8, 32, 128), max_wait_ms=2.0))
-    # warm the buckets the producers actually hit — large buckets dispatch
-    # to the fused-step whole-update kernel once calibration favors it
+        state, cfg, batcher=BatcherConfig(buckets=(8, 32, 128), max_wait_ms=2.0))
+    # warm the buckets the producers actually hit
     n = engine.warmup(buckets=(8, 32), padded=True)
-    print(f"learner up: net={engine.dims}, calibration={cm.source}, "
-          f"warmed {n} executables")
-    print("train dispatch:",
-          {b: cm.choose(b, engine.dims, phase='train') for b in (8, 32, 128)})
+    print(f"learner up: backend={cfg.backend}, warmed {n} executables")
 
     rng = np.random.default_rng(0)
     engine.start()
@@ -91,7 +80,7 @@ def main():
           f"{s['train_ips_device']:.0f} device train-IPS")
     print(f"latency p50 {s['p50_ms']:.2f} ms, p99 {s['p99_ms']:.2f} ms; "
           f"occupancy {s['batch_occupancy']:.2f}; "
-          f"dispatch {s['mode_histogram']}; trajectory chunks={m['chunks']}")
+          f"modes {s['mode_histogram']}; trajectory chunks={m['chunks']}")
     print(f"state advanced to step {int(engine.state.step)}")
 
 

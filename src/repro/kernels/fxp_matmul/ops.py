@@ -87,20 +87,9 @@ def fxp_dense_chain(x: Array, weights: tuple, biases: tuple, *,
     return x
 
 
-def chain_cost_hint(dims, phase: str = "act") -> dict:
+def chain_cost_hint(dims) -> dict:
     """Dispatcher hook: launch/FLOP shape of the per-layer chain for an MLP
     with layer dims `dims` — intra-layer parallelism (each launch spreads
-    one layer's output columns across the array).
-
-    phase="train" models a hypothetical per-layer fwd+bwd step (2 launches
-    per layer, ~3x the MACs); the chain has no autodiff rule today, so this
-    exists to keep the dispatcher's phase axis total across modes.
-    """
-    if phase == "train":
-        return {"launches": 2 * (len(dims) - 1),
-                "flops_per_item": 3 * mlp_flops(dims),
-                "parallelism": "intra_layer"}
-    if phase != "act":
-        raise ValueError(f"unknown cost phase {phase!r}; 'act' | 'train'")
+    one layer's output columns across the array)."""
     return {"launches": len(dims) - 1, "flops_per_item": mlp_flops(dims),
             "parallelism": "intra_layer"}

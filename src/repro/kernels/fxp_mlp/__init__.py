@@ -7,10 +7,9 @@ FIXAR's headline throughput comes from keeping the *whole* DDPG network in
 BRAM: weights never leave the chip and activations pipeline layer-to-layer
 without a memory round-trip.  The per-layer path (`kernels/fxp_matmul` +
 `kernels/quantize`) instead pays, per layer: a pad/unpad, an HBM activation
-round-trip, a limb split, a separate range-monitor sweep, and — in
-`rl/ddpg.py` — a `lax.cond` that traces BOTH precision kernels.  For DDPG's
-tiny layers (K <= 421) that launch overhead dominates; this module removes
-all of it.
+round-trip, a limb split, a separate range-monitor sweep, and a launch of
+its own.  For DDPG's tiny layers (K <= 421) that launch overhead dominates;
+this module removes all of it.
 
 Design
 ------
@@ -53,48 +52,45 @@ Design
   (sequential "arbitrary" grid), straight-through estimators at the fused
   QAT sites.  `rl/ddpg.py` trains through it with `backend="pallas"`.
 
-* **Whole-update fused step** (`fxp_mlp_train_step`): the endpoint of the
-  launch-count trajectory — one `ddpg.update` in exactly TWO launches
-  (critic step, actor step) instead of the custom-VJP path's eight.  The
-  contract that makes it work is *residuals stay in VMEM*: each launch runs
-  forward AND backward for its loss in one kernel body, so the per-layer
-  effective inputs / pre-STE site inputs / post-activation outputs are plain
-  VMEM values consumed by the backward sweep in the same grid step — they
-  are never written to HBM, never padded into residual outputs, never
-  re-read.  dW/db accumulate across batch blocks in VMEM scratch
-  (sequential "arbitrary" grid), and the LAST block runs the epilogue
-  in-kernel: Adam moment/param update (`optim/adam.leaf_update` /
+* **Whole-update fused step** (`fxp_mlp_train_step_padded`): the endpoint
+  of the launch-count trajectory — one `ddpg.update` in exactly TWO
+  launches (critic step, actor step) instead of the custom-VJP path's
+  eight.  The contract that makes it work is *residuals stay in VMEM*: each
+  launch runs forward AND backward for its loss in one kernel body, so the
+  per-layer effective inputs / pre-STE site inputs / post-activation
+  outputs are plain VMEM values consumed by the backward sweep in the same
+  grid step — they are never written to HBM, never padded into residual
+  outputs, never re-read.  dW/db accumulate across batch blocks in VMEM
+  scratch (sequential "arbitrary" grid), and the LAST block runs the
+  epilogue in-kernel: Adam moment/param update (`optim/adam.leaf_update` /
   `optim/fxp_adam.leaf_update(ste=False)` against SMEM-shipped
   `StepConstants`) followed by the Polyak soft-update of the target nets.
   The kernel-computed actions (launch 2) and target actions (launch 1) are
   lane-rotated next to the observations (`pltpu.roll`), so the critic's
-  first layer runs the same concat-input dot as the custom-VJP path.
-  `fxp_mlp_train_step_padded` is the step on the kernels' own layout (every
-  leaf zero-padded to lane tiles, and left exactly 0 there), each launch
-  updating its net's params, moments and target in place; the scanned
-  training window carries the agent so (`ddpg.pad_state`), and
-  `fxp_mlp_train_step` pads and unpads around it for one-off callers.
+  first layer runs the same concat-input dot as the custom-VJP path.  The
+  step runs on the kernels' own layout (every leaf zero-padded to lane
+  tiles, and left exactly 0 there), each launch updating its net's params,
+  moments and target in place.  `rl/ddpg` owns that layout: the scanned
+  training window carries the agent padded (`ddpg.to_carry`), and a single
+  `ddpg.update` on an unpadded agent pads and unpads around the step.
 
-Train-time dispatch (`serve/policy` + `train/learner`) chooses between
-`fused_step` (2 launches, best at large batch), `fused` (the 8-launch
-custom-VJP pair, kept as the bit-parity reference), and `jnp` autodiff
-(lowest constant cost at tiny batches) via the calibrated affine cost
-model; `ddpg.update(backend=...)` maps "pallas_fused_step" / "pallas" /
-"jnp" onto the same three paths.
+Which update runs is `ddpg.DDPGConfig.backend`'s choice alone:
+"pallas_fused_step" (the two launches above), "pallas" (the 8-launch
+custom-VJP pair, kept as the fused step's parity reference) or "jnp"
+(XLA autodiff).
 
 Files: `kernel.py` (pallas_call + grid spec, fwd + bwd + whole-update
 step), `ops.py` (jitted public wrappers, padding + range reduction +
-custom VJP + `fxp_mlp_train_step`), `ref.py` (pure-jnp per-layer oracle).
-The per-layer `fxp_dense` chain stays available as the reference/fallback
-(`backend="pallas_layer"` in `rl/ddpg.py`); forward parity is asserted in
+custom VJP + the step's wrapper), `ref.py` (pure-jnp per-layer oracle).
+The per-layer `fxp_dense` chain (kernels/fxp_matmul) is serving's `layer`
+mode (`ddpg.act_batch(mode="layer")`); forward parity is asserted in
 tests/kernels/test_fxp_mlp.py, gradient parity in
 tests/kernels/test_fxp_mlp_grad.py, whole-step parity + the ≤2-launch
 regression in tests/kernels/test_fxp_mlp_step.py.
 """
 from repro.kernels.fxp_mlp.ops import (fxp_mlp_forward, fxp_mlp_infer,
-                                       fxp_mlp_train, fxp_mlp_train_step,
-                                       fxp_mlp_train_step_padded)
+                                       fxp_mlp_train, fxp_mlp_train_step_padded)
 from repro.kernels.fxp_mlp.ref import ref_fxp_mlp
 
 __all__ = ["fxp_mlp_forward", "fxp_mlp_infer", "fxp_mlp_train",
-           "fxp_mlp_train_step", "fxp_mlp_train_step_padded", "ref_fxp_mlp"]
+           "fxp_mlp_train_step_padded", "ref_fxp_mlp"]

@@ -14,6 +14,9 @@ activations stay network-resident), and whose bwd rule is a SECOND
 network-resident Pallas launch (`fxp_mlp_bwd_pallas`) running the whole
 dW/db/dx chain with straight-through estimators at the fused QAT sites.  So
 one DDPG loss evaluation trains through exactly two launches: fwd + bwd.
+
+`fxp_mlp_train_step_padded` runs a whole DDPG update in two launches on
+the kernels' own zero-padded layout, which `rl/ddpg` carries.
 """
 from __future__ import annotations
 
@@ -305,20 +308,10 @@ def fxp_mlp_infer(x: Array, weights: tuple, biases: tuple,
     return jax.lax.stop_gradient(y)
 
 
-def fused_cost_hint(dims: Sequence[int], phase: str = "act") -> dict:
-    """Dispatcher hook: launch/FLOP shape of the fused path for an MLP with
-    layer dims `dims` — intra-batch parallelism, the whole network in ONE
-    launch (batch is the only grid axis).
-
-    phase="act" is the forward/acting path; phase="train" is a
-    forward+backward step through `fxp_mlp_train`: 2 launches (fused fwd +
-    fused bwd) and ~3x the MACs (fwd, plus dx and dW matmuls per layer).
-    """
-    if phase == "train":
-        return {"launches": 2, "flops_per_item": 3 * mlp_flops(dims),
-                "parallelism": "intra_batch"}
-    if phase != "act":
-        raise ValueError(f"unknown cost phase {phase!r}; 'act' | 'train'")
+def fused_cost_hint(dims: Sequence[int]) -> dict:
+    """Dispatcher hook: launch/FLOP shape of the fused forward for an MLP
+    with layer dims `dims` — intra-batch parallelism, the whole network in
+    ONE launch (batch is the only grid axis)."""
     return {"launches": 1, "flops_per_item": mlp_flops(dims),
             "parallelism": "intra_batch"}
 
@@ -328,15 +321,10 @@ def fused_cost_hint(dims: Sequence[int], phase: str = "act") -> dict:
 # ---------------------------------------------------------------------------
 
 
-def pad_wb(ws: Sequence[Array], bs: Sequence[Array]) -> list:
-    """Pad per-layer (w, b) leaves to lane tiles, interleaved
-    [w0, b0, w1, b1, ...] — the layout the fused-step kernels consume."""
-    return [a for w, b in zip(ws, bs) for a in (pad_weight(w), pad_bias(b))]
-
-
 def unpad_wb(wbp: Sequence[Array], dims: Sequence[int]) -> tuple:
-    """Inverse of `pad_wb`: ((w per layer), (b per layer)) cut back to the
-    unpadded widths `dims` (K0, N1, ..., NL)."""
+    """Interleaved [w0, b0, w1, b1, ...] leaves in the fused-step kernels'
+    layout (`pad_weight`, `pad_bias`) as ((w per layer), (b per layer)) cut
+    back to the unpadded widths `dims` (K0, N1, ..., NL)."""
     n = len(dims) - 1
     return (tuple(wbp[2 * i][:dims[i], :dims[i + 1]] for i in range(n)),
             tuple(wbp[2 * i + 1][0, :dims[i + 1]] for i in range(n)))
@@ -352,10 +340,9 @@ def _pad_batch(a: Array, mp: int) -> Array:
 class TrainStepOut(NamedTuple):
     """Everything `ddpg.update` needs back from the 2 launches.
 
-    The eight parameter fields are, from `fxp_mlp_train_step_padded`, the
-    interleaved [w0, b0, w1, b1, ...] leaves in the kernels' layout
-    (zero-padded, exactly 0 outside the unpadded widths), and from
-    `fxp_mlp_train_step`, ((w per layer), (b per layer)) unpadded."""
+    The eight parameter fields are the interleaved [w0, b0, w1, b1, ...]
+    leaves in the kernels' layout (zero-padded, exactly 0 outside the
+    unpadded widths)."""
 
     actor: tuple
     critic: tuple
@@ -468,46 +455,3 @@ def fxp_mlp_train_step_padded(obs, action, reward, done, next_obs, w,
         critic_m=tuple(ncm), critic_v=tuple(ncv),
         closs_sum=part1[0], y_sum=part1[1], q_sum=part2[0],
         c_mins=c_mins, c_maxs=c_maxs, a_mins=a_mins, a_maxs=a_maxs)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "actor_acts", "critic_acts", "obs_dim", "act_dim", "gamma", "tau",
-    "n_bits", "qat", "fxp32_phase1", "fxp_weights", "interpret"))
-def fxp_mlp_train_step(obs, action, reward, done, next_obs, w,
-                       actor_wb, critic_wb, actor_t_wb, critic_t_wb,
-                       actor_m, actor_v, critic_m, critic_v,
-                       deltas, zs, consts_c, consts_a, quant_phase, *,
-                       actor_acts, critic_acts, obs_dim: int, act_dim: int,
-                       gamma: float, tau: float, n_bits: int = 16,
-                       qat: bool = True, fxp32_phase1: bool = True,
-                       fxp_weights: bool = True,
-                       interpret: Optional[bool] = None) -> TrainStepOut:
-    """`fxp_mlp_train_step_padded` on unpadded leaves: every *_wb / moment
-    argument, and each of the eight parameter fields of the result, is
-    ((w per layer), (b per layer)) of UNPADDED leaves.  It pads all eight
-    on the way in and slices them back out on the way out, every call; a
-    caller that runs many updates carries the padded leaves instead
-    (`ddpg.pad_state`)."""
-    a_ws, a_bs = actor_wb
-    c_ws, c_bs = critic_wb
-    actor_dims = (obs_dim,) + tuple(int(x.shape[1]) for x in a_ws)
-    critic_dims = (obs_dim + act_dim,) + tuple(int(x.shape[1]) for x in c_ws)
-    assert actor_dims[-1] == act_dim, (actor_dims, act_dim)
-
-    out = fxp_mlp_train_step_padded(
-        obs, action, reward, done, next_obs, w,
-        *(pad_wb(*wb) for wb in (actor_wb, critic_wb, actor_t_wb,
-                                  critic_t_wb, actor_m, actor_v, critic_m,
-                                  critic_v)),
-        deltas, zs, consts_c, consts_a, quant_phase,
-        actor_acts=actor_acts, critic_acts=critic_acts,
-        actor_dims=actor_dims, critic_dims=critic_dims, gamma=gamma, tau=tau,
-        n_bits=n_bits, qat=qat, fxp32_phase1=fxp32_phase1,
-        fxp_weights=fxp_weights, interpret=interpret)
-
-    a, c = actor_dims, critic_dims
-    return out._replace(
-        actor=unpad_wb(out.actor, a), critic=unpad_wb(out.critic, c),
-        actor_t=unpad_wb(out.actor_t, a), critic_t=unpad_wb(out.critic_t, c),
-        actor_m=unpad_wb(out.actor_m, a), actor_v=unpad_wb(out.actor_v, a),
-        critic_m=unpad_wb(out.critic_m, c), critic_v=unpad_wb(out.critic_v, c))

@@ -62,7 +62,7 @@ class DispatchAudit:
             self._g_stale.set(0.0)
 
     def record(self, phase: str, mode: str, bucket: int, measured_s: float) -> None:
-        predicted_us = self.cost_model.estimate_us(mode, bucket, self.dims, phase)
+        predicted_us = self.cost_model.estimate_us(mode, bucket, self.dims)
         measured_us = measured_s * 1e6
         log_ratio = math.log(max(measured_us, _EPS_US) / max(predicted_us, _EPS_US))
         key = (phase, mode, int(bucket))

@@ -6,25 +6,28 @@ Both optimized with Adam, lr 1e-4 (paper), weights/grads projected onto the
 Q15.16 lattice every step (fixed-point weight & gradient memories, §III),
 activations run through QAT sites (Algorithm 1).
 
-Backends:
-  * `backend="jnp"` (default, training) — dense layers via jnp.dot on
-    fake-quantized values; differentiable, fast on CPU.
+Backends (`DDPGConfig.backend`, the one place the update is chosen):
+  * `backend="jnp"` (default) — dense layers via jnp.dot on fake-quantized
+    values; `update()` trains through XLA autodiff.
   * `backend="pallas"` — the network-resident fused kernel
     (kernels/fxp_mlp): ONE Pallas call runs the whole actor/critic forward
     with all weights VMEM-resident, QAT sites fused between layers and the
-    dual-precision datapath flipped by a scalar-prefetch phase flag (no
-    lax.cond double-trace).  Trainable: the fused forward carries a custom
-    VJP whose backward pass is a second network-resident Pallas launch
-    (whole dW/db/dx chain, STE at the QAT sites), so `update()` runs the
-    paper's BP/WU sequence through the fused kernel too.
-  * `backend="pallas_layer"` — the per-layer dual-precision AAP-core kernel
-    chain (kernels/fxp_matmul), precision switched by the QAT phase at
-    runtime via lax.cond; kept as the reference/fallback for the fused path.
+    dual-precision datapath flipped by a scalar-prefetch phase flag.  The
+    fused forward carries a custom VJP whose backward pass is a second
+    network-resident Pallas launch, so `update()` runs the paper's BP/WU
+    sequence through the fused kernel.  It is the reference the fused
+    step's parity tests compare against.
+  * `backend="pallas_fused_step"` — the whole update (both losses'
+    fwd+bwd, Adam, the target soft-update) in TWO Pallas launches; acting
+    runs the fused forward.  A loop that runs many updates carries the
+    agent in the kernels' padded layout (`to_carry` / `from_carry`).
+
+Serving's per-layer kernel chain (kernels/fxp_matmul) is no training
+backend: it runs through `act_batch(mode="layer")`.
 """
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
 from typing import Any, Optional
 
 import jax
@@ -33,7 +36,7 @@ import jax.numpy as jnp
 from repro.core import fixedpoint as fxp
 from repro.core.qat import (FrozenQuant, QATContext, QATState, freeze_quant,
                             quantize_grads)
-from repro.kernels.fxp_matmul.ops import fxp_dense, fxp_dense_chain
+from repro.kernels.fxp_matmul.ops import fxp_dense_chain
 from repro.kernels.fxp_mlp.ops import (fxp_mlp_infer, fxp_mlp_train,
                                        fxp_mlp_train_step_padded, pad_bias,
                                        pad_weight, unpad_wb)
@@ -48,6 +51,7 @@ CRITIC_SITES = ["critic/l0", "critic/l1", "critic/l2"]
 ACTOR_ACTS = ("relu", "relu", "tanh")
 CRITIC_ACTS = ("relu", "relu", "none")
 HIDDEN = (400, 300)  # paper §VI-B
+BACKENDS = ("jnp", "pallas", "pallas_fused_step")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -61,8 +65,13 @@ class DDPGConfig:
     qat_bits: int = 16
     qat_enabled: bool = True
     fxp_weights: bool = True    # project weights/grads to Q15.16
-    backend: str = "jnp"        # "jnp" | "pallas" (fused) | "pallas_layer"
+    backend: str = "jnp"        # one of BACKENDS
     exploration_sigma: float = 0.1
+
+    def __post_init__(self):
+        if self.backend not in BACKENDS:
+            raise ValueError(f"backend={self.backend!r}; DDPG trains through "
+                             "'jnp', 'pallas' or 'pallas_fused_step'")
 
 
 @jax.tree_util.register_dataclass
@@ -87,9 +96,9 @@ class PaddedDDPGState:
     moments, with the unpadded widths (K0, N1, ..., NL) of each net.
 
     The fused update (backend "pallas_fused_step") keeps the padded regions
-    exactly 0, so a loop that runs many updates pads once (`pad_state`),
+    exactly 0, so a loop that runs many updates pads once (`to_carry`),
     carries this through `act` and `update`, and unpads once
-    (`unpad_state`): no update pads or slices the eight parameter sets."""
+    (`from_carry`): no update pads or slices the eight parameter sets."""
 
     agent: DDPGState
     actor_dims: tuple = dataclasses.field(metadata=dict(static=True))
@@ -112,13 +121,7 @@ def _init_mlp(key, sizes: list[int]) -> Params:
             for i in range(len(sizes) - 1)}
 
 
-def _dense(x, layer, activation: str, *, backend: str, quant_phase) -> Array:
-    if backend == "pallas_layer":
-        full = partial(fxp_dense, full_precision=True, activation=activation)
-        half = partial(fxp_dense, full_precision=False, activation=activation)
-        return jax.lax.cond(quant_phase,
-                            lambda a: half(a, layer["w"], layer["b"]),
-                            lambda a: full(a, layer["w"], layer["b"]), x)
+def _dense(x, layer, activation: str) -> Array:
     y = x @ layer["w"] + layer["b"]
     if activation == "relu":
         y = jax.nn.relu(y)
@@ -163,16 +166,10 @@ def _mlp_forward(params: Params, x: Array, ctx: Optional[QATContext],
         # the fused-step backend only changes how update() runs BP/WU; any
         # plain forward (acting, evaluation) is the fused kernel either way
         return _fused_mlp(params, x, ctx, sites=sites, activations=activations)
-    # half-precision dense is tied to activation quantization: with QAT off
-    # there is no quantized phase, so the datapath stays full precision
-    # (keeps this path bit-comparable with the fused kernel's qat=False mode)
-    qp = (ctx.state.quantized_phase
-          if ctx is not None and ctx.state.config.enabled
-          else jnp.array(False))
     for i, act in enumerate(activations):
         if ctx is not None:
             x = ctx.site(sites[i], x)
-        x = _dense(x, params[f"l{i}"], act, backend=backend, quant_phase=qp)
+        x = _dense(x, params[f"l{i}"], act)
     return x
 
 
@@ -286,8 +283,7 @@ def act_batch(actor: Params, obs: Array,
         for i, act_name in enumerate(ACTOR_ACTS):
             if frozen is not None:
                 x = frozen.site(i, x)
-            x = _dense(x, {"w": ws[i], "b": bs[i]}, act_name,
-                       backend="jnp", quant_phase=None)
+            x = _dense(x, {"w": ws[i], "b": bs[i]}, act_name)
         y = x
     else:
         raise ValueError(f"unknown serve mode {mode!r}; expected "
@@ -336,8 +332,7 @@ def actor_site_telemetry(actor: Params, obs: Array,
             sats.append(jnp.float32(0.0))
         if frozen is not None:
             x = frozen.site(i, x)
-        x = _dense(x, actor[f"l{i}"], act_name, backend="jnp",
-                   quant_phase=None)
+        x = _dense(x, actor[f"l{i}"], act_name)
     return jnp.stack(mns), jnp.stack(mxs), jnp.stack(sats)
 
 
@@ -385,8 +380,14 @@ def _map_nets(state: DDPGState, actor_fn, critic_fn) -> DDPGState:
         critic_opt=opt(state.critic_opt, critic_fn))
 
 
-def pad_state(state: DDPGState) -> PaddedDDPGState:
-    """`state` in the fused kernels' layout."""
+def to_carry(state: DDPGState,
+             cfg: DDPGConfig) -> DDPGState | PaddedDDPGState:
+    """The agent in the form a loop of many `act`s and `update`s carries:
+    for backend "pallas_fused_step" a `PaddedDDPGState` (every leaf in the
+    fused kernels' layout), for the other backends `state` itself."""
+    if cfg.backend != "pallas_fused_step":
+        return state
+
     def pad(p):
         return {k: {"w": pad_weight(l["w"]), "b": pad_bias(l["b"])}
                 for k, l in p.items()}
@@ -395,8 +396,11 @@ def pad_state(state: DDPGState) -> PaddedDDPGState:
                            critic_dims=_net_dims(state.critic))
 
 
-def unpad_state(state: PaddedDDPGState) -> DDPGState:
-    """Inverse of `pad_state`."""
+def from_carry(state: DDPGState | PaddedDDPGState) -> DDPGState:
+    """Inverse of `to_carry`."""
+    if not isinstance(state, PaddedDDPGState):
+        return state
+
     def unpad(dims):
         return lambda p: _wb_to_params(unpad_wb(_interleaved(p), dims))
     return _map_nets(state.agent, unpad(state.actor_dims),
@@ -487,8 +491,8 @@ def _update_fused_step(state: DDPGState, batch: dict[str, Array],
                        cfg: DDPGConfig) -> tuple[DDPGState, dict[str, Array]]:
     """`_update_padded` on an unpadded agent: pad all eight parameter sets,
     update, unpad — every call."""
-    new_state, metrics = _update_padded(pad_state(state), batch, cfg)
-    return unpad_state(new_state), metrics
+    new_state, metrics = _update_padded(to_carry(state, cfg), batch, cfg)
+    return from_carry(new_state), metrics
 
 
 def update(state: DDPGState | PaddedDDPGState, batch: dict[str, Array],
@@ -497,10 +501,10 @@ def update(state: DDPGState | PaddedDDPGState, batch: dict[str, Array],
     """One FIXAR timestep's training work: critic BP/WU then actor BP/WU
     (operation sequence of Fig. 3), QAT-aware, fixed-point weights.
 
-    Trains with `backend="jnp"` (XLA autodiff) or `backend="pallas"` (the
+    Trains through `cfg.backend`: `"jnp"` (XLA autodiff), `"pallas"` (the
     fused kernel's custom VJP: fwd + bwd are one network-resident Pallas
-    launch each).  The per-layer chain has no autodiff rule and stays
-    inference-only.
+    launch each) or `"pallas_fused_step"` (two launches for the whole
+    update).
 
     `batch` may carry an optional `"mask"` row — (B,) validity weights, the
     contract `train/learner` uses to pad update streams to its batching
@@ -510,22 +514,13 @@ def update(state: DDPGState | PaddedDDPGState, batch: dict[str, Array],
     extrema; all-zero pad rows only widen a range that excludes 0, which
     mid-training activations essentially never do).
 
-    A `PaddedDDPGState` (backend "pallas_fused_step" only) is updated and
-    returned in the fused kernels' layout.
+    A `PaddedDDPGState` (what `to_carry` makes for "pallas_fused_step") is
+    updated and returned in the fused kernels' layout.
     """
     if isinstance(state, PaddedDDPGState):
-        if cfg.backend != "pallas_fused_step":
-            raise ValueError("a PaddedDDPGState trains only with "
-                             "backend='pallas_fused_step'")
         return _update_padded(state, batch, cfg)
     if cfg.backend == "pallas_fused_step":
         return _update_fused_step(state, batch, cfg)
-    if cfg.backend not in ("jnp", "pallas"):
-        raise ValueError(
-            f"backend={cfg.backend!r} is forward/inference-only (the "
-            "per-layer kernel chain has no autodiff rule); train with "
-            "backend='jnp', backend='pallas', or "
-            "backend='pallas_fused_step'")
     obs, action = batch["obs"], batch["action"]
     reward, next_obs = batch["reward"], batch["next_obs"]
     done = batch["done"].astype(jnp.float32)

@@ -11,12 +11,11 @@ Two execution modes:
     replay buffer, exploration noise, and the DDPG update all live in one
     jitted+scanned program — ``train_device`` runs an entire eval window
     (act → explore → env-step → store → update × window) as a SINGLE
-    ``lax.scan`` launch with zero host round-trips.  ``train_fused`` is the
-    legacy chunked driver over the same scanned window.
+    ``lax.scan`` launch with zero host round-trips.
 
-Both share the same DDPG update, QAT state, replay semantics, and the
-``TrainConfig`` knobs; ``LoopConfig`` is the deprecated alias of
-``TrainConfig`` kept for one release (same fields, same defaults).
+Both drivers, ``train_host`` and ``train_device``, share the same DDPG
+update (the one ``DDPGConfig.backend`` names), QAT state, replay
+semantics, and the ``TrainConfig`` knobs.
 """
 from __future__ import annotations
 
@@ -46,12 +45,9 @@ ACT, ENV, REPLAY_ADD, REPLAY_SAMPLE, UPDATE = PHASES
 
 @dataclasses.dataclass(frozen=True)
 class TrainConfig:
-    """One config for every training driver (`train_host` / `train_device` /
-    `train_fused`), mirroring `BatcherConfig` style: a single frozen —
-    hashable, therefore jit-static — dataclass instead of per-driver kwarg
-    sprawl.  Legacy call surfaces (`LoopConfig`, `train_fused(chunk=...)`)
-    normalize onto this through `as_train_config`, the one conversion path.
-    """
+    """One config for both training drivers (`train_host` /
+    `train_device`): a single frozen — hashable, therefore jit-static —
+    dataclass instead of per-driver kwarg sprawl."""
 
     total_steps: int = 10_000
     warmup_steps: int = 1_000          # env steps before updates start
@@ -60,32 +56,8 @@ class TrainConfig:
     eval_episodes: int = 10            # paper: 10 random starts
     n_envs: int = 1                    # device mode farms a vmapped fleet
     seed: int = 0
-    chunk: int = 1000                  # train_fused scan-window length
     noise_kind: str = "gaussian"       # rl/noise process: gaussian|ou|none
     noise_sigma: Optional[float] = None  # None -> dcfg.exploration_sigma
-
-
-# Deprecated alias (pre-redesign name), kept through one release.  Same
-# class on purpose: old constructor kwargs keep working and isinstance
-# checks stay true either way.
-LoopConfig = TrainConfig
-
-
-def as_train_config(cfg=None, **overrides) -> TrainConfig:
-    """The single normalization path from every legacy surface onto
-    `TrainConfig`: pass-through for `TrainConfig`/`LoopConfig`, field-copy
-    for duck-typed config objects, kwargs for dicts/None.  `overrides`
-    carries legacy per-call kwargs (e.g. `train_fused(chunk=...)`); only
-    non-None overrides win."""
-    if cfg is None:
-        cfg = TrainConfig()
-    elif isinstance(cfg, dict):
-        cfg = TrainConfig(**cfg)
-    elif not isinstance(cfg, TrainConfig):
-        names = (f.name for f in dataclasses.fields(TrainConfig))
-        cfg = TrainConfig(**{n: getattr(cfg, n) for n in names if hasattr(cfg, n)})
-    live = {k: v for k, v in overrides.items() if v is not None}
-    return dataclasses.replace(cfg, **live) if live else cfg
 
 
 def _noise_proc(cfg: TrainConfig, dcfg: ddpg.DDPGConfig) -> NoiseProcess:
@@ -96,7 +68,7 @@ def _noise_proc(cfg: TrainConfig, dcfg: ddpg.DDPGConfig) -> NoiseProcess:
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class TrainState:
-    agent: ddpg.DDPGState    # a ddpg.PaddedDDPGState inside the fused-step window's scan
+    agent: ddpg.DDPGState | ddpg.PaddedDDPGState  # the latter inside a window (ddpg.to_carry)
     env_state: EnvState      # fleet-batched (leading n_envs axis)
     obs: Array               # (n_envs, obs_dim)
     buf: replay.ReplayBuffer
@@ -105,7 +77,6 @@ class TrainState:
 
 
 def init_train_state(env, cfg: TrainConfig, dcfg: ddpg.DDPGConfig) -> TrainState:
-    cfg = as_train_config(cfg)
     key = jax.random.key(cfg.seed)
     k_agent, k_env, k_loop = jax.random.split(key, 3)
     agent = ddpg.init(k_agent, env.spec, dcfg)
@@ -124,7 +95,7 @@ def init_train_state(env, cfg: TrainConfig, dcfg: ddpg.DDPGConfig) -> TrainState
 # --------------------------------------------------------------------- #
 
 def _act_explore(
-    agent: ddpg.DDPGState,
+    agent: ddpg.DDPGState | ddpg.PaddedDDPGState,
     obs: Array,
     nz: NoiseState,
     k_noise: Array,
@@ -138,7 +109,7 @@ def _act_explore(
 
 
 def _policy_env_step(
-    agent: ddpg.DDPGState,
+    agent: ddpg.DDPGState | ddpg.PaddedDDPGState,
     env_state: EnvState,
     obs: Array,
     *,
@@ -214,21 +185,17 @@ def _train_window(
     the scanned body — the retrace regression is pinned in
     tests/test_loop.py.
 
-    With the two-launch fused update (`pallas_fused_step`) the agent is
-    padded into the kernels' layout once here and unpadded once at the
-    end: the scan carries it padded, so no timestep pads or slices the
-    eight parameter sets around the launches."""
-    padded = dcfg.backend == "pallas_fused_step"
-    if padded:
-        ts = dataclasses.replace(ts, agent=ddpg.pad_state(ts.agent))
+    The scan carries the agent in the form `ddpg.to_carry` gives for
+    `dcfg.backend` (the fused step's padded layout: no timestep pads or
+    slices the eight parameter sets around the launches)."""
+    ts = dataclasses.replace(ts, agent=ddpg.to_carry(ts.agent, dcfg))
 
     def body(carry, _):
         carry, m = _one_timestep(carry, env, cfg, dcfg)
         return carry, (m["reward"], m["did_update"])
 
     ts, (rewards, updates) = jax.lax.scan(body, ts, None, length=window)
-    if padded:
-        ts = dataclasses.replace(ts, agent=ddpg.unpad_state(ts.agent))
+    ts = dataclasses.replace(ts, agent=ddpg.from_carry(ts.agent))
     return ts, {"reward": jnp.mean(rewards), "updates": jnp.sum(updates)}
 
 
@@ -242,15 +209,14 @@ def train_device(
     """Fully device-resident training: each eval window (`cfg.eval_every`
     timesteps x `cfg.n_envs` fleet lanes) runs as a single jitted
     `lax.scan` launch — the host only reads back the window's scalar
-    metrics and runs the (also single-launch) evaluation.  Updates
-    dispatch through whatever `dcfg.backend` names (`jnp` autodiff, the
-    `pallas` custom-VJP pair, or the two-launch `pallas_fused_step`).
+    metrics and runs the (also single-launch) evaluation.  Updates run
+    through whatever backend `dcfg.backend` names.
 
     History per window: `step`, `eval_reward`, `train_reward` (window mean
     fleet reward), `ips` (env-steps/s = window x n_envs / wall), and
     `updates_per_s` (post-warmup updates / wall).
     """
-    cfg = as_train_config(cfg)
+    cfg = TrainConfig() if cfg is None else cfg
     dcfg = ddpg.DDPGConfig() if dcfg is None else dcfg
     ts = init_train_state(env, cfg, dcfg)
     evaluator = evaluate if eval_fn is None else eval_fn
@@ -273,48 +239,6 @@ def train_device(
     return ts, history
 
 
-def train_fused(
-    env,
-    cfg: TrainConfig,
-    dcfg: ddpg.DDPGConfig,
-    eval_fn: Optional[Callable] = None,
-    chunk: Optional[int] = None,
-) -> tuple[TrainState, dict[str, Any]]:
-    """Legacy chunked driver over the same scanned window as
-    `train_device` (the `chunk` kwarg keeps working and overrides
-    `cfg.chunk`).  Returns final state + history of eval rewards."""
-    cfg = as_train_config(cfg, chunk=chunk)
-    ts = init_train_state(env, cfg, dcfg)
-    evaluator = evaluate if eval_fn is None else eval_fn
-
-    history = {"step": [], "eval_reward": [], "train_reward": [], "ips": []}
-    steps_done = 0
-    # accumulate across the whole eval window, not just the chunk that
-    # happens to land on the eval boundary — with eval_every > chunk the
-    # recorded train_reward/ips used to describe only the LAST chunk
-    win_reward, win_chunks, win_steps, win_secs = 0.0, 0, 0, 0.0
-    while steps_done < cfg.total_steps:
-        t0 = time.perf_counter()
-        ts, stats = _train_window(ts, env=env, cfg=cfg, dcfg=dcfg, window=cfg.chunk)
-        mean_r = stats["reward"]
-        jax.block_until_ready(mean_r)
-        dt = time.perf_counter() - t0
-        steps_done += cfg.chunk
-        win_reward += float(mean_r)
-        win_chunks += 1
-        win_steps += cfg.chunk * max(cfg.n_envs, 1)
-        win_secs += dt
-        if steps_done % cfg.eval_every < cfg.chunk:
-            k_eval = jax.random.fold_in(jax.random.key(cfg.seed + 7), steps_done)
-            ev = evaluator(env, ts.agent, dcfg, k_eval, cfg.eval_episodes)
-            history["step"].append(steps_done)
-            history["eval_reward"].append(float(ev))
-            history["train_reward"].append(win_reward / win_chunks)
-            history["ips"].append(win_steps / win_secs)
-            win_reward, win_chunks, win_steps, win_secs = 0.0, 0, 0, 0.0
-    return ts, history
-
-
 def train_host(
     env, cfg: TrainConfig, dcfg: ddpg.DDPGConfig, *, learner=None, tracer=None, observability=None
 ) -> tuple[TrainState, dict[str, Any]]:
@@ -328,10 +252,10 @@ def train_host(
     `learner` (optional) is a `train/learner.LearnerEngine` (or anything
     with its `load_state`/`run_update`/`state` surface): when given, the
     freshly initialized agent is installed into the engine and every
-    update streams through `learner.run_update(batch)` — bucket padding,
-    train-phase adaptive dispatch, and learner metrics included — instead
-    of the loop's own jitted `ddpg.update`.  The engine's update backend
-    is whatever its dispatcher picks; `dcfg.backend` still drives acting.
+    update streams through `learner.run_update(batch)` — bucket padding
+    and learner metrics included — instead of the loop's own jitted
+    `ddpg.update`.  The engine trains through the backend of the config it
+    was built with; `dcfg.backend` drives acting.
 
     `tracer` (optional) is an `obs.Tracer`: when enabled, every timestep
     emits its phases as spans named from `PHASES` (`act` / `env` /
@@ -346,7 +270,6 @@ def train_host(
     /healthz while training, and the tracer is flushed on exit — normal
     or aborted — so the trace always lands on disk.
     """
-    cfg = as_train_config(cfg)
     if observability is not None:
         if tracer is None:
             tracer = observability.tracer

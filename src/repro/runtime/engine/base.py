@@ -8,8 +8,8 @@ time):
     latency histogram, occupancy, phase-keyed mode histogram), the
     optional `DispatchAudit` (predicted-vs-measured, when the engine has
     a cost model), `QATTelemetry`, and health registration;
-  * the adaptive dispatch hook — `choose_mode(bucket)` over the engine's
-    phase axis, with `force_mode` pinning;
+  * the adaptive dispatch hook — `choose_mode(bucket)`: the only mode
+    of a single-mode engine, else `force_mode` or the cost model's argmin;
   * the serve-thread lifecycle — `start` / `stop` (close-before-drain:
     sustained client traffic cannot livelock the shutdown, and any
     request that races past the close is failed loudly, never left
@@ -113,9 +113,11 @@ class StreamEngine:
     # ------------------------------------------------------------------ #
 
     def choose_mode(self, bucket: int) -> str:
+        if len(self.modes) == 1:
+            return self.modes[0]
         if self.force_mode is not None:
             return self.force_mode
-        return self.cost_model.choose(bucket, self.dims, self.modes, phase=self.phase)
+        return self.cost_model.choose(bucket, self.dims, self.modes)
 
     def _finish_call(self, items: int, bucket: int, mode: str, device_s: float) -> bool:
         """Account one dispatched device call (audit + metrics); returns

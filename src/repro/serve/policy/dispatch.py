@@ -18,29 +18,18 @@ The dispatcher scores each mode with a two-term affine cost
                + B * kflops_per_item * us_per_kflop[mode]
 
 and picks the argmin.  Launch counts and FLOP shapes come from the kernels'
-own cost hints (`fused_cost_hint` / `chain_cost_hint`, each with an
-"act"/"train" phase axis now that the fused kernel trains through its custom
-VJP), so the model tracks the kernels if their structure changes.  The
-default coefficients encode the hardware-shaped regime (fused pays a big
-single-launch setup for the best per-item rate; the per-layer chain is the
-cheapest way to finish one vector); `CostModel.from_bench` refits the model
-from measured `BENCH_fused_mlp.json` acting-path IPS — with the two-batch
+own cost hints (`fused_cost_hint` / `chain_cost_hint`), so the model tracks
+the kernels if their structure changes.  The default coefficients encode
+the hardware-shaped regime (fused pays a big single-launch setup for the
+best per-item rate; the per-layer chain is the cheapest way to finish one
+vector); `CostModel.from_bench` refits the model from measured
+`BENCH_fused_mlp.json` acting-path IPS — with the two-batch
 `actor_ips_by_batch` measurements it separates slope (per-item rate) from
-intercept (launch overhead), which is what `benchmarks/serve_bench` consumes
-on real hardware.
+intercept (launch overhead), which is what `benchmarks/serve_bench`
+consumes.
 
-The WHOLE CostModel API carries the phase axis: `estimate_us`, `choose`,
-and `launches` all take `phase="act" | "train"` (they used to hardcode the
-acting path even though `cost_hint` already modeled training — a train-time
-mode choice was silently costed as inference).  Train-phase coefficients
-live in `CostModel.train_costs`: empty by default (the act coefficients are
-reused against the train-phase launch/FLOP hints, which already encode the
-2-launch / ~3x-MAC custom-VJP shape), and fitted per mode by `from_bench`
-from the `BENCH_fused_mlp.json["train"]` section — two-point from
-`train.ips_by_batch` when present, single-point from `train.updates_per_s`
-otherwise.  `train/learner` dispatches its update streams through
-`choose(..., phase="train")` over `TRAIN_MODES` (the per-layer chain has no
-autodiff rule, so it never appears in a train-phase argmin).
+The dispatcher prices the act phase only: training runs the one update
+`ddpg.DDPGConfig.backend` names, so the learner has no choice to make.
 """
 from __future__ import annotations
 
@@ -54,48 +43,21 @@ from repro.kernels.fxp_matmul.ops import chain_cost_hint
 from repro.kernels.fxp_mlp.ops import fused_cost_hint
 
 MODES = ("fused", "layer", "jnp")
-# the modes a train-phase dispatch may pick: the per-layer chain is
-# forward-only (no autodiff rule), so it never enters a train argmin;
-# fused_step is the 2-launch whole-update kernel (fwd+bwd+Adam+soft-update
-# resident per loss) and is train-only — it has no acting face
-TRAIN_MODES = ("fused_step", "fused", "jnp")
 
-# maps a DDPG backend name (BENCH_fused_mlp.json's actor_ips keys) to a mode
-BACKEND_TO_MODE = {"pallas": "fused", "pallas_layer": "layer", "jnp": "jnp",
-                   "pallas_fused_step": "fused_step"}
+# maps BENCH_fused_mlp.json's actor_ips keys to a mode
+BACKEND_TO_MODE = {"pallas": "fused", "pallas_layer": "layer", "jnp": "jnp"}
 
 
-def cost_hint(mode: str, dims: Sequence[int], phase: str = "act") -> dict:
-    """The per-mode launch/FLOP shape: the two kernel modes describe
-    themselves (`fused_cost_hint` / `chain_cost_hint`); the jnp fallback is
-    one fused XLA dispatch over the same MLP.
-
-    phase="act" is the forward/acting path (serving); phase="train" models
-    one fwd+bwd step (the fused kernel's custom-VJP pair = 2 launches and
-    ~3x the MACs), keeping the dispatcher's cost axis consistent with what
-    `kernels/fxp_mlp.fxp_mlp_train` actually launches.
-    """
-    if phase not in ("act", "train"):
-        raise ValueError(f"unknown cost phase {phase!r}; 'act' | 'train'")
-    if mode == "fused_step":
-        if phase != "train":
-            raise ValueError(
-                "mode 'fused_step' is train-only (the whole-update kernel "
-                "has no acting face); use 'fused' for the act phase")
-        # one whole ddpg.update: 2 launches (critic step, actor step).  The
-        # FLOP axis stays per-loss-normalized (~3x a forward, same axis as
-        # 'fused') so the two modes' fitted rates are directly comparable;
-        # the second loss's MACs and the batch-independent Adam/soft-update
-        # epilogues fold into the fitted coefficients
-        return {"launches": 2, "flops_per_item": 3 * flops_per_item(dims),
-                "parallelism": "intra_batch"}
+def cost_hint(mode: str, dims: Sequence[int]) -> dict:
+    """The per-mode launch/FLOP shape of one act: the two kernel modes
+    describe themselves (`fused_cost_hint` / `chain_cost_hint`); the jnp
+    fallback is one fused XLA dispatch over the same MLP."""
     if mode == "fused":
-        return fused_cost_hint(dims, phase)
+        return fused_cost_hint(dims)
     if mode == "layer":
-        return chain_cost_hint(dims, phase)
+        return chain_cost_hint(dims)
     if mode == "jnp":
-        mult = 3 if phase == "train" else 1
-        return {"launches": 1, "flops_per_item": mult * flops_per_item(dims),
+        return {"launches": 1, "flops_per_item": flops_per_item(dims),
                 "parallelism": "none"}
     raise ValueError(f"unknown serve mode {mode!r}; expected one of {MODES}")
 
@@ -114,26 +76,14 @@ DEFAULT_COSTS = {
     "fused": ModeCost(per_launch_us=120.0, us_per_kflop=0.0010),
     "layer": ModeCost(per_launch_us=10.0, us_per_kflop=0.0045),
     "jnp": ModeCost(per_launch_us=45.0, us_per_kflop=0.0120),
-    # train-only whole-update kernel: fused's launch overhead minus the
-    # per-launch residual traffic it no longer pays, slightly better
-    # per-kflop rate (no HBM residual round-trip between fwd and bwd)
-    "fused_step": ModeCost(per_launch_us=110.0, us_per_kflop=0.0009),
 }
 
 
 @dataclasses.dataclass
 class CostModel:
-    """Per-(phase, mode) affine latency model + argmin chooser.
-
-    `costs` holds the act-phase coefficients; `train_costs` holds per-mode
-    train-phase overrides.  A mode missing from `train_costs` falls back to
-    its act coefficients — the phase-dependent launch/FLOP *hints* already
-    model the custom-VJP shape (2 launches, ~3x MACs), so the fallback is a
-    structural estimate rather than a phase-blind one.
-    """
+    """Per-mode affine latency model + argmin chooser."""
 
     costs: dict[str, ModeCost]
-    train_costs: dict[str, ModeCost] = dataclasses.field(default_factory=dict)
     source: str = "default"
 
     @staticmethod
@@ -141,35 +91,25 @@ class CostModel:
         return CostModel(dict(DEFAULT_COSTS))
 
     @staticmethod
-    def launches(mode: str, dims: Sequence[int], phase: str = "act") -> int:
-        return cost_hint(mode, dims, phase)["launches"]
+    def launches(mode: str, dims: Sequence[int]) -> int:
+        return cost_hint(mode, dims)["launches"]
 
-    def coeffs(self, mode: str, phase: str = "act") -> ModeCost:
-        """The fitted coefficients serving a (mode, phase) estimate."""
-        if phase == "train" and mode in self.train_costs:
-            return self.train_costs[mode]
-        return self.costs[mode]
-
-    def estimate_us(self, mode: str, batch: int, dims: Sequence[int],
-                    phase: str = "act") -> float:
-        c = self.coeffs(mode, phase)
-        hint = cost_hint(mode, dims, phase)
+    def estimate_us(self, mode: str, batch: int, dims: Sequence[int]) -> float:
+        c = self.costs[mode]
+        hint = cost_hint(mode, dims)
         kflops = batch * hint["flops_per_item"] / 1e3
         return c.per_launch_us * hint["launches"] + c.us_per_kflop * kflops
 
     def choose(self, batch: int, dims: Sequence[int],
-               modes: Optional[Sequence[str]] = None,
-               phase: str = "act") -> str:
-        if modes is None:
-            modes = TRAIN_MODES if phase == "train" else MODES
-        return min(modes,
-                   key=lambda m: self.estimate_us(m, batch, dims, phase))
+               modes: Optional[Sequence[str]] = None) -> str:
+        return min(MODES if modes is None else modes,
+                   key=lambda m: self.estimate_us(m, batch, dims))
 
     @staticmethod
-    def _fit_mode(mode: str, net: Sequence[int], phase: str,
-                  by_batch: dict, single_us: Optional[float],
-                  single_batch: int, base: ModeCost) -> Optional[ModeCost]:
-        """One (mode, phase) affine fit from measured throughput.
+    def _fit_mode(mode: str, net: Sequence[int], by_batch: dict,
+                  single_us: Optional[float], single_batch: int,
+                  base: ModeCost) -> Optional[ModeCost]:
+        """One mode's affine fit from measured throughput.
 
         Preferred input: `by_batch` — {batch: items-per-second} at TWO (or
         more) batch sizes.  Two measurements separate the slope from the
@@ -182,7 +122,7 @@ class CostModel:
         `single_batch` items — keep `base`'s launch overhead and back out
         the marginal rate.  Returns None when nothing usable was measured.
         """
-        hint = cost_hint(mode, net, phase)
+        hint = cost_hint(mode, net)
         kflops = hint["flops_per_item"] / 1e3
 
         # ---- two-point fit: slope AND intercept ---------------------------
@@ -208,26 +148,17 @@ class CostModel:
 
     @staticmethod
     def from_bench(path, fallback_to_default: bool = True) -> "CostModel":
-        """Recalibrate the affine cost model from `BENCH_fused_mlp.json`.
-
-        Act phase: fits from `actor_ips_by_batch` (two-point, both
-        coefficients) or the legacy single-batch `actor_ips` (rate only,
-        default overheads) — see `_fit_mode`.
-
-        Train phase: fits per-mode `train_costs` from the bench's `train`
-        section — two-point from `train.ips_by_batch` (trained-samples/sec
-        per batch size) when present, else single-point from
-        `train.updates_per_s` at `train.batch` (one update's wall time
-        against the train-phase launch/FLOP hint).
+        """Recalibrate the affine cost model from `BENCH_fused_mlp.json`:
+        fits from `actor_ips_by_batch` (two-point, both coefficients) or
+        the legacy single-batch `actor_ips` (rate only, default overheads)
+        — see `_fit_mode`.
 
         Missing file / missing modes / degenerate fits keep their defaults
         (the model must stay total — the dispatcher cannot refuse to
-        answer; an unfitted train mode estimates through its act
-        coefficients and the train-phase hint).
+        answer).
         """
         path = pathlib.Path(path)
         costs = dict(DEFAULT_COSTS)
-        train_costs: dict[str, ModeCost] = {}
         if not path.exists():
             if not fallback_to_default:
                 raise FileNotFoundError(path)
@@ -245,7 +176,7 @@ class CostModel:
                 try:
                     ips = float(single.get(backend, 0.0))
                     fit = CostModel._fit_mode(
-                        mode, net, "act", by_batch.get(backend, {}),
+                        mode, net, by_batch.get(backend, {}),
                         b0 / ips * 1e6 if ips > 0 else None, b0,
                         costs[mode])
                     if fit is not None:
@@ -253,25 +184,6 @@ class CostModel:
                 except (ValueError, TypeError, KeyError, AttributeError):
                     # one malformed backend entry must not discard the
                     # other modes' fits — THIS mode keeps its default
-                    if not fallback_to_default:
-                        raise
-                    continue
-            train = data.get("train", {}) or {}
-            tb = int(train.get("batch", b0))
-            t_by_batch = train.get("ips_by_batch", {})
-            t_single = train.get("updates_per_s", {})
-            for backend in sorted({*t_single, *t_by_batch}):
-                mode = BACKEND_TO_MODE.get(backend)
-                if mode is None:
-                    continue
-                try:
-                    ups = float(t_single.get(backend, 0.0))
-                    fit = CostModel._fit_mode(
-                        mode, net, "train", t_by_batch.get(backend, {}),
-                        1e6 / ups if ups > 0 else None, tb, costs[mode])
-                    if fit is not None:
-                        train_costs[mode] = fit
-                except (ValueError, TypeError, KeyError, AttributeError):
                     if not fallback_to_default:
                         raise
                     continue
@@ -283,8 +195,8 @@ class CostModel:
                 raise
             return CostModel(dict(DEFAULT_COSTS),
                              source=f"default (unreadable bench: {err})")
-        return CostModel(costs, train_costs, source=str(path))
+        return CostModel(costs, source=str(path))
 
 
-__all__ = ["MODES", "TRAIN_MODES", "ModeCost", "CostModel", "DEFAULT_COSTS",
+__all__ = ["MODES", "ModeCost", "CostModel", "DEFAULT_COSTS",
            "cost_hint", "flops_per_item"]

@@ -11,11 +11,8 @@ path; this package does the same for the update path:
                                   │  coalesce FIFO requests to ≤ max rows,
                                   │  pad to a bucket (+ zero-weight mask)
                                   ▼
-                     train-phase adaptive dispatcher
-                     (serve/policy/dispatch.CostModel, phase="train")
-                                  │  fused custom-VJP / jnp autodiff
-                                  ▼
-                     ONE ddpg.update per micro-batch
+                     ONE ddpg.update per micro-batch, through the
+                     backend its DDPGConfig names
                      (sequential: the learner owns the DDPGState)
                                   │
                  futures resolve ◀── per-request metrics
@@ -29,8 +26,8 @@ Design decisions, mirroring `serve/policy`'s engine doc:
     streamed run is a *deterministic* sequence of `ddpg.update` calls.
   * **Coalescing, not splitting.**  The throughput win is combining many
     small update requests (per-actor replay batches, trajectory chunks)
-    into one bucket-padded batch for ONE fused fwd+bwd launch pair —
-    intra-batch parallelism, the paper's training dataflow.  Oversized
+    into one bucket-padded batch for ONE update — intra-batch
+    parallelism, the paper's training dataflow.  Oversized
     requests are chunked to the top bucket at submit time.
   * **Bit-exact streaming.**  A request whose row count hits a bucket
     exactly runs the *same jitted `ddpg.update` executable* a direct call
@@ -38,14 +35,11 @@ Design decisions, mirroring `serve/policy`'s engine doc:
     tests/train/test_learner.py).  Padded batches carry a zero-weight
     `mask` row (`ddpg.update`'s weighted-loss contract), so pad rows
     contribute exactly zero gradient.
-  * **Phase-plumbed dispatch.**  Mode choice goes through
-    `CostModel.choose(..., phase="train")` over `TRAIN_MODES` — the
-    train-phase cost axis (2 launches, ~3x MACs for the fused VJP pair)
-    that `serve/policy/dispatch` now carries end to end, recalibratable
-    from `BENCH_fused_mlp.json["train"]` via `CostModel.from_bench`.
-  * **Generic update family.**  The engine drives any
-    `update_fn(state, batch) -> (state, metrics)` keyed by mode;
-    `LearnerEngine.from_ddpg` builds the DDPG family (fused/jnp), and
+  * **One update, no dispatch.**  `ddpg.DDPGConfig.backend` alone decides
+    how DDPG trains; `LearnerEngine.from_ddpg` jits `ddpg.update` for it
+    and names the engine's one mode after it.
+  * **Generic update family.**  The engine drives any one
+    `update_fn(state, batch) -> (state, metrics)`, keyed by its mode name;
     `train/step.learner_update_fns` adapts the LM train step.
 
 `benchmarks/learner_bench.py` turns this into the Fig. 9-comparable
@@ -54,12 +48,10 @@ p50/p99, per-phase mode histogram), schema-gated in CI next to the kernel
 and serving artifacts.
 
 Public API:
-  LearnerEngine   — queue + micro-batch + train-phase dispatch + metrics
+  LearnerEngine   — queue + micro-batch + one update function + metrics
   UpdateBatcher   — multi-row request queue (reuses serve/policy machinery)
-  TRAIN_BACKENDS  — dispatch mode -> trainable ddpg backend
 """
 from repro.train.learner.batcher import UpdateBatcher, UpdateRequest
-from repro.train.learner.engine import TRAIN_BACKENDS, LearnerEngine
+from repro.train.learner.engine import LearnerEngine
 
-__all__ = ["LearnerEngine", "UpdateBatcher", "UpdateRequest",
-           "TRAIN_BACKENDS"]
+__all__ = ["LearnerEngine", "UpdateBatcher", "UpdateRequest"]

@@ -1,11 +1,11 @@
 """Batched learner engine (the tentpole of train/learner — see __init__).
 
 `LearnerEngine` owns one training state and streams update requests
-through it: coalesce → pad to bucket → train-phase adaptive dispatch →
-ONE `update_fn` call per micro-batch, applied sequentially.
+through it: coalesce → pad to bucket → ONE call of its update function per
+micro-batch, applied sequentially.
 
-The queue, serve thread, dispatch hook, and observability wiring are the
-shared `repro.runtime.engine.StreamEngine`; this module keeps only the
+The queue, serve thread, and observability wiring are the shared
+`repro.runtime.engine.StreamEngine`; this module keeps only the
 learner-specific parts: sequential state mutation under `_ulock`, the
 mask/exact pad policy, oversized-request chunking, and the live-QAT
 telemetry probe.
@@ -14,11 +14,11 @@ Observability runs through `repro.obs` (pass an `Observability` bundle):
 the shared registry carries the training-throughput story end to end —
 updates/sec, trained-samples/sec (train IPS, the Fig. 8 headline axis),
 p50/p99 request latency via the streaming histogram, batch occupancy, the
-phase-keyed dispatch histogram — plus the dispatch predicted-vs-measured
-audit and the per-site QAT range/saturation telemetry pulled straight off
-the live `QATState` between updates (`benchmarks/learner_bench` lands it
-all in `BENCH_learner.json`).  An enabled tracer gets per-update spans
-(dispatch → launch → block_until_ready).
+phase-keyed mode histogram — plus the per-site QAT range/saturation
+telemetry pulled straight off the live `QATState` between updates
+(`benchmarks/learner_bench` lands it all in `BENCH_learner.json`).  An
+enabled tracer gets per-update spans (dispatch → launch →
+block_until_ready).
 """
 
 from __future__ import annotations
@@ -35,7 +35,6 @@ import numpy as np
 from repro.obs import Observability
 from repro.rl import ddpg
 from repro.runtime.engine import BatcherConfig, StreamEngine
-from repro.serve.policy.dispatch import TRAIN_MODES, CostModel
 from repro.train.learner.batcher import (
     TRANSITION_KEYS,
     JoinedFuture,
@@ -45,11 +44,6 @@ from repro.train.learner.batcher import (
     merge_chunk_metrics,
 )
 
-# dispatch mode -> the ddpg backend that can actually train through it
-# (the per-layer chain has no autodiff rule, hence no "layer" entry);
-# fused_step is the 2-launch whole-update kernel (fwd+bwd+Adam+soft-update)
-TRAIN_BACKENDS = {"fused_step": "pallas_fused_step", "fused": "pallas", "jnp": "jnp"}
-
 # learner-shaped default buckets: update batches are replay-sized (tens to
 # hundreds of rows), never single observations
 DEFAULT_BUCKETS = (8, 32, 128)
@@ -58,17 +52,18 @@ UpdateFn = Callable[[Any, dict], tuple[Any, dict]]
 
 
 class LearnerEngine(StreamEngine):
-    """Streams batched updates through an adaptive train-phase dispatcher.
+    """Streams batched updates through one update function.
 
     Synchronous use: `run_update(batch)` — one (or, for oversized batches,
-    a chunked sequence of) padded, dispatched, sequentially applied
-    update(s).  Threaded use: `start()`, then `submit(batch).result()`
-    from any number of producer threads; `stop()` to drain and join.
+    a chunked sequence of) padded, sequentially applied update(s).
+    Threaded use: `start()`, then `submit(batch).result()` from any number
+    of producer threads; `stop()` to drain and join.
 
-    The engine is generic over the update family: `update_fns` maps each
-    dispatch mode to an `update_fn(state, batch) -> (new_state, metrics)`.
-    `from_ddpg` builds the DDPG family (fused custom-VJP / jnp autodiff);
-    `train/step.learner_update_fns` adapts the LM train step.
+    The engine is generic over the update family: `update_fns` is
+    `{mode: update_fn(state, batch) -> (new_state, metrics)}` with exactly
+    one entry, the mode naming it in the metrics.  `from_ddpg` builds the
+    DDPG update for the config's backend; `train/step.learner_update_fns`
+    adapts the LM train step.
 
     `pad_policy`:
       * "mask"  — pad short batches to the bucket with zero rows plus a
@@ -91,17 +86,18 @@ class LearnerEngine(StreamEngine):
         state,
         update_fns: dict[str, UpdateFn],
         *,
-        dims: Sequence[int],
-        cost_model: Optional[CostModel] = None,
         batcher: Optional[BatcherConfig] = None,
-        force_mode: Optional[str] = None,
         pad_policy: str = "mask",
         required_keys: Optional[Sequence[str]] = None,
         warmup_template: Optional[Callable[[int], dict]] = None,
         obs: Optional[Observability] = None,
     ):
+        if len(update_fns) != 1:
+            raise ValueError(
+                f"a learner trains through one update function; got modes {sorted(update_fns)}"
+            )
         self._state = state
-        self._update_fns = dict(update_fns)
+        ((mode, self._update_fn),) = update_fns.items()
         self.batcher_config = batcher or BatcherConfig(buckets=DEFAULT_BUCKETS)
         if pad_policy not in ("mask", "exact"):
             raise ValueError(f"pad_policy {pad_policy!r}; 'mask' | 'exact'")
@@ -123,10 +119,7 @@ class LearnerEngine(StreamEngine):
                 registry=obs.registry,
                 prefix="learner.batcher",
             ),
-            modes=tuple(self._update_fns),
-            dims=dims,
-            cost_model=cost_model or CostModel.default(),
-            force_mode=force_mode,
+            modes=(mode,),
             obs=obs,
         )
 
@@ -135,26 +128,14 @@ class LearnerEngine(StreamEngine):
         cls,
         state: "ddpg.DDPGState",
         cfg: "ddpg.DDPGConfig",
-        *,
-        modes: Sequence[str] = TRAIN_MODES,
         **kwargs,
     ) -> "LearnerEngine":
-        """The DDPG learner: one jitted `ddpg.update` per trainable
-        dispatch mode (executables per bucket come from the jit cache, so
-        a bucket-sized stream and a direct call share the SAME program —
-        that is what makes streamed results bit-identical)."""
-        unknown = [m for m in modes if m not in TRAIN_BACKENDS]
-        if unknown:
-            raise ValueError(
-                f"modes {unknown} cannot train; trainable "
-                f"dispatch modes: {sorted(TRAIN_BACKENDS)}"
-            )
-        import dataclasses
-
-        fns = {}
-        for m in modes:
-            mode_cfg = dataclasses.replace(cfg, backend=TRAIN_BACKENDS[m])
-            fns[m] = jax.jit(partial(ddpg.update, cfg=mode_cfg))
+        """The DDPG learner: one jitted `ddpg.update` through `cfg.backend`,
+        which also names the engine's mode (executables per bucket come
+        from the jit cache, so a bucket-sized stream and a direct call
+        share the SAME program — that is what makes streamed results
+        bit-identical)."""
+        fns = {cfg.backend: jax.jit(partial(ddpg.update, cfg=cfg))}
         n = len(ddpg.ACTOR_ACTS)
         dims = [int(state.actor["l0"]["w"].shape[0])] + [
             int(state.actor[f"l{i}"]["w"].shape[1]) for i in range(n)
@@ -171,7 +152,7 @@ class LearnerEngine(StreamEngine):
 
         kwargs.setdefault("required_keys", TRANSITION_KEYS)
         kwargs.setdefault("warmup_template", transitions)
-        return cls(state, fns, dims=dims, **kwargs)
+        return cls(state, fns, **kwargs)
 
     # ------------------------------------------------------------------ #
     # state
@@ -188,7 +169,7 @@ class LearnerEngine(StreamEngine):
             self._state = state
 
     # ------------------------------------------------------------------ #
-    # dispatch + device call
+    # device call
     # ------------------------------------------------------------------ #
 
     def _pad(self, batch: dict[str, np.ndarray], rows: int, bucket: int) -> dict[str, np.ndarray]:
@@ -213,10 +194,9 @@ class LearnerEngine(StreamEngine):
     def warmup(
         self,
         buckets: Optional[Sequence[int]] = None,
-        modes: Optional[Sequence[str]] = None,
         padded: bool = False,
     ) -> int:
-        """Lower + compile the (bucket, mode) update executables ahead of
+        """Lower + compile the per-bucket update executables ahead of
         traffic without advancing the training state.  `padded=True` also
         warms the masked variants (bucket-1 rows).  Returns the number of
         executables warmed.
@@ -234,16 +214,15 @@ class LearnerEngine(StreamEngine):
         n = 0
         for bucket in buckets or self.batcher_config.buckets:
             rows_list = [bucket] + ([bucket - 1] if padded and bucket > 1 else [])
-            for mode in modes or ([self.force_mode] if self.force_mode else self.modes):
-                for rows in rows_list:
-                    batch = self._pad(self.warmup_template(rows), rows, bucket)
-                    with self._ulock:
-                        jax.block_until_ready(self._update_fns[mode](self._state, batch))
-                    n += 1
+            for rows in rows_list:
+                batch = self._pad(self.warmup_template(rows), rows, bucket)
+                with self._ulock:
+                    jax.block_until_ready(self._update_fn(self._state, batch))
+                n += 1
         return n
 
     def _apply(self, batch: dict[str, np.ndarray], rows: int) -> dict[str, float]:
-        """One micro-batch through the dispatcher and onto the state."""
+        """One micro-batch through the update function and onto the state."""
         tracer = self.obs.tracer
         bucket = self.batcher_config.bucket_for(rows)
         with tracer.span("learner.dispatch", bucket=bucket, rows=rows) as sp:
@@ -253,7 +232,7 @@ class LearnerEngine(StreamEngine):
         with self._ulock:
             t0 = time.perf_counter()
             with tracer.span("learner.launch", bucket=bucket, mode=mode):
-                new_state, metrics = self._update_fns[mode](self._state, padded)
+                new_state, metrics = self._update_fn(self._state, padded)
             with tracer.span("learner.block_until_ready", bucket=bucket, mode=mode):
                 jax.block_until_ready((new_state, metrics))
             device_s = time.perf_counter() - t0
@@ -273,7 +252,7 @@ class LearnerEngine(StreamEngine):
 
     def run_update(self, batch) -> dict[str, float]:
         """Synchronously stream one update request: chunk to the top
-        bucket if oversized, pad, dispatch, apply sequentially.  Returns
+        bucket if oversized, pad, apply sequentially.  Returns
         the update metrics (row-weighted means across chunks)."""
         arrs, rows = as_transition_batch(batch, self.required_keys)
         if rows <= self.batcher_config.max_batch:
@@ -339,8 +318,8 @@ class LearnerEngine(StreamEngine):
     def stats(self) -> dict:
         """Training-throughput metrics so far, read off the shared
         registry: exact lifetime totals, streaming-histogram latency
-        quantiles, the phase-keyed dispatch histogram, and the two audit
-        sections."""
+        quantiles, the phase-keyed mode histogram, and the QAT telemetry
+        section."""
         m = self._metrics
         device_s = m.device_s
         wall = m.wall_s()
@@ -356,10 +335,8 @@ class LearnerEngine(StreamEngine):
             "p99_ms": m.latency_ms(0.99),
             "batch_occupancy": m.occupancy(),
             "mode_histogram": m.mode_histogram(),
-            "cost_model": self.cost_model.source,
-            "dispatch_audit": self._audit.snapshot(),
             "qat_telemetry": self._qat.stats(),
         }
 
 
-__all__ = ["LearnerEngine", "TRAIN_BACKENDS", "DEFAULT_BUCKETS"]
+__all__ = ["LearnerEngine", "DEFAULT_BUCKETS"]

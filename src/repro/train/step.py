@@ -122,8 +122,8 @@ def learner_update_fns(cfg: ModelConfig, opt_cfg: adam.AdamConfig,
     contract: {mode: update_fn(state, batch) -> (state, metrics)}.
 
     The LM step has one trainable path (XLA autodiff), so the family is the
-    single "jnp" mode — dispatch degenerates to a pass-through, but the
-    engine's queueing/coalescing/metrics machinery applies unchanged.  LM
+    single "jnp" mode; the engine's queueing/coalescing/metrics machinery
+    applies unchanged.  LM
     batches carry no per-row loss mask, so pair this with
     `LearnerEngine(pad_policy="exact")` and buckets matching the batch
     shapes (`kwargs` forward to `make_train_step`).

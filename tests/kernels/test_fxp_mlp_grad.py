@@ -226,22 +226,6 @@ def test_update_gradient_parity_vs_jnp_backend(qat_enabled):
                            rtol=1e-4, atol=2e-5, err_msg=attr)
 
 
-def test_update_pallas_layer_still_guarded():
-    """The per-layer chain has no autodiff rule — update() must refuse."""
-    env = make("swimmer")
-    cfg = ddpg.DDPGConfig(batch_size=8, backend="pallas_layer")
-    st = ddpg.init(jax.random.key(0), env.spec, cfg)
-    batch = {
-        "obs": jnp.zeros((8, env.spec.obs_dim)),
-        "action": jnp.zeros((8, env.spec.act_dim)),
-        "reward": jnp.zeros((8,)),
-        "next_obs": jnp.zeros((8, env.spec.obs_dim)),
-        "done": jnp.zeros((8,), jnp.bool_),
-    }
-    with pytest.raises(ValueError, match="pallas_layer"):
-        ddpg.update(st, batch, cfg)
-
-
 def test_training_smoke_50_steps_matches_jnp_trajectory():
     """50 update() steps crossing the QAT delay: the fused-kernel training
     path must track the jnp backend's loss/Q trajectory within fixed-point

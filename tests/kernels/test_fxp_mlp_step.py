@@ -1,4 +1,4 @@
-"""Fused 2-launch training step (`fxp_mlp_train_step`) parity pins.
+"""Fused 2-launch training step (`backend="pallas_fused_step"`) parity pins.
 
 Acceptance contract for the whole-update kernel:
   * tracks the 8-launch `backend="pallas"` path tightly in the monitor
@@ -14,9 +14,9 @@ Acceptance contract for the whole-update kernel:
   * in-kernel Adam (the epilogue's `leaf_update`) bit-matches host Adam
     over 50 steps;
   * the agent carried in the kernels' padded layout from update to update
-    (`fxp_mlp_train_step_padded`, the training window's scan) gives the
-    bits of padding and unpadding it around every update, and its padded
-    regions stay exactly 0.
+    (`ddpg.to_carry`, the training window's scan) gives the bits of
+    padding and unpadding it around every update, and its padded regions
+    stay exactly 0.
 """
 import dataclasses
 
@@ -25,11 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import fixedpoint as fxp
-from repro.kernels.fxp_mlp.ops import (fxp_mlp_train_step,
-                                       fxp_mlp_train_step_padded, pad_wb,
-                                       unpad_wb)
-from repro.optim import adam, fxp_adam
+from repro.optim import adam
 from repro.rl import ddpg, envs, loop
 from repro.rl.envs.base import EnvSpec
 
@@ -260,14 +256,6 @@ def test_in_kernel_adam_bitmatches_host_50_steps():
                                   np.asarray(v_kern))
 
 
-def test_fused_step_backend_guard_message():
-    """The train-backend guard names all three trainable backends."""
-    cfg = ddpg.DDPGConfig(backend="pallas_layer")
-    state = ddpg.init(jax.random.key(0), SPEC, cfg)
-    with pytest.raises(ValueError, match="pallas_fused_step"):
-        ddpg.update(state, _batch(0, 8), cfg)
-
-
 # --------------------------------------------------------------------- #
 # the agent carried in the kernels' padded layout
 # --------------------------------------------------------------------- #
@@ -280,85 +268,94 @@ def _assert_same_bits(a, b, what):
     np.testing.assert_array_equal(_bits(a), _bits(b), err_msg=what)
 
 
-def _nets(state):
-    """The eight parameter sets in `fxp_mlp_train_step`'s order, each
-    ((w per layer), (b per layer))."""
-    n = len(ddpg.ACTOR_ACTS)
-    wb = lambda p: (tuple(p[f"l{i}"]["w"] for i in range(n)),
-                    tuple(p[f"l{i}"]["b"] for i in range(n)))
-    return [wb(p) for p in (state.actor, state.critic, state.actor_target,
-                            state.critic_target, state.actor_opt.mu,
-                            state.actor_opt.nu, state.critic_opt.mu,
-                            state.critic_opt.nu)]
+def _assert_padding_is_zero(padded, what):
+    """Every row and column of every carried leaf outside its net's
+    unpadded widths is exactly +0."""
+    for net, dims in (("actor", padded.actor_dims),
+                      ("critic", padded.critic_dims)):
+        sets = ("", "_target", "_opt.mu", "_opt.nu")
+        for name in sets:
+            tree = padded.agent
+            for attr in (net + name).split("."):
+                tree = getattr(tree, attr)
+            for i in range(len(dims) - 1):
+                for key, (rows, cols) in (("w", (dims[i], dims[i + 1])),
+                                          ("b", (1, dims[i + 1]))):
+                    pad = np.asarray(tree[f"l{i}"][key]).copy()
+                    pad[:rows, :cols] = 0.0
+                    assert not _bits(pad).any(), f"{what} {net}{name} {key}{i}"
+
+
+@pytest.mark.parametrize("backend", ["pallas_fused_step", "pallas", "jnp"])
+def test_to_carry_round_trips_bit_for_bit(backend):
+    """`ddpg.from_carry` undoes `ddpg.to_carry` exactly: the fused step's
+    padded form comes back as the same bits with zeros in every padded row
+    and column, and every other backend's agent passes through as is."""
+    cfg = ddpg.DDPGConfig(backend=backend)
+    state = ddpg.init(jax.random.key(2), SPEC, cfg)
+    carried = ddpg.to_carry(state, cfg)
+    if backend != "pallas_fused_step":
+        assert carried is state and ddpg.from_carry(carried) is state
+        return
+    _assert_padding_is_zero(carried, "to_carry")
+    back = ddpg.from_carry(carried)
+    assert jax.tree.structure(back) == jax.tree.structure(state)
+    for i, (x, y) in enumerate(zip(jax.tree.leaves(back), jax.tree.leaves(state))):
+        assert x.shape == y.shape, i
+        _assert_same_bits(x, y, f"leaf {i}")
 
 
 @pytest.mark.parametrize("quantized", [False, True],
                          ids=["monitor", "quantized"])
 def test_padded_carry_matches_the_unpadded_wrapper_bit_for_bit(quantized):
-    """4 updates at B = 128 through `fxp_mlp_train_step_padded` with all
-    eight parameter sets carried padded from update to update, against the
-    same updates through `fxp_mlp_train_step`, which pads and unpads them
-    every call: every unpadded leaf (params, targets, both moments), the
-    loss partials and the site extrema are the same bits, and every padded
-    row and column of every carried leaf is exactly +0."""
-    state = ddpg.init(jax.random.key(1), SPEC, ddpg.DDPGConfig())
-    a = (SPEC.obs_dim, *ddpg.HIDDEN, SPEC.act_dim)
-    c = (SPEC.obs_dim + SPEC.act_dim, *ddpg.HIDDEN, 1)
-    dims = [a, c, a, c, a, a, c, c]
-    # per-site affine params of ranges no site input stays inside, so the
-    # quantized phase clips as well as rounds
-    deltas, zs = fxp.affine_params(jnp.full((6,), -1.5), jnp.full((6,), 2.5),
-                                   16)
-    kw = dict(actor_acts=ddpg.ACTOR_ACTS, critic_acts=ddpg.CRITIC_ACTS,
-              gamma=0.99, tau=0.005)
-    unpadded = _nets(state)
-    carried = [pad_wb(*wb) for wb in unpadded]
+    """4 updates at B = 128 through `ddpg.update` on a `PaddedDDPGState`
+    carried from update to update (`ddpg.to_carry` once), against the same
+    updates on an unpadded `DDPGState`, which pads and unpads all eight
+    parameter sets every call: every unpadded leaf (params, targets, both
+    moments), the QAT ranges and the losses are the same bits, and every
+    padded row and column of every carried leaf is exactly +0.  In the
+    quantized case the QAT delay flips after the first update."""
+    cfg = ddpg.DDPGConfig(backend="pallas_fused_step",
+                          qat_delay=1 if quantized else 100)
+    ref = start = ddpg.init(jax.random.key(1), SPEC, cfg)
+    carried = ddpg.to_carry(ref, cfg)
+    assert isinstance(carried, ddpg.PaddedDDPGState)
     for t in range(4):
         b = _batch(200 + t, 128)
-        consts = adam.step_constants(fxp_adam.FxpAdamConfig(lr=1e-3),
-                                     jnp.asarray(t + 1, jnp.int32))
-        args = (b["obs"], b["action"], b["reward"], b["done"],
-                b["next_obs"], jnp.ones((128,), jnp.float32))
-        tail = (deltas, zs, consts, consts, jnp.array(quantized))
-        # the wrapper's body outside its own jit: its pads and slices run op
-        # by op around the very program the carried path runs, so XLA:CPU
-        # cannot fuse the interpreted kernel's arithmetic differently in
-        # the two (which moves moments by an ulp or so)
-        ref = fxp_mlp_train_step.__wrapped__(
-            *args, *unpadded, *tail, obs_dim=SPEC.obs_dim,
-            act_dim=SPEC.act_dim, **kw)
-        out = fxp_mlp_train_step_padded(*args, *carried, *tail,
-                                        actor_dims=a, critic_dims=c, **kw)
-        unpadded, carried = list(ref[:8]), list(out[:8])
-        for k, (wbp, d, (ws, bs)) in enumerate(zip(carried, dims, unpadded)):
-            got_ws, got_bs = unpad_wb(wbp, d)
-            for i in range(len(ws)):
-                _assert_same_bits(got_ws[i], ws[i], f"step {t} set {k} w{i}")
-                _assert_same_bits(got_bs[i], bs[i], f"step {t} set {k} b{i}")
-            for i, leaf in enumerate(wbp):
-                rows, cols = ((d[i // 2], d[i // 2 + 1]) if i % 2 == 0
-                              else (1, d[i // 2 + 1]))
-                pad = np.asarray(leaf).copy()
-                pad[:rows, :cols] = 0.0
-                assert not _bits(pad).any(), f"step {t} set {k} leaf {i}"
-        for name in ("closs_sum", "y_sum", "q_sum", "c_mins", "c_maxs",
-                     "a_mins", "a_maxs"):
-            _assert_same_bits(getattr(out, name), getattr(ref, name),
-                              f"step {t} {name}")
-    # the updates moved every set, so the carry was exercised
-    assert float(jnp.max(jnp.abs(unpadded[4][0][1]))) > 0.0
+        # both sides run eagerly around the same jitted two-launch step, so
+        # XLA:CPU cannot fuse the interpreted kernel's arithmetic
+        # differently in the two (which moves moments by an ulp or so)
+        ref, ref_m = ddpg.update(ref, b, cfg)
+        carried, got_m = ddpg.update(carried, b, cfg)
+        assert isinstance(carried, ddpg.PaddedDDPGState)
+        got = ddpg.from_carry(carried)
+        for i, (x, y) in enumerate(zip(jax.tree.leaves(got),
+                                       jax.tree.leaves(ref))):
+            _assert_same_bits(x, y, f"step {t} leaf {i}")
+        for name in ref_m:
+            _assert_same_bits(got_m[name], ref_m[name], f"step {t} {name}")
+        _assert_padding_is_zero(carried, f"step {t}")
+    assert bool(ref.qat.quantized_phase) == quantized
+    # the updates moved every one of the eight sets, so the carry was exercised
+    def sets(s):
+        return (s.actor, s.critic, s.actor_target, s.critic_target,
+                s.actor_opt.mu, s.actor_opt.nu, s.critic_opt.mu, s.critic_opt.nu)
+
+    for k, (a, b) in enumerate(zip(sets(ref), sets(start))):
+        assert any(bool(jnp.any(x != y)) for x, y in
+                   zip(jax.tree.leaves(a), jax.tree.leaves(b))), f"set {k}"
 
 
-def test_train_window_carries_the_agent_padded_bit_for_bit():
-    """The scanned window with the fused update carries the agent padded
-    (one pad at entry, one unpad at exit); the same window with the agent
-    carried unpadded, each update padding and unpadding it, returns the
-    same `TrainState` bit for bit (agent, replay rows, env, noise, key)
-    and the same window metrics, across the QAT delay into the quantized
-    phase."""
+@pytest.mark.parametrize("backend", ["pallas_fused_step", "jnp"])
+def test_train_window_carries_the_agent_padded_bit_for_bit(backend):
+    """The scanned window carries the agent in `ddpg.to_carry`'s form: for
+    the fused update padded (one pad at entry, one unpad at exit), for
+    `jnp` as it is.  The same window with the agent carried unpadded, each
+    fused update padding and unpadding it, returns the same `TrainState`
+    bit for bit (agent, replay rows, env, noise, key) and the same window
+    metrics, across the QAT delay into the quantized phase."""
     env = envs.make("halfcheetah")
-    dcfg = ddpg.DDPGConfig(batch_size=32, backend="pallas_fused_step",
-                           qat_delay=5)
+    dcfg = ddpg.DDPGConfig(batch_size=32, backend=backend, qat_delay=5)
     cfg = loop.TrainConfig(total_steps=14, warmup_steps=4,
                            replay_capacity=64, eval_every=14, seed=3)
     ts = loop.init_train_state(env, cfg, dcfg)

@@ -21,8 +21,9 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro.kernels.fxp_matmul.ops import fxp_dense
+from repro.kernels._compat import round_up
 from repro.kernels.fxp_mlp.ops import (fxp_mlp_forward, fxp_mlp_train,
-                                       fxp_mlp_train_step)
+                                       fxp_mlp_train_step_padded)
 from repro.kernels.quantize.ops import monitor_quant
 from repro.optim import adam
 from repro.rl import ddpg, replay
@@ -67,6 +68,13 @@ def _net(sharding, dims):
     return ws, bs
 
 
+def _padded_net(sharding, dims):
+    """One net's interleaved [w0, b0, w1, b1, ...] in the kernels' layout."""
+    p = [round_up(d, 128) for d in dims]
+    return [_sds(sharding, shape) for k, n in zip(p[:-1], p[1:])
+            for shape in ((k, n), (1, n))]
+
+
 def _kernels(compiled) -> int:
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
@@ -85,11 +93,12 @@ def test_fused_forward_compiles(one_chip, batch):
 @pytest.mark.parametrize("batch", [128, 512])
 def test_fused_train_step_compiles(one_chip, batch):
     s = functools.partial(_sds, one_chip)
-    actor, critic = _net(one_chip, ACTOR), _net(one_chip, CRITIC)
+    actor, critic = _padded_net(one_chip, ACTOR), _padded_net(one_chip, CRITIC)
     consts = adam.StepConstants(*(s(()) for _ in adam.StepConstants._fields))
     fn = jax.jit(functools.partial(
-        fxp_mlp_train_step, actor_acts=ddpg.ACTOR_ACTS, critic_acts=ddpg.CRITIC_ACTS,
-        obs_dim=OBS, act_dim=ACT, gamma=0.99, tau=0.005, interpret=False))
+        fxp_mlp_train_step_padded, actor_acts=ddpg.ACTOR_ACTS,
+        critic_acts=ddpg.CRITIC_ACTS, actor_dims=ACTOR, critic_dims=CRITIC,
+        gamma=0.99, tau=0.005, interpret=False))
     compiled = fn.lower(
         s((batch, OBS)), s((batch, ACT)), s((batch,)), s((batch,)), s((batch, OBS)),
         s((batch,)), actor, critic, actor, critic, actor, actor, critic, critic,

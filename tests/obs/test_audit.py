@@ -8,15 +8,15 @@ from repro.obs.audit import DispatchAudit
 
 
 class StubCostModel:
-    """Cost model with a fixed prediction per (phase, mode) in µs."""
+    """Cost model with a fixed prediction per mode in µs."""
 
     source = "stub"
 
     def __init__(self, predictions):
         self.predictions = dict(predictions)
 
-    def estimate_us(self, mode, batch, dims, phase="act"):
-        return self.predictions[(phase, mode)]
+    def estimate_us(self, mode, batch, dims):
+        return self.predictions[mode]
 
 
 DIMS = [17, 400, 300, 6]
@@ -32,7 +32,7 @@ def test_empty_audit_reports_no_drift():
 
 
 def test_calibrated_model_not_flagged():
-    cm = StubCostModel({("act", "fused"): 100.0, ("act", "layer"): 50.0})
+    cm = StubCostModel({"fused": 100.0, "layer": 50.0})
     audit = DispatchAudit(cm, DIMS)
     for _ in range(10):
         audit.record("act", "fused", 128, 100e-6)   # measured == predicted
@@ -52,7 +52,7 @@ def test_calibrated_model_not_flagged():
 def test_stale_cost_model_flags_drift():
     """The satellite's drift-flag unit test: a model whose predictions are
     5x off on every batch must cross the default threshold (3.0)."""
-    cm = StubCostModel({("train", "fused"): 10.0})
+    cm = StubCostModel({"fused": 10.0})
     audit = DispatchAudit(cm, DIMS)
     for _ in range(5):
         audit.record("train", "fused", 32, 50e-6)   # 5x the prediction
@@ -67,7 +67,7 @@ def test_stale_cost_model_flags_drift():
 
 
 def test_threshold_configurable():
-    cm = StubCostModel({("act", "jnp"): 10.0})
+    cm = StubCostModel({"jnp": 10.0})
     audit = DispatchAudit(cm, DIMS, threshold=10.0)
     audit.record("act", "jnp", 1, 50e-6)            # 5x off
     d = audit.drift()
@@ -77,7 +77,7 @@ def test_threshold_configurable():
 def test_cell_mean_weighting_not_dominated_by_noise():
     """Per-cell mean first: one cell with symmetric noise around a perfect
     prediction must not read as drift."""
-    cm = StubCostModel({("act", "fused"): 100.0})
+    cm = StubCostModel({"fused": 100.0})
     audit = DispatchAudit(cm, DIMS)
     for _ in range(50):
         audit.record("act", "fused", 128, 200e-6)   # 2x over
@@ -90,7 +90,7 @@ def test_cell_mean_weighting_not_dominated_by_noise():
 
 def test_snapshot_is_json_shaped_and_reset_clears():
     import json
-    cm = StubCostModel({("act", "fused"): 100.0, ("train", "jnp"): 20.0})
+    cm = StubCostModel({"fused": 100.0, "jnp": 20.0})
     audit = DispatchAudit(cm, DIMS)
     audit.record("act", "fused", 128, 120e-6)
     audit.record("train", "jnp", 8, 20e-6)
@@ -103,7 +103,7 @@ def test_snapshot_is_json_shaped_and_reset_clears():
 
 
 def test_audit_thread_safe_counts():
-    cm = StubCostModel({("act", "fused"): 100.0})
+    cm = StubCostModel({"fused": 100.0})
     audit = DispatchAudit(cm, DIMS)
     n, per = 8, 500
 

@@ -50,6 +50,7 @@ SERVE_KEYS_PRE_OBS = {
     "batch_occupancy": (float, type(None)), "mode_histogram": dict,
     "cost_model": str,
 }
+# the learner trains through one backend: it has no cost model to report
 LEARNER_KEYS_PRE_OBS = {
     "requests": int, "updates": int, "transitions": int,
     "updates_per_s_device": (float, type(None)),
@@ -58,7 +59,6 @@ LEARNER_KEYS_PRE_OBS = {
     "train_ips_wall": (float, type(None)),
     "p50_ms": (float, type(None)), "p99_ms": (float, type(None)),
     "batch_occupancy": (float, type(None)), "mode_histogram": dict,
-    "cost_model": str,
 }
 
 
@@ -84,7 +84,7 @@ def test_serve_stats_keys_compatible_with_obs_disabled():
 def test_learner_stats_keys_compatible_with_obs_disabled():
     state, cfg = _state()
     eng = LearnerEngine.from_ddpg(
-        state, cfg, force_mode="jnp",
+        state, cfg,
         batcher=BatcherConfig(buckets=(4, 8)))
     assert eng.obs.tracer.enabled is False
     eng.run_update(_batch(np.random.default_rng(0), 4))
@@ -167,7 +167,7 @@ def test_learner_stats_hammered_during_threaded_submits():
     state, cfg = _state()
     obsb = Observability()
     eng = LearnerEngine.from_ddpg(
-        state, cfg, force_mode="jnp",
+        state, cfg,
         batcher=BatcherConfig(buckets=(4, 8, 16), max_wait_ms=0.5),
         obs=obsb)
     eng.warmup(padded=True)
@@ -207,7 +207,7 @@ def test_learner_stats_hammered_during_threaded_submits():
     assert not errors, errors
     st = eng.stats()
     assert st["requests"] == 12
-    assert st["dispatch_audit"]["batches"] == st["updates"] > 0
+    assert sum(st["mode_histogram"]["train"].values()) == st["updates"] > 0
 
 
 # --------------------------------------------------------------------- #
@@ -252,7 +252,7 @@ def test_learner_trace_and_qat_sections(tmp_path):
     state, cfg = _state()
     obsb = Observability.tracing(qat_probe_every=1)
     eng = LearnerEngine.from_ddpg(
-        state, cfg, force_mode="jnp",
+        state, cfg,
         batcher=BatcherConfig(buckets=(4, 8)), obs=obsb)
     rng = np.random.default_rng(0)
     for _ in range(2):
@@ -266,9 +266,7 @@ def test_learner_trace_and_qat_sections(tmp_path):
     for entry in probed:
         assert 0.0 <= entry["saturation"] <= 1.0
         assert entry["act_min"] <= entry["act_max"]
-    audit = st["dispatch_audit"]
-    assert audit["batches"] == 2
-    assert audit["table"]["train"]["jnp"]
+    assert st["mode_histogram"] == {"train": {"jnp": 2}}
     path = tmp_path / "trace_learner.jsonl"
     obsb.tracer.write(path)
     evs = read_jsonl(path)
@@ -285,7 +283,7 @@ def test_shared_registry_across_engines_and_reset():
     serve = PolicyEngine.from_ddpg(state, force_mode="jnp",
                                    batcher=BatcherConfig(buckets=(1, 8)),
                                    obs=obsb)
-    learner = LearnerEngine.from_ddpg(state, cfg, force_mode="jnp",
+    learner = LearnerEngine.from_ddpg(state, cfg,
                                       batcher=BatcherConfig(buckets=(4, 8)),
                                       obs=obsb)
     serve.run_batch(np.zeros((3, SPEC.obs_dim), np.float32))
@@ -326,7 +324,7 @@ def test_learner_engine_close_flushes_trace(tmp_path):
     path = tmp_path / "learner.jsonl"
     obsb = Observability.tracing(trace_path=str(path))
     eng = LearnerEngine.from_ddpg(
-        state, cfg, force_mode="jnp",
+        state, cfg,
         batcher=BatcherConfig(buckets=(4, 8), max_wait_ms=0.5), obs=obsb)
     rng = np.random.default_rng(0)
     with eng:

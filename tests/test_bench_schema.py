@@ -94,19 +94,16 @@ def test_serve_policy_drift_fails():
 
 
 def test_learner_drift_fails():
-    """The learner artifact's contract: per-mode training throughput, BOTH
-    per-phase dispatch tables, and the phase-keyed mode histogram."""
+    """The learner artifact's contract: per-backend training throughput and
+    the phase-keyed mode histogram."""
     good = _load("BENCH_learner.json")
     bench_schema.validate_report(good)
     for mutate in (
         lambda d: d.pop("modes"),
         lambda d: d["modes"].pop("fused"),
         lambda d: d["modes"]["jnp"].pop("train_ips"),
-        lambda d: d["dispatch"].pop("train"),           # phase axis dropped
-        lambda d: d["dispatch"].pop("act"),
         lambda d: d["adaptive"].pop("train_ips_wall"),
         lambda d: d["adaptive"]["mode_histogram"].pop("train"),
-        lambda d: d["adaptive"].pop("dispatch_audit"),  # v2 audit section
         lambda d: d["adaptive"].pop("qat_telemetry"),
         lambda d: d["config"].update(buckets=[8, 32]),  # < 3 buckets
     ):

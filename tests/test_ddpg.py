@@ -93,31 +93,33 @@ def test_pallas_backend_matches_jnp():
 
 
 def test_fused_backend_matches_perlayer_backend():
-    """Network-resident fused kernel (backend="pallas") == per-layer
-    AAP-core chain (backend="pallas_layer"): same actions, same QAT range
-    evolution."""
+    """Network-resident fused kernel (backend="pallas") == the per-layer
+    AAP-core chain (serving's `act_batch(mode="layer")`): same actions in
+    the quantized phase and with QAT off; and the fused kernel's range
+    monitors evolve as the jnp backend's QAT sites do."""
     from repro.core.qat import QATContext, QATState
 
     env = make("halfcheetah")
     st = ddpg.init(jax.random.key(0), env.spec, ddpg.DDPGConfig())
+    assert bool(st.qat.quantized_phase)
     obs = jax.random.normal(jax.random.key(1), (8, env.spec.obs_dim)) * 2
     a_fused = ddpg.act(st, obs, cfg=ddpg.DDPGConfig(backend="pallas"))
-    a_layer = ddpg.act(st, obs, cfg=ddpg.DDPGConfig(backend="pallas_layer"))
+    a_layer = ddpg.act_batch(st.actor, obs, ddpg.freeze_actor_quant(st),
+                             mode="layer")
     np.testing.assert_allclose(np.asarray(a_fused), np.asarray(a_layer),
                                rtol=1e-5, atol=1e-5)
 
-    # with QAT off neither backend may flip to the half-precision datapath
+    # with QAT off neither path may flip to the half-precision datapath
     cfg_off = ddpg.DDPGConfig(qat_enabled=False)
     st_off = ddpg.init(jax.random.key(0), env.spec, cfg_off)
     a_f = ddpg.act(st_off, obs, cfg=dataclasses.replace(cfg_off, backend="pallas"))
-    a_l = ddpg.act(st_off, obs,
-                   cfg=dataclasses.replace(cfg_off, backend="pallas_layer"))
+    a_l = ddpg.act_batch(st_off.actor, obs, None, mode="layer")
     np.testing.assert_allclose(np.asarray(a_f), np.asarray(a_l),
                                rtol=1e-6, atol=1e-6)
 
     qat = QATState.init(delay=100, sites=ddpg.ACTOR_SITES + ddpg.CRITIC_SITES)
     finals = {}
-    for backend in ("pallas", "pallas_layer"):
+    for backend in ("pallas", "jnp"):
         ctx = QATContext(qat)
         ddpg.actor_forward(st.actor, obs, ctx, backend=backend)
         finals[backend] = ctx.finalize().ranges
@@ -125,8 +127,17 @@ def test_fused_backend_matches_perlayer_backend():
         for attr in ("a_min", "a_max", "count"):
             np.testing.assert_allclose(
                 np.asarray(getattr(finals["pallas"][site], attr)),
-                np.asarray(getattr(finals["pallas_layer"][site], attr)),
+                np.asarray(getattr(finals["jnp"][site], attr)),
                 rtol=1e-6, err_msg=f"{site}.{attr}")
+
+
+@pytest.mark.parametrize("backend", ["pallas_layer", "fused_step", "fused"])
+def test_config_rejects_a_backend_without_an_update(backend):
+    """Only the three backends `update` trains through are accepted, and
+    the refusal names them: the retired per-layer backend and the learner
+    dispatcher's old mode names fail at construction."""
+    with pytest.raises(ValueError, match="'jnp', 'pallas' or 'pallas_fused_step'"):
+        ddpg.DDPGConfig(backend=backend)
 
 
 def test_act_qat_off_touches_no_qat_state(monkeypatch):
@@ -146,7 +157,7 @@ def test_act_qat_off_touches_no_qat_state(monkeypatch):
             super().__init__(state)
 
     monkeypatch.setattr(ddpg, "QATContext", SpyContext)
-    for backend in ("jnp", "pallas", "pallas_layer"):
+    for backend in ddpg.BACKENDS:
         a = ddpg.act(st, obs, cfg=dataclasses.replace(cfg, backend=backend))
         assert a.shape == (4, env.spec.act_dim)
     assert instantiated == [], "QAT state touched during no-QAT inference"
@@ -166,9 +177,9 @@ def test_learns_pendulum():
     dcfg = ddpg.DDPGConfig(qat_enabled=False, fxp_weights=False,
                            batch_size=64, actor_lr=3e-4, critic_lr=1e-3,
                            exploration_sigma=0.15, qat_delay=10 ** 9)
-    cfg = loop.LoopConfig(total_steps=12_000, warmup_steps=500,
-                          eval_every=4_000, replay_capacity=20_000,
-                          eval_episodes=4, seed=1)
-    _, hist = loop.train_fused(env, cfg, dcfg, chunk=2000)
+    cfg = loop.TrainConfig(total_steps=12_000, warmup_steps=500,
+                           eval_every=4_000, replay_capacity=20_000,
+                           eval_episodes=4, seed=1)
+    _, hist = loop.train_device(env, cfg, dcfg)
     assert hist["eval_reward"][-1] > hist["eval_reward"][0] + 150
     assert hist["eval_reward"][-1] > -900

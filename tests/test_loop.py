@@ -1,9 +1,8 @@
-"""rl/loop — retrace regression, history windowing, learner streaming.
+"""rl/loop — retrace regression, single-launch windows, learner streaming.
 
-Pins the PR-5 satellite fixes: `evaluate` must not re-trace its episode
-scan on every call (the jit is hoisted to module level with env/dcfg as
-static keys), `train_fused` history must describe the whole eval window
-(not just the boundary chunk), and `train_host` optionally streams its
+`evaluate` must not re-trace its episode scan on every call (the jit is
+hoisted to module level with env/dcfg as static keys), every window of
+`train_device` reuses one trace, and `train_host` optionally streams its
 updates through a `train/learner.LearnerEngine`.
 """
 import dataclasses
@@ -14,7 +13,6 @@ import numpy as np
 import pytest
 
 from repro.rl import ddpg, loop
-from repro.rl.envs.base import EnvSpec, EnvState
 from repro.rl.envs.locomotion import make
 from repro.serve.policy import BatcherConfig
 from repro.train.learner import LearnerEngine
@@ -56,72 +54,6 @@ def test_evaluate_matches_paper_protocol_shape():
 
 
 # --------------------------------------------------------------------- #
-# train_fused: history covers the whole eval window
-# --------------------------------------------------------------------- #
-
-@dataclasses.dataclass(frozen=True)
-class _CountingEnv:
-    """Deterministic stub: reward at step t is exactly t, never done —
-    makes the eval-window mean analytically checkable."""
-
-    spec: EnvSpec = EnvSpec("counting", obs_dim=3, act_dim=2,
-                            episode_length=10 ** 6)
-
-    def reset(self, key):
-        state = EnvState(q=jnp.zeros(1), qd=jnp.zeros(1),
-                         t=jnp.zeros((), jnp.int32), key=key)
-        return state, jnp.zeros(3, jnp.float32)
-
-    def step(self, s, action):
-        ns = EnvState(q=s.q, qd=s.qd, t=s.t + 1, key=s.key)
-        return (ns, jnp.zeros(3, jnp.float32),
-                s.t.astype(jnp.float32), jnp.zeros((), jnp.bool_))
-
-
-def test_train_fused_history_accumulates_across_eval_window(monkeypatch):
-    """eval_every = 2 chunks of 3 steps: rewards are t = 0..5, so the
-    window mean is 2.5 — the old code recorded only the boundary chunk's
-    mean (4.0)."""
-    monkeypatch.setattr(loop, "evaluate",
-                        lambda *a, **k: jnp.float32(0.0))
-    env = _CountingEnv()
-    cfg = loop.LoopConfig(total_steps=12, eval_every=6,
-                          warmup_steps=10 ** 6, replay_capacity=32,
-                          eval_episodes=1)
-    dcfg = ddpg.DDPGConfig(qat_enabled=False, batch_size=4)
-    _, history = loop.train_fused(env, cfg, dcfg, chunk=3)
-    assert history["step"] == [6, 12]
-    # window 1: chunks cover t=0..2 (mean 1.0) and t=3..5 (mean 4.0)
-    np.testing.assert_allclose(history["train_reward"][0], 2.5, rtol=1e-6)
-    # window 2: t=6..8 (mean 7.0) and t=9..11 (mean 10.0)
-    np.testing.assert_allclose(history["train_reward"][1], 8.5, rtol=1e-6)
-    # ips covers the window's steps over the window's wall time
-    assert all(v > 0 for v in history["ips"])
-
-
-# --------------------------------------------------------------------- #
-# train_host: optional learner streaming
-# --------------------------------------------------------------------- #
-
-def test_train_config_normalization_single_path():
-    """Every legacy surface lands on the same frozen TrainConfig."""
-    assert loop.LoopConfig is loop.TrainConfig          # deprecated alias
-    base = loop.TrainConfig(total_steps=7, chunk=3)
-    assert loop.as_train_config(base) is base           # pass-through
-    assert loop.as_train_config(None) == loop.TrainConfig()
-    assert loop.as_train_config({"total_steps": 7, "chunk": 3}) == base
-    # duck-typed config object (e.g. a user's own dataclass): field copy
-    duck = dataclasses.make_dataclass(
-        "Duck", [("total_steps", int, 7), ("chunk", int, 3)])()
-    assert loop.as_train_config(duck) == base
-    # per-call kwargs override only when not None (train_fused(chunk=...))
-    assert loop.as_train_config(base, chunk=5).chunk == 5
-    assert loop.as_train_config(base, chunk=None).chunk == 3
-    with pytest.raises(dataclasses.FrozenInstanceError):
-        base.chunk = 9
-
-
-# --------------------------------------------------------------------- #
 # train_device: single-launch windows + host/device parity
 # --------------------------------------------------------------------- #
 
@@ -131,13 +63,14 @@ _SMALL = dict(total_steps=24, warmup_steps=8, replay_capacity=64,
 
 def test_train_window_traces_once_across_windows_and_drivers():
     """The tentpole claim, pinned: an entire eval window is ONE jitted
-    launch, and every window — across `train_device` calls and the legacy
-    `train_fused` driver at the same shapes — reuses the single trace."""
+    launch, and every window — across `train_device` calls at the same
+    shapes, with or without an evaluation function — reuses the single
+    trace."""
     if not hasattr(loop._train_window, "_cache_size"):
         pytest.skip("jit cache introspection unavailable")
     env = make("pendulum")
     dcfg = ddpg.DDPGConfig(qat_enabled=False, batch_size=8)
-    cfg = loop.TrainConfig(n_envs=2, chunk=12, **_SMALL)
+    cfg = loop.TrainConfig(n_envs=2, **_SMALL)
     before = loop._train_window._cache_size()
     _, hist = loop.train_device(env, cfg, dcfg,
                                 eval_fn=lambda *a: jnp.float32(0.0))
@@ -145,8 +78,7 @@ def test_train_window_traces_once_across_windows_and_drivers():
     after = loop._train_window._cache_size()
     assert after == before + 1                    # ...through one trace
     loop.train_device(env, cfg, dcfg, eval_fn=lambda *a: jnp.float32(0.0))
-    loop.train_fused(env, cfg, dcfg, chunk=12,
-                     eval_fn=lambda *a: jnp.float32(0.0))
+    loop.train_device(env, cfg, dcfg)
     assert loop._train_window._cache_size() == after
 
 
@@ -199,13 +131,12 @@ def test_train_device_fleet_runs_and_reports():
 
 def test_train_host_streams_updates_through_learner():
     env = make("pendulum")
-    cfg = loop.LoopConfig(total_steps=6, warmup_steps=2,
-                          replay_capacity=32, eval_every=10 ** 6)
-    dcfg = ddpg.DDPGConfig(qat_enabled=False, batch_size=8)
+    cfg = loop.TrainConfig(total_steps=6, warmup_steps=2,
+                           replay_capacity=32, eval_every=10 ** 6)
+    dcfg = ddpg.DDPGConfig(qat_enabled=False, batch_size=8, backend="jnp")
     seed_state = ddpg.init(jax.random.key(0), env.spec, dcfg)
     learner = LearnerEngine.from_ddpg(
-        seed_state, dcfg, force_mode="jnp",
-        batcher=BatcherConfig(buckets=(8, 16)))
+        seed_state, dcfg, batcher=BatcherConfig(buckets=(8, 16)))
     ts, info = loop.train_host(env, cfg, dcfg, learner=learner)
     # every post-warmup step streamed one update through the engine
     st = learner.stats()

@@ -21,9 +21,10 @@ def test_fixar_timestep_sequence():
     critic update -> actor update, fused; state stays finite."""
     env = make("hopper")
     dcfg = ddpg.DDPGConfig(batch_size=32, qat_delay=5)
-    cfg = loop.LoopConfig(total_steps=40, warmup_steps=8,
-                          replay_capacity=512, eval_every=10 ** 9)
-    ts, _ = loop.train_fused(env, cfg, dcfg, chunk=40)
+    cfg = loop.TrainConfig(total_steps=40, warmup_steps=8,
+                           replay_capacity=512, eval_every=10 ** 9)
+    ts, _ = loop.train_device(env, cfg, dcfg,
+                              eval_fn=lambda *a: jnp.float32(0.0))
     assert int(ts.agent.step) > 0
     assert all(bool(jnp.all(jnp.isfinite(x)))
                for x in jax.tree.leaves(ts.agent.actor))
@@ -33,8 +34,8 @@ def test_host_mode_produces_breakdown():
     """Fig.-9 style env/runtime/accelerator time split."""
     env = make("pendulum")
     dcfg = ddpg.DDPGConfig(batch_size=16)
-    cfg = loop.LoopConfig(total_steps=30, warmup_steps=10,
-                          replay_capacity=256, eval_every=10 ** 9)
+    cfg = loop.TrainConfig(total_steps=30, warmup_steps=10,
+                           replay_capacity=256, eval_every=10 ** 9)
     _, report = loop.train_host(env, cfg, dcfg)
     t = report["times"]
     assert set(t) == {"env", "runtime", "accelerator"}

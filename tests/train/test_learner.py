@@ -1,13 +1,11 @@
 """train/learner — parity pins and engine behavior.
 
 The acceptance contract: learner-engine results bit-match direct
-`ddpg.update` per backend and bucket size (≥3 buckets through the fused
-custom-VJP backend), the phase-plumbed dispatcher picks the expected mode
-per (phase, B) under default costs, and `CostModel.from_bench`'s
-train-phase fit round-trips from a synthetic bench JSON.
+`ddpg.update` per training backend and bucket size, and the engine trains
+through exactly the one backend its `DDPGConfig` names — no cost model, no
+dispatch audit.
 """
 import dataclasses
-import json
 import threading
 
 import jax
@@ -16,13 +14,10 @@ import pytest
 
 from repro.rl import ddpg
 from repro.rl.envs.locomotion import make
-from repro.serve.policy import BatcherConfig, CostModel
-from repro.serve.policy.dispatch import (DEFAULT_COSTS, MODES, TRAIN_MODES,
-                                         cost_hint)
-from repro.train.learner import TRAIN_BACKENDS, LearnerEngine, UpdateBatcher
+from repro.serve.policy import BatcherConfig
+from repro.train.learner import LearnerEngine, UpdateBatcher
 
 BUCKETS = (8, 16, 32)
-ACTOR_DIMS = [17, 400, 300, 6]  # halfcheetah actor
 
 _STATE = {}
 
@@ -58,28 +53,44 @@ def _assert_trees_equal(got, want, msg=""):
 # parity: streamed update ≡ direct ddpg.update (the acceptance pin)
 # --------------------------------------------------------------------- #
 
-@pytest.mark.parametrize("mode", list(TRAIN_MODES))
+@pytest.mark.parametrize("backend", list(ddpg.BACKENDS))
 @pytest.mark.parametrize("bucket", list(BUCKETS))
-def test_streamed_update_bitmatches_direct(mode, bucket):
+def test_streamed_update_bitmatches_direct(backend, bucket):
     """A bucket-sized request streams through the SAME jitted executable a
     direct call uses — params, targets, and metrics are bit-identical.
-    Covers ≥3 bucket sizes through backend='pallas' (mode='fused')."""
+    Covers 3 bucket sizes through each of the three training backends."""
     state, cfg = _state()
-    eng = LearnerEngine.from_ddpg(state, cfg, force_mode=mode,
+    bcfg = dataclasses.replace(cfg, backend=backend)
+    eng = LearnerEngine.from_ddpg(state, bcfg,
                                   batcher=BatcherConfig(buckets=BUCKETS))
     batch = _batch(bucket, key=bucket)
     got_metrics = eng.run_update(batch)
-    bcfg = dataclasses.replace(cfg, backend=TRAIN_BACKENDS[mode])
     want, want_metrics = jax.jit(
         lambda s, b: ddpg.update(s, b, bcfg))(state, batch)
     _assert_trees_equal(
         (eng.state.actor, eng.state.critic, eng.state.actor_target),
         (want.actor, want.critic, want.actor_target),
-        msg=f"{mode}/b{bucket}")
+        msg=f"{backend}/b{bucket}")
     for k, v in want_metrics.items():
-        assert got_metrics[k] == float(v), f"{mode}/b{bucket}/{k}"
-    assert got_metrics["mode"] == mode
+        assert got_metrics[k] == float(v), f"{backend}/b{bucket}/{k}"
+    assert got_metrics["mode"] == backend
     assert int(eng.state.step) == int(state.step) + 1
+
+
+@pytest.mark.parametrize("backend", ["pallas_fused_step", "pallas", "jnp"])
+def test_from_ddpg_trains_through_the_configured_backend(backend):
+    """`from_ddpg` builds one update, for `cfg.backend`: the engine has
+    that one mode at every bucket, reports no cost model and no dispatch
+    audit, and its mode histogram names only that backend."""
+    state, cfg = _state()
+    eng = LearnerEngine.from_ddpg(state, dataclasses.replace(cfg, backend=backend),
+                                  batcher=BatcherConfig(buckets=BUCKETS))
+    assert eng.modes == (backend,)
+    assert [eng.choose_mode(b) for b in BUCKETS] == [backend] * len(BUCKETS)
+    assert eng.run_update(_batch(5, key=5))["mode"] == backend
+    st = eng.stats()
+    assert "cost_model" not in st and "dispatch_audit" not in st
+    assert st["mode_histogram"] == {"train": {backend: 1}}
 
 
 def test_padded_update_bitmatches_direct_masked_call():
@@ -88,7 +99,7 @@ def test_padded_update_bitmatches_direct_masked_call():
     batch, and numerically matches the unpadded direct update (pad rows
     carry zero loss weight)."""
     state, cfg = _state()
-    eng = LearnerEngine.from_ddpg(state, cfg, force_mode="jnp",
+    eng = LearnerEngine.from_ddpg(state, cfg,
                                   batcher=BatcherConfig(buckets=BUCKETS))
     batch = _batch(5, key=3)
     eng.run_update(batch)
@@ -111,7 +122,7 @@ def test_oversized_request_chunks_sequentially():
     top-bucket updates applied in order — same final state as manually
     feeding the chunks."""
     state, cfg = _state()
-    eng = LearnerEngine.from_ddpg(state, cfg, force_mode="jnp",
+    eng = LearnerEngine.from_ddpg(state, cfg,
                                   batcher=BatcherConfig(buckets=BUCKETS))
     traj = _batch(70, key=7)
     metrics = eng.run_update(traj)
@@ -145,114 +156,6 @@ def test_update_mask_all_ones_matches_no_mask():
 
 
 # --------------------------------------------------------------------- #
-# phase-plumbed dispatcher
-# --------------------------------------------------------------------- #
-
-def test_dispatcher_expected_mode_per_phase_and_batch():
-    """The fixed bug, pinned: act and train phases produce DIFFERENT
-    dispatch tables under the default costs.  Act keeps the serving
-    crossover (layer at B=1, fused at B=512); train amortizes the fused
-    fwd+bwd pair's double launch — jnp autodiff wins tiny update batches,
-    fused wins replay-sized ones."""
-    cm = CostModel.default()
-    assert cm.choose(1, ACTOR_DIMS, phase="act") == "layer"
-    assert cm.choose(512, ACTOR_DIMS, phase="act") == "fused"
-    assert cm.choose(8, ACTOR_DIMS, phase="train") == "jnp"
-    assert cm.choose(32, ACTOR_DIMS, phase="train") == "fused_step"
-    assert cm.choose(128, ACTOR_DIMS, phase="train") == "fused_step"
-    # the 2-loss whole-update kernel still beats the custom-VJP pair when
-    # restricted to the pre-fused-step mode set
-    assert cm.choose(128, ACTOR_DIMS, modes=("fused", "jnp"),
-                     phase="train") == "fused"
-    # train argmin never returns the autodiff-less per-layer chain
-    for b in (1, 8, 32, 128, 512):
-        assert cm.choose(b, ACTOR_DIMS, phase="train") in TRAIN_MODES
-    # phase-blind regression: the same (B, modes) pair must cost
-    # differently across phases for every mode
-    for mode in MODES:
-        assert cm.estimate_us(mode, 32, ACTOR_DIMS, "train") > \
-            cm.estimate_us(mode, 32, ACTOR_DIMS, "act")
-
-
-def test_launches_carries_phase():
-    assert CostModel.launches("fused", ACTOR_DIMS) == 1
-    assert CostModel.launches("fused", ACTOR_DIMS, "train") == 2
-    assert CostModel.launches("fused_step", ACTOR_DIMS, "train") == 2
-    assert CostModel.launches("layer", ACTOR_DIMS, "train") == \
-        2 * (len(ACTOR_DIMS) - 1)
-    with pytest.raises(ValueError):
-        CostModel.launches("fused", ACTOR_DIMS, "serve")
-    # fused_step is train-only: it has no acting face to cost
-    with pytest.raises(ValueError, match="train-only"):
-        cost_hint("fused_step", ACTOR_DIMS, "act")
-
-
-def test_from_bench_train_fit_roundtrips(tmp_path):
-    """Synthesize train-phase IPS from known affine coefficients and check
-    the two-point fit recovers BOTH (overhead + rate) into train_costs,
-    leaving the act fit untouched."""
-    truth = {"pallas": (100.0, 0.002), "jnp": (30.0, 0.010),
-             "pallas_fused_step": (80.0, 0.0015)}
-    mode_of = {"pallas": "fused", "jnp": "jnp",
-               "pallas_fused_step": "fused_step"}
-    by_batch = {}
-    for backend, (per_launch, rate) in truth.items():
-        hint = cost_hint(mode_of[backend], ACTOR_DIMS, "train")
-        by_batch[backend] = {}
-        for b in (32, 256):
-            t_us = (per_launch * hint["launches"]
-                    + b * hint["flops_per_item"] / 1e3 * rate)
-            by_batch[backend][str(b)] = b / (t_us * 1e-6)
-    bench = {"config": {"batch": 256, "net": ACTOR_DIMS},
-             "actor_ips": {}, "actor_ips_by_batch": {},
-             "train": {"batch": 128, "ips_by_batch": by_batch,
-                       "updates_per_s": {}}}
-    path = tmp_path / "BENCH_fused_mlp.json"
-    path.write_text(json.dumps(bench))
-    cm = CostModel.from_bench(path)
-    assert cm.source == str(path)
-    for backend, (per_launch, rate) in truth.items():
-        got = cm.train_costs[mode_of[backend]]
-        np.testing.assert_allclose(got.per_launch_us, per_launch, rtol=1e-6,
-                                   err_msg=f"{backend} overhead")
-        np.testing.assert_allclose(got.us_per_kflop, rate, rtol=1e-6,
-                                   err_msg=f"{backend} rate")
-    assert cm.costs == DEFAULT_COSTS  # no acting-path measurements
-
-
-def test_from_bench_train_single_point_fallback(tmp_path):
-    """Legacy bench with only updates_per_s (no ips_by_batch): the
-    train-phase rate refits per mode with default overheads kept."""
-    bench = {"config": {"batch": 256, "net": ACTOR_DIMS},
-             "actor_ips": {}, "actor_ips_by_batch": {},
-             "train": {"batch": 128,
-                       "updates_per_s": {"pallas": 50.0, "jnp": 40.0,
-                                         "pallas_fused_step": 70.0}}}
-    path = tmp_path / "bench.json"
-    path.write_text(json.dumps(bench))
-    cm = CostModel.from_bench(path)
-    assert set(cm.train_costs) == {"fused", "fused_step", "jnp"}
-    for mode in ("fused", "fused_step", "jnp"):
-        assert cm.train_costs[mode].per_launch_us == \
-            DEFAULT_COSTS[mode].per_launch_us
-        assert cm.train_costs[mode].us_per_kflop > 0
-
-
-def test_from_bench_without_train_section_falls_back_to_act_coeffs(tmp_path):
-    """No train section: train_costs stays empty and train estimates run
-    through the act coefficients against the train-phase hints (the model
-    stays total)."""
-    bench = {"config": {"batch": 256, "net": ACTOR_DIMS},
-             "actor_ips": {"jnp": 200_000.0}}
-    path = tmp_path / "bench.json"
-    path.write_text(json.dumps(bench))
-    cm = CostModel.from_bench(path)
-    assert cm.train_costs == {}
-    assert cm.coeffs("fused", "train") == cm.costs["fused"]
-    assert cm.estimate_us("fused", 32, ACTOR_DIMS, "train") > 0
-
-
-# --------------------------------------------------------------------- #
 # batching / engine lifecycle
 # --------------------------------------------------------------------- #
 
@@ -274,7 +177,7 @@ def test_update_batcher_coalesces_by_rows():
 def test_threaded_streaming_applies_all_requests_sequentially():
     state, cfg = _state()
     eng = LearnerEngine.from_ddpg(
-        state, cfg, force_mode="jnp",
+        state, cfg,
         batcher=BatcherConfig(buckets=BUCKETS, max_wait_ms=5.0))
     eng.warmup(padded=True)
     eng.start()
@@ -307,7 +210,7 @@ def test_threaded_streaming_applies_all_requests_sequentially():
 
 def test_submit_requires_running_engine_and_splits_oversize():
     state, cfg = _state()
-    eng = LearnerEngine.from_ddpg(state, cfg, force_mode="jnp",
+    eng = LearnerEngine.from_ddpg(state, cfg,
                                   batcher=BatcherConfig(buckets=BUCKETS))
     with pytest.raises(RuntimeError, match="not streaming"):
         eng.submit(_batch(8))
@@ -325,14 +228,19 @@ def test_submit_requires_running_engine_and_splits_oversize():
 
 
 def test_force_mode_and_pad_policy_validation():
+    """The learner takes no mode to force or choose among: `force_mode=`
+    and `modes=` are refused, as is an update family of more than one
+    function; a bad pad policy is refused by name."""
     state, cfg = _state()
-    with pytest.raises(ValueError, match="force_mode"):
-        LearnerEngine.from_ddpg(state, cfg, force_mode="layer")
-    with pytest.raises(ValueError, match="cannot train"):
-        LearnerEngine.from_ddpg(state, cfg, modes=("fused", "layer"))
+    with pytest.raises(TypeError, match="force_mode"):
+        LearnerEngine.from_ddpg(state, cfg, force_mode="jnp")
+    with pytest.raises(TypeError, match="modes"):
+        LearnerEngine.from_ddpg(state, cfg, modes=("jnp",))
+    with pytest.raises(ValueError, match="one update function"):
+        LearnerEngine(state, {"jnp": None, "pallas": None})
     with pytest.raises(ValueError, match="pad_policy"):
         LearnerEngine.from_ddpg(state, cfg, pad_policy="truncate")
-    eng = LearnerEngine.from_ddpg(state, cfg, force_mode="jnp",
+    eng = LearnerEngine.from_ddpg(state, cfg,
                                   batcher=BatcherConfig(buckets=BUCKETS),
                                   pad_policy="exact")
     with pytest.raises(ValueError, match="exact"):
@@ -343,7 +251,7 @@ def test_force_mode_and_pad_policy_validation():
 
 def test_warmup_compiles_without_advancing_state():
     state, cfg = _state()
-    eng = LearnerEngine.from_ddpg(state, cfg, force_mode="jnp",
+    eng = LearnerEngine.from_ddpg(state, cfg,
                                   batcher=BatcherConfig(buckets=BUCKETS))
     n = eng.warmup(padded=True)
     assert n == len(BUCKETS) * 2  # exact + masked variant per bucket
@@ -364,7 +272,6 @@ def test_generic_update_family_contract():
         return state + batch["x"].sum(), {"loss": batch["x"].mean()}
 
     eng = LearnerEngine(np.float64(0.0), {"jnp": update},
-                        dims=ACTOR_DIMS,
                         batcher=BatcherConfig(buckets=(4, 8)),
                         pad_policy="exact")
     m = eng.run_update({"x": np.ones((8, 2))})
